@@ -11,17 +11,13 @@ runs the TPU build's analog end-to-end in one process:
 - the Python clerks and recipient decrypt, combine, and reveal — the
   exact sum proves byte-level wire compatibility.
 
-    python examples/embedded_participant.py
+    JAX_PLATFORMS=cpu python examples/embedded_participant.py
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

@@ -5,7 +5,7 @@ fixed-point-encoded model deltas are aggregated — masked, secret-shared
 across an 8-clerk committee on a device mesh, and revealed as an exact
 sum. No individual update ever leaves a client in the clear.
 
-Runs anywhere (forces the CPU backend with 8 virtual devices):
+A CPU dry run of the mesh (``force_cpu``: 8 virtual devices, no chip):
 
     python examples/fedavg_lenet.py
 """
@@ -14,12 +14,12 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=8")
+
+from sda_tpu.utils.backend import force_cpu
+
+force_cpu(8)
+
 import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 import optax

@@ -6,7 +6,7 @@ talking REST, then drives one `FederatedSession` round: encoded float
 deltas go up, clerks decrypt/sum/re-encrypt, and the recipient reveals
 the exact quantized mean.
 
-    python examples/federated_http.py
+    JAX_PLATFORMS=cpu python examples/federated_http.py
 """
 
 import os
@@ -14,10 +14,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

@@ -6,7 +6,7 @@ footprint, checkpointing an atomic fsync'd snapshot as it goes. This demo
 injects a failure partway through the stream, then resumes from the
 snapshot and proves the result equals an uninterrupted run exactly.
 
-    python examples/streamed_checkpoint.py
+    JAX_PLATFORMS=cpu python examples/streamed_checkpoint.py
 """
 
 import os
@@ -16,8 +16,6 @@ import tempfile
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 
@@ -39,7 +37,7 @@ key = jax.random.PRNGKey(0)
 with tempfile.TemporaryDirectory() as tmp:
     ck = f"{tmp}/round.ckpt"
 
-    # a provider that dies after a few chunks, like a tunnel mid-round
+    # a provider that dies after a few chunks, like a preempted host mid-round
     calls = {"n": 0}
 
     def flaky(p0, p1, d0, d1):

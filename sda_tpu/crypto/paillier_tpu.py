@@ -4,8 +4,8 @@ The server's Paillier hot loop is homomorphic premix-combine: folding P
 ciphertexts per (clerk, slot) with multiplication mod n^2
 (reference server snapshot premixing, /root/reference/server/src/snapshot.rs:4-47,
 with the PackedPaillier scheme /root/reference/protocol/src/crypto.rs:164-174).
-Host bigint premix measures ~428k el/s (BENCH_SUITE paillier-2048 with the
-native Montgomery ladder); a flagship round needs ~6M 4096-bit modmuls per
+Host bigint premix measures ~428k el/s (a builder's single-core host run
+with the native Montgomery ladder, docs/crypto.md); a flagship round needs ~6M 4096-bit modmuls per
 round, i.e. ~10 minutes of single-core host premix. This module is the
 TPU-native prototype (round-3 verdict #7): ciphertexts as [B, L] arrays of
 8-bit limbs in int32 lanes, batched Montgomery (CIOS) multiplication as

@@ -1,10 +1,10 @@
 """Dim-tiled round schedule: lax.scan over fixed-width dimension tiles.
 
-The round-3 hardware window measured the full-width single-chip round
-SUPERLINEAR in d (marginal 25.8ms at d~1M vs 7.7ms at d/2 — per-element
-cost 1.7x worse at full width; benchmarks/ROOFLINE.md 'Superlinearity').
-Scanning fixed-width tiles keeps every tile on the fast side of that
-cliff and makes round cost affine in d by construction. Shared by the
+A full-width program's intermediates grow with d and may spill where a
+narrower one stays fused (benchmarks/ROOFLINE.md "Width" — whether the
+full-width round is superlinear in d on the chip is not measured).
+Scanning fixed-width tiles bounds every tile's live set and makes round
+cost affine in d by construction. Shared by the
 XLA (mesh.single_chip_round) and Pallas (fields.pallas_round) drivers,
 and — via :func:`tile_plan` — by the model-scale sharded driver
 (mesh/devscale.py), so every tiled lane slices the dimension with ONE

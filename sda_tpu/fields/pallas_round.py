@@ -327,11 +327,7 @@ def fused_mask_share_combine(
     # BlockSpec index maps and loop indices become i64, which Mosaic cannot
     # legalize (func.return (i64) lowering error on real TPU); every value
     # in the kernel is explicitly uint32/int32 so semantics are unchanged.
-    # jax.enable_x64 graduated from jax.experimental after 0.4; take
-    # whichever this jax has
-    _enable_x64 = getattr(jax, "enable_x64", None) \
-        or jax.experimental.enable_x64
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         return call(*args)
 
 

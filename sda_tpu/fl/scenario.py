@@ -1137,6 +1137,8 @@ def _robust_tree_update(codec, leaf_subtotals) -> Optional[np.ndarray]:
 
 def _base_report(profile: FLProfile, dim, codec, accuracy_by_round,
                  per_round, reached_at, exact_rounds, failures) -> dict:
+    import jax
+
     from ..utils import phase_report
 
     from .dp import gaussian_accounting
@@ -1158,7 +1160,9 @@ def _base_report(profile: FLProfile, dim, codec, accuracy_by_round,
         "value": reached_at if reached else rounds_run + 1,
         "direction": "lower",
         "unit": "rounds",
-        "platform": "cpu",
+        # local training and the role code's device path run on whatever
+        # JAX selected — say which
+        "platform": jax.default_backend(),
         # which serving transport carried the rounds (None: in-process,
         # no HTTP plane in the path) — benchmark evidence must say
         "http_plane": (("async" if profile.async_http else "threaded")

@@ -15,15 +15,18 @@ BENCH-style record:
   cannot afford the full object-dtype reference);
 - ``retraces == 0`` across rounds and one compiled shape per stage
   (uniform tails — the devprof tripwire, recorded not just asserted);
-- ``roofline_utilization`` and the ``hbm`` watermark advisory
-  (``hbm_peak_bytes / watermark``) — the two advisory metrics the
-  regression gate reports (obs/regress.py);
+- the ``cost`` block (FLOPs/bytes XLA counts from the shapes) and the
+  ``hbm`` watermark advisory (``hbm_peak_bytes / watermark``); on a
+  device with a complete sourced row in ``devprof.CHIP_PEAKS`` also the
+  ``roofline`` block and ``roofline_utilization`` — a CPU run has
+  neither, nor today a v5e run (obs/regress.py reports both as advisory metrics);
 - comparability tags ``dim / p_shards / d_shards / pallas`` so this
   record NEVER gates against single-chip or different-topology history.
 
-On CPU the record is honest about provenance: ``host_scaled`` marks the
-numbers as CPU-CI stand-ins (same schedule, same verdicts — the chip
-fields populate when hardware is present).
+Every record names its device (``platform``, ``device_kind``,
+``device_count``); ``host_scaled`` marks a CPU run — same schedule, same
+verdicts, no device timing — and ``pallas_interpret`` a kernel that was
+interpreted rather than compiled by Mosaic.
 """
 
 from __future__ import annotations
@@ -149,8 +152,10 @@ def run_devscale(profile: DevScaleProfile) -> dict:
         n_devices, scheme.output_size)[0]
     d_shards = profile.d_shards or (n_devices // p_shards)
     mesh = make_mesh(p_shards, d_shards)
-    platform = jax.devices()[0].platform
-    cpu = platform == "cpu"
+    from ..utils.backend import device_record
+
+    device = device_record()
+    cpu = device["platform"] == "cpu"
 
     obs.reset_all()
     devprof.install_monitoring()
@@ -258,8 +263,8 @@ def run_devscale(profile: DevScaleProfile) -> dict:
         }
 
     wall = time.perf_counter() - wall0
-    roofline = devprof.roofline(seconds=wall, platform=platform)
-    hbm = devprof.watermark_report(platform=platform)
+    roofline = devprof.roofline(seconds=wall)
+    hbm = devprof.watermark_report()
     value = P_total * dim / per_round if per_round > 0 else 0
 
     tiles = -(-dim // pod.dim_chunk)
@@ -269,8 +274,10 @@ def run_devscale(profile: DevScaleProfile) -> dict:
                    % (profile.clerks, profile.mask)),
         "value": round(value),
         "unit": "elements/sec",
-        "platform": platform,
+        **device,
         "pallas": bool(pod.pallas_active),
+        "pallas_interpret": bool(pod.pallas_active
+                                 and profile.pallas_interpret),
         "dim": dim,
         "participants": P_total,
         "p_shards": p_shards,
@@ -289,14 +296,16 @@ def run_devscale(profile: DevScaleProfile) -> dict:
         "warm_program_reused": bool(warm_reused),
         "compiled_shapes": {name: shapes for name, (comp, shapes)
                             in compiles_after.items()},
-        "roofline": roofline,
-        "roofline_utilization": roofline.get("utilization"),
+        "cost": devprof.cost_totals(),
         "hbm": hbm,
         "hbm_watermark_ratio": hbm.get("hbm_watermark_ratio"),
         "host_scaled": cpu,
         "seed": profile.seed,
         "xla": devprof.compile_totals(),
     }
+    if roofline is not None:
+        record["roofline"] = roofline
+        record["roofline_utilization"] = roofline.get("utilization")
     if family:
         record["family"] = family
     if clerk_fed is not None:
@@ -304,9 +313,9 @@ def run_devscale(profile: DevScaleProfile) -> dict:
     if scan is not None:
         record["scan_lane"] = scan
     if cpu:
-        record["note"] = ("CPU CI stand-in: same schedule/verdicts as the "
-                          "chip run; real-TPU fields populate when "
-                          "hardware is present")
+        record["note"] = ("CPU run: same schedule and verdicts as on a "
+                          "chip; its seconds and rates are not device "
+                          "metrics")
     record["ok"] = bool(
         exact and retraces == 0 and warm_reused
         and (clerk_fed is None or clerk_fed["exact"])

@@ -330,7 +330,6 @@ class ModelScaleRound:
     def aggregate(self, inputs, key=None):
         """[P, d] participant inputs -> [d] aggregate (one full round)."""
         import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         inputs = np.asarray(inputs)
@@ -349,7 +348,8 @@ class ModelScaleRound:
         step = self._get_step(P_pad, d_pad)
         sharding = NamedSharding(self.mesh, P("p", "d"))
         with timed_phase("devscale.round"):
-            device_inputs = jax.device_put(jnp.asarray(inputs), sharding)
+            # host array straight onto the mesh, shard by shard
+            device_inputs = jax.device_put(inputs, sharding)
             out = step(device_inputs, key)
             out.block_until_ready()
         return out[:d_total]
@@ -434,10 +434,11 @@ class DeviceTileSink:
                 padded = np.zeros((self._pc, d_size), dtype=host.dtype)
                 padded[: host.shape[0], : host.shape[1]] = host
                 host = padded
-            arr = jnp.asarray(host)
+            # a sharded landing goes host -> each device's own shard,
+            # never through a whole-block copy on device 0
             if self._sharding is not None:
-                arr = jax.device_put(arr, self._sharding)
-            return arr
+                return jax.device_put(host, self._sharding)
+            return jnp.asarray(host)
 
         return self._batch.submit(job)
 

@@ -72,17 +72,9 @@ SHAMIR_SCHEMES = (PackedShamirSharing, BasicShamirSharing)
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with per-shard checking off, falling back to the
-    pre-0.5 ``jax.experimental.shard_map`` spelling (same semantics, the
-    check flag was named ``check_rep``) so the mesh modes run on either
-    jax generation present across this repo's environments."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with per-shard replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # re-export: lives in fields.fastfield (pure field arithmetic); kept under
@@ -297,17 +289,17 @@ def _pallas_env_default() -> bool:
 
 
 def _resolve_pallas(scheme, masking, f: FieldOps, use_pallas, what: str) -> bool:
-    """Shared constructor gating for the three aggregators: env default
-    (SDA_PALLAS=1) falls back to the XLA step silently on unsupported
-    configs; an EXPLICIT use_pallas=True raises instead."""
+    """Shared constructor gating for the three aggregators: ``use_pallas``
+    None takes the SDA_PALLAS=1 env default. Asked for — either way — on
+    a config the kernel does not serve raises; nothing falls back to the
+    XLA step behind the caller."""
     want = _pallas_env_default() if use_pallas is None else bool(use_pallas)
-    active = want and _pallas_supported(scheme, masking, f)
-    if use_pallas and not active:
+    if want and not _pallas_supported(scheme, masking, f):
         raise ValueError(
             f"pallas {what} step requires packed-Shamir over a Solinas "
             f"prime (none/full/chacha masking)"
         )
-    return active
+    return want
 
 
 def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
@@ -659,7 +651,10 @@ class SimulatedPod:
         # first round per shape includes jit compilation (jax.jit is lazy):
         # it shows in the phase stats as max_s >> min_s
         with timed_phase("mesh.round"):
-            device_inputs = jax.device_put(jnp.asarray(inputs), sharding)
+            # host array straight onto the mesh: each device receives only
+            # its own shard (jnp.asarray first would commit the whole
+            # [P, d] matrix to device 0 and reshard from there)
+            device_inputs = jax.device_put(inputs, sharding)
             out = step(device_inputs, key)
             out.block_until_ready()
         return out[:d_total]
@@ -694,12 +689,10 @@ def single_chip_round(
     dimension to be a multiple of 8 (one ChaCha block).
 
     ``dim_tile``: process the dimension in fixed-width tiles via
-    ``lax.scan`` instead of one full-width program. The round-3 hardware
-    window measured the full-width XLA program SUPERLINEAR in d (marginal
-    25.8ms at d~1M vs 7.7ms at d/2 — ratio 3.4, i.e. per-element cost
-    1.7x worse at full width; HW_WATCH.jsonl timing_check), so tiling the
-    dim axis keeps every tile on the fast side of that cliff and makes
-    round cost linear in d by construction. Exact for any tile width:
+    ``lax.scan`` instead of one full-width program, which bounds every
+    tile's live set and makes round cost linear in d by construction
+    (fields/dimtile.py; whether the full-width program is superlinear in
+    d on the chip is not measured). Exact for any tile width:
     each tile is a complete mask->share->combine->reconstruct->unmask
     round over its own columns (masks cancel per tile; ChaCha tiles read
     their window of the global stream via d_block0).

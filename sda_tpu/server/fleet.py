@@ -164,9 +164,11 @@ class Fleet:
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        # the fleet plane measures the transport/store tier; keep worker
-        # startup light and deterministic on any host
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # sdad workers are CPU processes, whatever the launcher runs on: a
+        # chip belongs to one process, and under an inherited
+        # JAX_PLATFORMS=tpu every worker's first device dispatch would
+        # contend for the launcher's chip
+        env["JAX_PLATFORMS"] = "cpu"
         for worker in self.workers:
             # stderr folded into stdout: worker tracebacks land in the
             # retained log instead of interleaving on the launcher's tty

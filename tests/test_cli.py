@@ -214,15 +214,12 @@ def test_cli_paillier_errors_are_friendly(httpd, tmp_path, capsys):
     assert "Sodium" in err and "keys create --encryption paillier" in err
 
 
-def test_sim_cli_clerk_dropout(capsys, monkeypatch):
+def test_sim_cli_clerk_dropout(capsys):
     """`sda-sim --drop-clerks`: the finale reveals exactly from the
     surviving quorum; below-quorum drops fail fast with a clear error."""
     import json
 
     from sda_tpu.cli import sim
-
-    # skip the TPU probe: conftest already pinned the CPU backend
-    monkeypatch.setenv("SDA_SIM_PLATFORM", "cpu")
 
     rc = sim.main([
         "--participants", "8", "--dim", "99", "--clerks", "8",
@@ -231,6 +228,9 @@ def test_sim_cli_clerk_dropout(capsys, monkeypatch):
     assert rc == 0
     result = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert result["exact"] is True and result["dropped_clerks"] == [6]
+    # every line names the device JAX gave the process (conftest: CPU)
+    assert result["platform"] == "cpu" and result["device_kind"] == "cpu"
+    assert result["device_count"] == 8
 
     rc = sim.main([
         "--participants", "8", "--dim", "99", "--clerks", "8",

@@ -158,24 +158,71 @@ def test_streaming_uniform_tail_single_step_shape():
 
 # -- cost analysis / roofline ------------------------------------------------
 
-def test_cost_analysis_feeds_roofline_block():
+def test_cost_analysis_feeds_cost_block_and_cpu_gets_no_roofline():
     devprof.enable_cost_analysis()
     scheme, p = _scheme()
     pod = SimulatedPod(scheme, FullMasking(p))
     rng = np.random.default_rng(3)
     pod.aggregate(rng.integers(0, 99, size=(8, 48), dtype=np.int64))
-    block = devprof.roofline(seconds=0.25)
+    block = devprof.cost_totals()
     assert block["flops"] > 0
     assert block["bytes"] > 0
     assert block["arithmetic_intensity"] > 0
-    assert 0 < block["utilization"] < 1
-    assert block["attainable_flops_per_s"] > 0
     assert block["hbm_peak_bytes"] > 0
     assert "mesh.simpod.round" in block["phases"]
+    # the CPU is not in the peak table: no roofline, not a made-up one
+    assert devprof.roofline(seconds=0.25) is None
     # peak-HBM watermark gauges land in the metrics registry
     gauges = metrics.gauge_report("device.hbm.")
     assert gauges.get("device.hbm.peak_bytes", 0) > 0
     assert gauges.get("device.hbm.peak_bytes.mesh.simpod.round", 0) > 0
+
+
+def test_roofline_reads_peaks_by_device_kind(monkeypatch):
+    import jax
+
+    class _Known:
+        platform = "tpu"
+        device_kind = "TPU unit"
+
+    peaks = {"flops_per_s": 6.0e12, "flops_source": "unit test",
+             "hbm_bytes_per_s": 819e9, "hbm_source": "unit test"}
+    monkeypatch.setitem(devprof.CHIP_PEAKS, "TPU unit", peaks)
+    prof = devprof.profile("unit.round")
+    prof.shapes[("sig",)] = 2
+    prof.costs[("sig",)] = {"flops": 3.0e9, "bytes_accessed": 1.0e9}
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Known()])
+    block = devprof.roofline(seconds=1.0)
+    assert block["device_kind"] == "TPU unit"
+    assert block["peaks"] is peaks
+    assert block["bound"] == "memory"  # AI 3 x 819e9 B/s < the compute peak
+    assert block["utilization"] == pytest.approx(
+        6.0e9 / (3.0 * 819e9), rel=1e-3)
+
+    class _Unknown(_Known):
+        device_kind = "TPU v99"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Unknown()])
+    assert devprof.roofline(seconds=1.0) is None
+
+
+def test_v5e_row_is_sourced_only_and_yields_no_roofline(monkeypatch):
+    # no published int32 VPU peak exists: the row carries the sourced HBM
+    # figure alone, and a row without both peaks emits no utilization
+    import jax
+
+    class _V5e:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    row = devprof.CHIP_PEAKS["TPU v5 lite"]
+    assert "flops_per_s" not in row
+    assert row["hbm_source"]
+    prof = devprof.profile("unit.round")
+    prof.shapes[("sig",)] = 1
+    prof.costs[("sig",)] = {"flops": 3.0e9, "bytes_accessed": 1.0e9}
+    monkeypatch.setattr(jax, "devices", lambda *a: [_V5e()])
+    assert devprof.roofline(seconds=1.0) is None
 
 
 def test_cost_analysis_off_by_default_keeps_single_compile(monkeypatch):
@@ -190,14 +237,12 @@ def test_cost_analysis_off_by_default_keeps_single_compile(monkeypatch):
 
 def test_roofline_block_math():
     # AI = 10 flops/byte; attainable capped by compute peak; 50% achieved
-    block = devprof.roofline_block(
-        1000.0, 100.0, seconds=1.0, platform="cpu")
-    peaks = block["peaks"]
-    attainable = min(peaks["flops_per_s"],
-                     10.0 * peaks["hbm_bytes_per_s"])
+    peaks = {"flops_per_s": 2000.0, "hbm_bytes_per_s": 1000.0}
+    block = devprof.roofline_block(1000.0, 100.0, peaks, seconds=1.0)
     assert block["arithmetic_intensity"] == 10.0
-    assert block["attainable_flops_per_s"] == attainable
-    assert block["utilization"] == pytest.approx(1000.0 / attainable)
+    assert block["attainable_flops_per_s"] == 2000.0
+    assert block["bound"] == "compute"
+    assert block["utilization"] == pytest.approx(0.5)
 
 
 def test_reset_all_clears_devprof_state():

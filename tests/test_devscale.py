@@ -106,7 +106,7 @@ def test_hbm_watermark_env_override(monkeypatch):
     assert devprof.hbm_watermark() == 123456789
     monkeypatch.delenv("SDA_HBM_WATERMARK")
     default = devprof.hbm_watermark()
-    assert 0 < default <= devprof.HBM_WATERMARK_DEFAULTS["cpu"]
+    assert 0 < default <= devprof.CPU_PLANNING_HBM_BYTES
 
 
 def test_watermark_report_shape(monkeypatch):
@@ -320,7 +320,11 @@ def test_run_devscale_record_smoke():
     # the comparability tags the regression gate keys on
     for tag in ("dim", "p_shards", "d_shards", "pallas", "platform"):
         assert tag in record, tag
-    assert record["roofline_utilization"] is not None
+    # counts hold on any backend; a roofline needs a device the peak
+    # table knows, which the CPU is not
+    assert record["cost"]["flops"] > 0
+    assert "roofline" not in record
+    assert record["platform"] == "cpu" and record["device_count"] == 8
     assert record["compiled_shapes"] == {"stream.pod.step": 1,
                                          "stream.pod.finale": 1}
 

@@ -16,7 +16,6 @@ import pytest
 _WORKER = r"""
 import sys
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 port, pid = sys.argv[1], int(sys.argv[2])
 from sda_tpu.mesh import multihost
@@ -106,6 +105,7 @@ def test_two_process_pod_round():
 
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",  # multihost workers are CPU processes
         XLA_FLAGS="--xla_force_host_platform_device_count=4",
         PYTHONPATH=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
@@ -135,7 +135,6 @@ import os
 import sys
 
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 port, pid, attempt, ckdir = (sys.argv[1], int(sys.argv[2]),
                              int(sys.argv[3]), sys.argv[4])
@@ -179,6 +178,7 @@ print(f"CK_OK rank={pid} calls={calls['n']}", flush=True)
 def _launch_ck_workers(port, attempt, ckdir):
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",  # multihost workers are CPU processes
         XLA_FLAGS="--xla_force_host_platform_device_count=4",
         PYTHONPATH=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
@@ -263,7 +263,6 @@ import os
 import sys
 
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 port, pid, attempt, ckdir = (sys.argv[1], int(sys.argv[2]),
                              int(sys.argv[3]), sys.argv[4])
@@ -322,6 +321,7 @@ print(f"QUAD_OK rank={pid} calls={calls['n']}", flush=True)
 def _launch_quad_workers(port, attempt, ckdir):
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",  # multihost workers are CPU processes
         XLA_FLAGS="--xla_force_host_platform_device_count=2",
         PYTHONPATH=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )

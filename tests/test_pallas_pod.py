@@ -228,6 +228,9 @@ def test_pallas_env_default(monkeypatch):
     s = fast_scheme()
     monkeypatch.setenv("SDA_PALLAS", "1")
     assert StreamingAggregator(s).pallas_active
-    assert not StreamingAggregator(GOLDEN).pallas_active  # silent fallback
+    # asked for through the env and unsupported: raises, no silent XLA step
+    with pytest.raises(ValueError, match="requires packed-Shamir"):
+        StreamingAggregator(GOLDEN)
+    assert not StreamingAggregator(GOLDEN, use_pallas=False).pallas_active
     monkeypatch.delenv("SDA_PALLAS")
     assert not StreamingAggregator(s).pallas_active

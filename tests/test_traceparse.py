@@ -1,5 +1,5 @@
-"""Profiler-trace parsing for the hardware timing cross-check
-(utils/traceparse.py; consumed by benchmarks/hw_check.py trace_check)."""
+"""Profiler-trace parsing (utils/traceparse.py): device-lane detection
+and per-module durations out of a ``jax.profiler`` capture."""
 
 import gzip
 import json
@@ -45,8 +45,7 @@ def test_device_lane_detection_and_stats():
     assert stats["jit_round_fn"]["total_us"] == 3000.0
     assert traceparse.dominant_module(stats) == "jit_round_fn"
 
-    # even-length lists take the midpoint average (hw_check traces an even
-    # number of dispatches, so every real run hits this case)
+    # even-length lists take the midpoint average
     tr["traceEvents"].append({"ph": "X", "pid": 2, "tid": 1,
                               "name": "jit_round_fn", "ts": 4000, "dur": 100.0})
     stats = traceparse.device_module_stats(tr)
@@ -77,7 +76,7 @@ def test_load_latest_trace_roundtrip(tmp_path):
 
 def test_real_cpu_capture_has_no_device_lane(tmp_path):
     """A real jax.profiler capture on the CPU backend parses cleanly and
-    reports no accelerator lane — the hw_check stage's advisory path."""
+    reports no accelerator lane."""
     fn = jax.jit(lambda x: (x @ x).sum())
     x = jax.numpy.ones((64, 64))
     jax.block_until_ready(fn(x))

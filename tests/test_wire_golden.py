@@ -27,10 +27,6 @@ one-key object), Option -> null, tuples -> arrays, padded base64.
 
 import json
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 from sda_tpu.protocol import (
     AdditiveSharing,
     Aggregation,
