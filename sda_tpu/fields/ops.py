@@ -53,10 +53,13 @@ class FieldOps:
 
     # -- conversions ------------------------------------------------------
     def to_residues(self, inputs):
-        """Any-integer inputs -> canonical residues in the working dtype."""
-        if self.sp is not None:
-            return fastfield.to_residues32(inputs, self.sp)
-        return modular.canon(jnp.asarray(inputs, jnp.int64), self.m)
+        """Any-integer inputs -> canonical residues in the working dtype.
+        Every round's residue pass goes through here, so this is where it
+        gets its name on the device trace."""
+        with jax.named_scope("sda.residues"):
+            if self.sp is not None:
+                return fastfield.to_residues32(inputs, self.sp)
+            return modular.canon(jnp.asarray(inputs, jnp.int64), self.m)
 
     def to_int64(self, x):
         return x.astype(jnp.int64)

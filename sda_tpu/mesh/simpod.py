@@ -53,8 +53,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..fields import chacha_jax, fastfield, numtheory, sharing
 from ..fields.ops import FieldOps
+from .. import obs
 from ..obs import devprof
-from ..utils import timed_phase
+from ..utils import metrics, timed_phase
 from ..protocol import (
     AdditiveSharing,
     BasicShamirSharing,
@@ -344,7 +345,10 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     S, d_loc = x.shape
     k, t = scheme.secret_count, scheme.privacy_threshold
     masked = isinstance(masking, FullMasking)
-    x_cols = sharing.batch_columns(x, k)                    # [S, k, B0]
+    # sda.relayout: the XLA passes that put the input into the kernel's
+    # [S, k, B] tile layout (and take the mask sum back out of it, below)
+    with jax.named_scope("sda.relayout"):
+        x_cols = sharing.batch_columns(x, k)                # [S, k, B0]
     B0 = x_cols.shape[-1]
     p_block, tile = pallas_knobs()
     # a SWEEP-sourced tile (tuned at flagship widths) must not inflate
@@ -358,7 +362,8 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
         tile = min(tile, shape_tile)
     pad = (-B0) % tile
     if pad:  # padded columns are sliced off below; their shares never land
-        x_cols = jnp.pad(x_cols, ((0, 0), (0, 0), (0, pad)))
+        with jax.named_scope("sda.relayout"):
+            x_cols = jnp.pad(x_cols, ((0, 0), (0, 0), (0, pad)))
     seed = jax.random.randint(dev_key, (), 0, np.int32(2**31 - 1),
                               dtype=jnp.int32)
     ext = None
@@ -374,7 +379,8 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     shares = shares[:, :B0]
     if not masked:
         return shares, chacha_mask_sum
-    return shares, sharing.unbatch_columns(mask_tot[:, :B0], d_loc)
+    with jax.named_scope("sda.relayout"):
+        return shares, sharing.unbatch_columns(mask_tot[:, :B0], d_loc)
 
 
 def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
@@ -620,8 +626,11 @@ class SimulatedPod:
         )
         # devprof: compiled-shape registry + retrace span events + (opt-in)
         # cost analysis for the roofline block — one profile entry for the
-        # whole SPMD round regardless of how many shapes get built
-        return devprof.instrument("mesh.simpod.round", jax.jit(fn))
+        # whole SPMD round regardless of how many shapes get built. Every
+        # holder of the callable (aggregate(), aggregate_fn() callers,
+        # multihost) gets the pod.dispatch span around its calls
+        return devprof.instrument("mesh.simpod.round", jax.jit(fn),
+                                  span="pod.dispatch")
 
     def padded_shape(self, P_total: int, d_total: int) -> Tuple[int, int]:
         p_shards, d_shards = self.mesh.devices.shape
@@ -632,7 +641,13 @@ class SimulatedPod:
         )
 
     def aggregate(self, inputs, key=None):
-        """[P, d] participant inputs -> [d] aggregate (one full round)."""
+        """[P, d] participant inputs -> [d] aggregate (one full round).
+
+        Spans: ``pod.pad`` (only when a pad happens), then ``mesh.round``
+        = ``pod.feed`` + ``pod.dispatch`` + ``pod.wait``, then
+        ``pod.strip`` -- siblings in one trace. Counters at the same
+        boundaries: ``mesh.feed.{calls,bytes,pad_bytes}``
+        (docs/observability.md)."""
         inputs = np.asarray(inputs)
         if key is None:
             from ..crypto.core import fresh_prng_key
@@ -640,24 +655,36 @@ class SimulatedPod:
             key = fresh_prng_key()
         P_total, d_total = inputs.shape
         P_pad, d_pad = self.padded_shape(P_total, d_total)
+        trace = obs.sibling_context()
+        pad_bytes = 0
         if (P_pad, d_pad) != (P_total, d_total):
             # zero participants/components aggregate as zero (masks on the
             # padding cancel like any other mask); strip below
-            padded = np.zeros((P_pad, d_pad), dtype=inputs.dtype)
-            padded[:P_total, :d_total] = inputs
-            inputs = padded
+            with obs.span("pod.pad", parent=trace):
+                padded = np.zeros((P_pad, d_pad), dtype=inputs.dtype)
+                padded[:P_total, :d_total] = inputs
+                inputs = padded
+            pad_bytes = inputs.nbytes
         step = self._get_step(P_pad, d_pad)
         sharding = NamedSharding(self.mesh, P("p", "d"))
+        metrics.count("mesh.feed.calls")
+        metrics.count("mesh.feed.bytes", inputs.nbytes)
+        metrics.count("mesh.feed.pad_bytes", pad_bytes)
         # first round per shape includes jit compilation (jax.jit is lazy):
         # it shows in the phase stats as max_s >> min_s
-        with timed_phase("mesh.round"):
+        with timed_phase("mesh.round", parent=trace):
             # host array straight onto the mesh: each device receives only
             # its own shard (jnp.asarray first would commit the whole
             # [P, d] matrix to device 0 and reshard from there)
-            device_inputs = jax.device_put(inputs, sharding)
-            out = step(device_inputs, key)
-            out.block_until_ready()
-        return out[:d_total]
+            with obs.span("pod.feed", attributes={
+                    "bytes": inputs.nbytes, "dtype": str(inputs.dtype),
+                    "shape": list(inputs.shape)}):
+                device_inputs = jax.device_put(inputs, sharding)
+            out = step(device_inputs, key)  # opens pod.dispatch (_build)
+            with obs.span("pod.wait"):
+                out.block_until_ready()
+        with obs.span("pod.strip", parent=trace):
+            return out[:d_total]
 
     def _get_step(self, P_pad: int, d_pad: int):
         """The jitted SPMD round for an already-padded shape (one-shape

@@ -29,6 +29,7 @@ from .trace import (
     seed_ids,
     set_attribute,
     set_span_sink,
+    sibling_context,
     span,
     span_sink,
 )
@@ -75,6 +76,7 @@ __all__ = [
     "seed_ids",
     "set_attribute",
     "set_span_sink",
+    "sibling_context",
     "slowest_spans",
     "span",
     "span_sink",

@@ -362,7 +362,7 @@ def _record_retrace(name: str, sig: Tuple, compiles: int) -> None:
             _trace.add_event("xla.retrace", **attrs)
 
 
-def instrument(name: str, fn):
+def instrument(name: str, fn, span: Optional[str] = None):
     """Wrap a jitted callable with the compiled-shape registry.
 
     Repeated ``instrument`` calls with the same ``name`` (e.g. the
@@ -370,6 +370,12 @@ def instrument(name: str, fn):
     ONE profile entry, so the registry reflects the logical phase, not
     the python object. The wrapper forwards ``lower``/``_cache_size`` so
     AOT consumers and the jit-cache tripwire tests keep working.
+
+    ``span`` names an ``obs`` span to open around every top-level call,
+    from entry until the (asynchronous) dispatch returns: the host's side
+    of the call, registry bookkeeping included, on every path that holds
+    the wrapper. A retrace event then lands on that span. Calls from
+    inside an outer trace open none (they dispatch nothing).
     """
     profile(name)  # eager registration; the wrapper re-resolves per call
     # compile accounting and cost capture only make sense for jit-like
@@ -377,13 +383,7 @@ def instrument(name: str, fn):
     # fabricate "compiles"/"retraces" per new argument shape
     jitlike = hasattr(fn, "lower") or _cache_size(fn) is not None
 
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        if _is_traced(args, kwargs):
-            import jax
-
-            with jax.named_scope(name):
-                return fn(*args, **kwargs)
+    def dispatch(args, kwargs):
         # re-resolved per call, NOT closed over: module-level wrappers
         # (fields/sharing.py) outlive obs.reset_all(), and stats written
         # into a pre-reset profile object would be invisible forever
@@ -422,6 +422,18 @@ def instrument(name: str, fn):
             if compiles_before >= 1:
                 _record_retrace(name, sig, compiles_before)
         return out
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        if _is_traced(args, kwargs):
+            import jax
+
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        if span is None:
+            return dispatch(args, kwargs)
+        with _trace.span(span):
+            return dispatch(args, kwargs)
 
     wrapper.__wrapped__ = fn
     for attr in ("lower", "_cache_size", "trace", "eval_shape"):

@@ -215,8 +215,8 @@ def chrome_trace_from_records(records: List[dict]) -> dict:
 
 
 def merge_chrome_traces(*traces: dict) -> dict:
-    """Concatenate Chrome trace dicts (e.g. the span export plus a
-    ``jax.profiler`` device trace loaded via ``traceparse``), remapping
+    """Concatenate Chrome trace dicts (e.g. the span exports of several
+    processes, or one beside a profiler's ``*.trace.json.gz``), remapping
     pids so lanes from different sources never collide."""
     events = []
     next_pid = 0

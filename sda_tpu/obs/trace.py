@@ -20,9 +20,11 @@ lease-reissue caused it?". This module is the causal view:
   ``X-Trace-Context`` response header of clerking-job polls, mirrored in a
   bounded in-process registry (``link_job``/``job_link``).
 - **Export**: finished spans land in a bounded ring buffer; ``chrome_trace``
-  renders them in the Chrome trace-event format — the same format family
-  ``utils/traceparse.py`` already reads, so ``jax.profiler`` device lanes
-  merge into the same timeline (``timeline.merge_chrome_traces``).
+  renders them in the Chrome trace-event format, so any Chrome-format
+  trace merges into the same timeline (``timeline.merge_chrome_traces``).
+  A finished-span sink (``set_span_sink``) hands every span, on the epoch
+  clock, to whoever lays it beside a device trace
+  (``benchmarks/chip/reduce/``).
 
 Ids come from ``SystemRandom`` by default; ``seed_ids(seed)`` switches to a
 deterministic stream so replay tests get byte-stable traces. Recording a
@@ -215,6 +217,15 @@ def current_context() -> Optional[SpanContext]:
     return None if span_ is None else span_.context
 
 
+def sibling_context() -> SpanContext:
+    """The context under which a run of sibling spans that no span
+    encloses still forms ONE trace: the current span's context when one is
+    open (``parent=`` it is then the default nesting), else a fresh trace
+    id with no span id -- spans opened with ``parent=`` that are roots
+    (``parent_id`` None) sharing the trace."""
+    return current_context() or SpanContext(_ids.trace_id(), None)
+
+
 @contextlib.contextmanager
 def span(
     name: str,
@@ -362,8 +373,7 @@ def chrome_trace(spans: Optional[List[Span]] = None) -> dict:
     ("X") event per span (``ts``/``dur`` in microseconds of wall-clock
     epoch, trace/span/parent ids under ``args``), one instant ("i") event
     per span event, and ``process_name`` metadata naming each lane. The
-    format family is what ``utils/traceparse.py`` parses and what
-    ``chrome://tracing`` / Perfetto load directly."""
+    format is what ``chrome://tracing`` / Perfetto load directly."""
     if spans is None:
         spans = finished_spans()
     lanes: Dict[str, int] = {}

@@ -3,27 +3,27 @@
 The reference has no tracing or profiling at all (SURVEY.md §5.1 — no
 timers, spans, or metrics anywhere in /root/reference). Here every protocol
 phase (participant mask/share/encrypt, clerk decrypt/combine/encrypt,
-recipient reconstruct/unmask, server snapshot steps) runs under
-``timed_phase``, which
+recipient reconstruct/unmask, server snapshot steps, the pod's round) runs
+under ``timed_phase``: ONE ``obs.span`` whose measured duration also
+accumulates in a process-global registry (``phase_report()`` returns the
+stats; ``bench`` and tests read it). One span layer, one clock pair: the
+span's epoch start puts the phase on a device trace's clock (through
+``obs.set_span_sink``; ``benchmarks/chip/reduce/`` labels the device's idle
+gaps with it), its ``perf_counter`` duration is the phase's seconds.
 
-- accumulates wall-clock stats in a process-global registry
-  (``phase_report()`` returns them; ``bench`` and tests read it), and
-- opens a ``jax.profiler.TraceAnnotation`` so the phase shows up as a named
-  span on the TensorBoard trace timeline when a profiler session is active
-  (``profile_trace`` context manager, or programmatic
-  ``jax.profiler.start_trace``).
-
-Timing costs one ``perf_counter`` pair + dict update per phase — noise next
-to any device math, safe to leave on permanently.
+A phase costs one span (two ``perf_counter`` calls, ids, a deque append)
++ one dict update — noise next to any device math, safe to leave on
+permanently.
 """
 
 from __future__ import annotations
 
 import contextlib
 import threading
-import time
 from dataclasses import dataclass, field
-from typing import Dict, Iterator
+from typing import Dict, Iterator, Optional
+
+from ..obs import trace as _trace
 
 
 @dataclass
@@ -58,28 +58,25 @@ _stats: Dict[str, PhaseStat] = {}
 
 
 @contextlib.contextmanager
-def timed_phase(name: str) -> Iterator[None]:
-    """Time a protocol phase, annotate it on any active profiler trace, and
-    record it as a span in the distributed-tracing layer (``sda_tpu.obs``)
-    so the phase joins the round's causal timeline, parented to whatever
-    span is active on this thread (an HTTP server span, a client role
-    span, ...)."""
-    import jax.profiler
-
-    from .. import obs
-
-    start = time.perf_counter()
+def timed_phase(name: str, *,
+                parent: Optional[_trace.SpanContext] = None,
+                ) -> Iterator[_trace.Span]:
+    """Time a protocol phase: one span in the distributed-tracing layer
+    (``sda_tpu.obs``), so the phase joins the round's causal timeline --
+    parented to ``parent`` when given, else to whatever span is active on
+    this thread (an HTTP server span, a client role span, ...) -- whose
+    measured duration also lands in the phase registry."""
+    span_ = None
     try:
-        with jax.profiler.TraceAnnotation(name):
-            with obs.span(name):
-                yield
+        with _trace.span(name, parent=parent) as span_:
+            yield span_
     finally:
-        elapsed = time.perf_counter() - start
-        with _lock:
-            stat = _stats.get(name)
-            if stat is None:
-                stat = _stats[name] = PhaseStat()
-            stat.add(elapsed)
+        if span_ is not None:  # closed by now, also on an exception
+            with _lock:
+                stat = _stats.get(name)
+                if stat is None:
+                    stat = _stats[name] = PhaseStat()
+                stat.add(span_.duration_s)
 
 
 def phase_report() -> Dict[str, Dict[str, float]]:
@@ -95,8 +92,11 @@ def reset_phase_report() -> None:
 
 @contextlib.contextmanager
 def profile_trace(logdir: str) -> Iterator[None]:
-    """Capture a JAX/XLA profiler trace (device + host timelines, with
-    ``timed_phase`` spans) into ``logdir`` for TensorBoard/XProf."""
+    """Capture a JAX/XLA profiler trace (the device's ops under their
+    ``sda.*`` named scopes, and the profiler's own host timeline) into
+    ``logdir`` for TensorBoard/XProf: an operator's device trace. The
+    program's phases are not in it; they are ``obs`` spans on the epoch
+    clock, which ``benchmarks/chip/reduce/`` lays beside the device ops."""
     import jax.profiler
 
     jax.profiler.start_trace(logdir)

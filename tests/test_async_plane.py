@@ -680,11 +680,20 @@ def test_traceparent_joins_parked_longpoll_pickup(plane):
         t.join(timeout=10)
         assert not t.is_alive()
         assert got["job"] is not None
-        joined = [s for s in obs.finished_spans()
-                  if s.name.startswith("http.server")
-                  and s.trace_id == got["trace"]]
+        # the async plane amends the span AFTER it wrote the reply, so the
+        # client can be back first: give the event loop a moment
+        deadline = time.monotonic() + 5.0
+        while True:
+            joined = [s for s in obs.finished_spans()
+                      if s.name.startswith("http.server")
+                      and s.trace_id == got["trace"]]
+            parked = max(joined, key=lambda s: s.duration_s or 0.0,
+                         default=None)
+            if (parked is not None and (parked.duration_s or 0.0) >= 0.3) \
+                    or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert joined, "server spans must join the clerk's trace"
-        parked = max(joined, key=lambda s: s.duration_s or 0.0)
         assert parked.attributes["http.route"].startswith("GET:")
         assert (parked.duration_s or 0.0) >= 0.3
     finally:

@@ -70,17 +70,18 @@ def test_simpod_shape_change_midrun_emits_retrace_span_event():
     counters = metrics.counter_report("xla.compile.retrace")
     assert counters.get("xla.compile.retrace") == 1
     assert counters.get("xla.compile.retrace.mesh.simpod.round") == 1
-    # ... and the retrace is attributed in the exported trace, parented
-    # into the round that paid it (aggregate runs under timed_phase)
+    # ... and the retrace is attributed in the exported trace: it lands on
+    # the pod.dispatch span that paid it, whose parent is a mesh.round span
     trace = obs.chrome_trace()
     instants = [e for e in trace["traceEvents"]
                 if e.get("ph") == "i" and e["name"] == "xla.retrace"]
     assert len(instants) == 1
     assert instants[0]["args"]["function"] == "mesh.simpod.round"
-    round_spans = [e for e in trace["traceEvents"]
-                   if e.get("ph") == "X" and e["name"] == "mesh.round"]
-    assert instants[0]["args"]["span_id"] in {
-        e["args"]["span_id"] for e in round_spans}
+    spans = {e["args"]["span_id"]: e for e in trace["traceEvents"]
+             if e.get("ph") == "X"}
+    dispatch = spans[instants[0]["args"]["span_id"]]
+    assert dispatch["name"] == "pod.dispatch"
+    assert spans[dispatch["args"]["parent_id"]]["name"] == "mesh.round"
 
 
 def test_streaming_at_most_two_compiled_shapes_per_axis():

@@ -14,6 +14,7 @@ is exactly the sharing shape two ``sdad`` OS processes have.
 """
 
 import threading
+import time
 
 import pytest
 
@@ -473,8 +474,15 @@ def test_node_id_lands_on_server_spans():
     ).start_background()
     try:
         requests.get(srv.address + "/v1/ping")
-        spans = [s for s in obs.finished_spans()
-                 if s.name.startswith("http.server")]
+        # the server span closes after the reply is written: the client can
+        # be back first, so give the handler thread a moment to finish
+        deadline = time.monotonic() + 5.0
+        while True:
+            spans = [s for s in obs.finished_spans()
+                     if s.name.startswith("http.server")]
+            if spans or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert spans, "expected a server span"
         assert all(s.attributes.get("node_id") == "w7" for s in spans)
     finally:

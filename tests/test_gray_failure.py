@@ -541,6 +541,7 @@ def test_breaker_opens_sheds_and_recovers():
     # recovery elapses -> half-open: exactly one probe goes through
     breaker._opened_at -= 31.0
     store._inner.failing = False
+    time.sleep(0.001)  # the report rounds the outage to 0.1 ms
     assert store.ping() is None
     assert breaker.state == "closed"
     report = breaker.report()
