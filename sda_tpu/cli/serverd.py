@@ -336,8 +336,6 @@ def main(argv=None) -> int:
         from ..http.server import trace_log
 
         trace_log.setLevel(logging.INFO)
-    print(f"sdad listening on {server.address}", flush=True)
-
     # graceful drain on SIGTERM/SIGINT (the fleet contract): stop
     # accepting, finish in-flight requests, hand held clerking-job leases
     # back to the shared store so a peer reissues them immediately, and
@@ -354,6 +352,9 @@ def main(argv=None) -> int:
 
     signal.signal(signal.SIGTERM, _on_signal)
     signal.signal(signal.SIGINT, _on_signal)
+    # announced only once the handlers are in: a launcher may SIGTERM the
+    # worker the moment it reads this line, and must get a drain for it
+    print(f"sdad listening on {server.address}", flush=True)
     server.start_background()
     try:
         stop.wait()

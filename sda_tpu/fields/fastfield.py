@@ -141,24 +141,25 @@ def mulmod32_const(x, c: int, sp: SolinasPrime):
 def modsum32(x, sp: SolinasPrime, axis: int = 0):
     """Canonical residues summed along ``axis`` -> canonical (clerk kernel).
 
-    Tree reduction with a canonicalizing fold every ``fan`` terms, fan
-    chosen so partial sums stay < 2^32 (fan*(p-1) < 2^32).
+    ONE reduce whose combiner is the modular add (a + b < 2p < 2^30, so
+    the unsigned minimum of s and s - p is the canonical sum): exact in
+    any order, and a single op that the TPU compiler fuses with an
+    elementwise producer — the fold of a round's whole input reads it
+    once and writes only the sum. Keep it one op: a tree of raw uint32
+    adds with a canonicalizing fold every few terms needs its axis padded
+    to whole groups, and the compiler fuses no producer through that pad
+    (nor into a whole-groups/remainder pair of reduces, which gives the
+    producer two consumers): it writes the canonical [S, d] input to HBM
+    and reads it back.
     """
-    fan = (0xFFFFFFFF) // (sp.p - 1) if sp.p > 1 else 8
-    fan = max(2, min(256, fan))
     x = jnp.asarray(x, _U32)
-    x = jnp.moveaxis(x, axis, 0)
-    while x.shape[0] > 1:
-        n = x.shape[0]
-        chunk = min(fan, n)
-        pad = (-n) % chunk
-        if pad:
-            x = jnp.concatenate(
-                [x, jnp.zeros((pad,) + x.shape[1:], _U32)], axis=0
-            )
-        x = x.reshape((x.shape[0] // chunk, chunk) + x.shape[1:])
-        x = canon32(jnp.sum(x, axis=1, dtype=_U32), sp)
-    return x[0]
+    p = np.uint32(sp.p)
+
+    def add(a, b):
+        s = a + b
+        return jnp.minimum(s, s - p)
+
+    return jax.lax.reduce(x, np.uint32(0), add, (axis % x.ndim,))
 
 
 def uniform32(key, shape, sp: SolinasPrime):

@@ -1,12 +1,17 @@
-"""Fused Pallas kernel: mask + share + participant-combine in one HBM pass.
+"""Fused Pallas kernel: mask + share + participant-combine, nothing per
+participant in HBM.
 
-The XLA fast path (fields.fastfield) still materializes the [P, n, B] share
-tensor in HBM between the share matmul and the clerk combine — for the
-flagship config that's ~2GB of write+read traffic. This kernel fuses the
-participant loop: for each dimension tile it draws the masks and share
-randomness on-core (pltpu PRNG), forms each participant's shares in VMEM,
-and folds them straight into [n, TB] accumulators. HBM traffic drops to
-one read of the inputs plus accumulator-sized writes.
+Sharing is linear, so the clerk-combined shares of a round are
+``M @ (Σ_p x_p + Σ_p mask_p ; Σ_p r_p)``. The callers fold the secrets
+over the participants on the input's native ``[P, d]`` layout (one read of
+the input, ``fastfield.modsum32``) and hand the kernel the folded secrets
+in the column-per-batch layout ``[k, B]`` — 1/P of the input, so the
+layout change in front of the kernel costs nothing. For each dimension
+tile the kernel takes that block once, draws every participant's masks and
+share randomness on-core (pltpu PRNG), folds the draws in VMEM and runs
+the share contraction on the folds into ``[n, TB]`` accumulators. It reads
+``[k, B]`` and writes accumulator-sized outputs; masks and share
+randomness never touch HBM.
 
 Algebra is the uint32 Solinas fast field (see fastfield.py — same bounds,
 same helpers; fastfield's jnp ops compose inside Pallas kernels). The
@@ -21,9 +26,10 @@ an input — it exists so the arithmetic is bit-checkable under
 also what a protocol-grade deployment would use to inject threefry/ChaCha
 streams (reference mask PRGs: client/src/crypto/masking/*.rs).
 
-Opt-in: `single_chip_round_pallas` is selected by bench/driver code when
-SDA_PALLAS=1; the XLA paths remain the default until the kernel wins on
-real hardware.
+Callers: ``mesh.simpod._pallas_stage`` (the pod, streamed and model-scale
+steps, with ``use_pallas=True`` or ``SDA_PALLAS=1``) and
+``single_chip_round_pallas`` below (``bench.py``). The XLA steps stay the
+default of the library; the chip benchmark's cells all run this kernel.
 """
 
 from __future__ import annotations
@@ -73,10 +79,13 @@ def _share_rows_const(values_rows, m_host_row, sp: SolinasPrime):
 
 
 def _participant_tile(pb: int, rows_per_participant: int, tile: int) -> int:
-    """Participants per VMEM block, sized so the (double-buffered) input
-    blocks stay ~3MB total. ``rows_per_participant`` counts every uint32
-    row the grid streams per participant: k for the x block, plus 2*draws
-    bit rows in external-bits mode (which therefore tiles more finely)."""
+    """Participants per grid step along the participant axis, sized as if
+    ``rows_per_participant`` uint32 rows per participant streamed through
+    (double-buffered) ~3MB VMEM blocks: k, plus 2*draws bit rows in
+    external-bits mode (which therefore tiles more finely). The k secret
+    rows no longer stream — the secrets arrive folded — but the count is
+    kept so the blocking (and the PRNG stream per tile) is what it was;
+    re-blocking the participant axis is ROADMAP queue A."""
     cap = max(1, 3_000_000 // (rows_per_participant * tile * 4))
     return max(pb, (cap // pb) * pb)
 
@@ -93,7 +102,8 @@ def _balanced_tiling(P: int, pb: int, tile_cap: int):
 
 
 def fused_mask_share_combine(
-    x_cols,
+    x_sum,
+    participants: int,
     seed,
     sp: SolinasPrime,
     m_host: np.ndarray,
@@ -106,7 +116,9 @@ def fused_mask_share_combine(
     p_tile: Optional[int] = None,
     tree_fold: bool = False,
 ):
-    """[P, k, B] canonical uint32 columns -> ([n, B] combined shares,
+    """[k, B] uint32 secrets folded over the participants (Σ_p x_p mod p,
+    column-per-batch layout; canonicalized at first touch) and the number
+    of ``participants`` P to draw for -> ([n, B] combined shares of all P,
     [k, B] mask totals).
 
     external_bits: optional [P, 2*(k+t) or 2*t, B] uint32 pre-drawn bits
@@ -117,8 +129,9 @@ def fused_mask_share_combine(
     and one matmul per block); it shrinks to a divisor of P when needed.
     ``p_tile`` (a multiple of the effective p_block dividing P; derived
     from the VMEM budget when None) sets how many participants each
-    grid-axis-1 block streams through VMEM. The mod-p algebra is exact,
-    so neither size ever changes results.
+    grid-axis-1 step draws for (and, in external-bits mode, streams bits
+    for). The mod-p algebra is exact, so neither size ever changes
+    results.
 
     ``tree_fold`` replaces the per-slice participant fold (adds on
     [rows, TB] slices, rows = k or t of 8 sublanes per vreg) with a
@@ -128,11 +141,14 @@ def fused_mask_share_combine(
     only when the effective p_block is a power of two >= 2; otherwise
     the slice fold runs as before.
     """
-    P, k, B = x_cols.shape
+    P = int(participants)
+    k, B = x_sum.shape
     n, m2 = m_host.shape
     t = privacy_threshold
     if m2 != 1 + k + t:
         raise ValueError(f"share matrix width {m2} != 1+k+t={1 + k + t}")
+    if P < 1:
+        raise ValueError(f"participants={P} must be at least 1")
     if B % tile:
         raise ValueError(f"B={B} must be divisible by tile={tile}")
     pb = max(1, min(int(p_block), P))
@@ -140,10 +156,14 @@ def fused_mask_share_combine(
         pb = math.gcd(pb, P)
     draws = (k + t) if masked else t
     internal = external_bits is None
-    # participants stream through VMEM in tiles of p_tile along a second
-    # (reduction) grid axis — holding all P in one block OOMs VMEM beyond
-    # a few hundred participants (external-bits mode carries 2*draws extra
-    # rows per participant and tiles more finely)
+    if not internal and external_bits.shape != (P, 2 * draws, B):
+        raise ValueError(
+            f"external_bits {external_bits.shape} != "
+            f"[P, 2*draws, B] = {(P, 2 * draws, B)}"
+        )
+    # participants are drawn for in tiles of p_tile along a second
+    # (reduction) grid axis — external bits for all P in one block OOM
+    # VMEM beyond a few hundred participants
     rows = k if internal else k + 2 * draws
     if p_tile is None:
         p_tile = min(P, _participant_tile(pb, rows, tile))
@@ -234,46 +254,41 @@ def fused_mask_share_combine(
         mh_k, mh_t = mh_ref[...][:, :k], mh_ref[...][:, k:]
         ml_k, ml_t = ml_ref[...][:, :k], ml_ref[...][:, k:]
 
+        # share-combine is LINEAR: the clerk-combined output
+        # Σ_p M @ values_p equals M @ (Σ_p values_p), so participants fold
+        # with cheap adds FIRST and the matmul runs once per fold —
+        # per-participant share rows are never materialized (in the
+        # distributed protocol they live on the participants' own devices;
+        # a chip computing the aggregate needs only their sum). Bit-exact
+        # vs the per-participant XLA path given the same bits: mod-p
+        # arithmetic is exact, so fold order is free.
+
         # the participant axis (grid dim 1) revisits the same output block:
-        # zero it on the first visit, accumulate on the rest
+        # on the first visit it takes the share of the folded secrets
+        # (their block index ignores that axis: one fetch per dim tile),
+        # the rest accumulate the draws' shares onto it
         @pl.when(pl.program_id(1) == 0)
         def _init():
-            shares_ref[...] = jnp.zeros_like(shares_ref)
+            # canon at first touch: the contraction's limb bounds need
+            # terms < p, and the docstring contract is otherwise unenforced
+            shares_ref[...] = fastfield.modmatmul32_limbs(
+                mh_k, ml_k, canon32(x_ref[...], sp), sp)      # [n, TB]
             masktot_ref[...] = jnp.zeros_like(masktot_ref)
 
         def body(b_ix, carry):
-            # share-combine is LINEAR: the clerk-combined output
-            # Σ_p M @ values_p equals M @ (Σ_p values_p), so participants
-            # fold with cheap adds FIRST and the matmul runs once per fold
-            # block — per-participant share rows are never materialized
-            # (in the distributed protocol they live on the participants'
-            # own devices; a chip computing the aggregate needs only their
-            # sum). Bit-exact vs the per-participant XLA path given the
-            # same bits: mod-p arithmetic is exact, so fold order is free.
             p0 = b_ix * np.int32(pb)
-            x_blk = x_ref[pl.ds(p0, pb)]                      # [pb, k, TB]
-            # canon at first touch: the folds' raw-add bounds need terms
-            # < p, and the docstring contract (canonical inputs) is
-            # otherwise unenforced
-            if use_tree:
-                xsum = tree_fold_block(
-                    canon32(x_blk, sp).reshape(pb * k, tile), k)  # [k, TB]
-            else:
-                xsum = fold_slices(
-                    lambda i: canon32(x_blk[i], sp), pb)      # [k, TB]
             if masked:
                 masksum = draw_sum(k, 0, p0)                  # [k, TB]
-                values_k = modadd32(xsum, masksum, sp)
                 masktot_ref[...] = modadd32(masktot_ref[...], masksum, sp)
-                randsum = draw_sum(t, k, p0)
+                contrib = modadd32(
+                    fastfield.modmatmul32_limbs(mh_k, ml_k, masksum, sp),
+                    fastfield.modmatmul32_limbs(
+                        mh_t, ml_t, draw_sum(t, k, p0), sp),
+                    sp,
+                )                                             # [n, TB]
             else:
-                values_k = xsum
-                randsum = draw_sum(t, 0, p0)
-            contrib = modadd32(
-                fastfield.modmatmul32_limbs(mh_k, ml_k, values_k, sp),
-                fastfield.modmatmul32_limbs(mh_t, ml_t, randsum, sp),
-                sp,
-            )                                                 # [n, TB]
+                contrib = fastfield.modmatmul32_limbs(
+                    mh_t, ml_t, draw_sum(t, 0, p0), sp)
             shares_ref[...] = modadd32(shares_ref[...], contrib, sp)
             return carry  # int32 zero: Mosaic cannot legalize an i64 carry
 
@@ -290,16 +305,15 @@ def fused_mask_share_combine(
     ml_np = (m_active & 0x7FFF).astype(np.uint32)
 
     # grid dim 0: dim tiles; grid dim 1 (innermost): participant tiles
-    # streamed through the same output block
+    # accumulated into the same output block
     grid = (B // tile, P // p_tile)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                     # seed
-        pl.BlockSpec((p_tile, k, tile), lambda i, j: (j, 0, i),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((k, tile), lambda i, j: (0, i), memory_space=pltpu.VMEM),
         pl.BlockSpec(mh_np.shape, lambda i, j: (0, 0), memory_space=pltpu.VMEM),
         pl.BlockSpec(ml_np.shape, lambda i, j: (0, 0), memory_space=pltpu.VMEM),
     ]
-    args = [jnp.asarray([seed], jnp.int32), x_cols,
+    args = [jnp.asarray([seed], jnp.int32), x_sum,
             jnp.asarray(mh_np), jnp.asarray(ml_np)]
     if not internal:
         in_specs.append(
@@ -373,8 +387,11 @@ def single_chip_round_pallas(
 
     def one_tile(inputs, key):
         P, d = inputs.shape
-        x = fastfield.to_residues32(inputs, sp)
-        x_cols = batch_columns(x, k)                               # [P, k, B0]
+        # fold first, lay out second: the column-per-batch relayout and
+        # the tile pad run on the folded [d] vector, never on [P, d]
+        x_sum = fastfield.modsum32(
+            fastfield.to_residues32(inputs, sp), sp, axis=0)
+        x_cols = batch_columns(x_sum, k)                           # [k, B0]
         pb = max(1, min(p_block, P))
         B0 = x_cols.shape[-1]
         # lane-dim tile: multiples of 128 lanes; large tiles amortize the
@@ -382,8 +399,8 @@ def single_chip_round_pallas(
         TB = tile if tile is not None else (
             2048 if B0 >= 2048 else max(128, -(-B0 // 128) * 128)
         )
-        # pad the participant axis to a balanced tiling (zero rows
-        # aggregate as zero; their masks cancel)
+        # round the DRAW count up to a balanced tiling: the extra
+        # participants contribute no secrets and their masks cancel
         rows = k if external_bits_fn is None else k + 2 * draws
         if p_tile is None:
             ptile_eff, P_eff = _balanced_tiling(
@@ -392,18 +409,16 @@ def single_chip_round_pallas(
         else:
             ptile_eff = int(p_tile)
             P_eff = -(-P // ptile_eff) * ptile_eff
-        if P_eff > P:
-            x_cols = jnp.pad(x_cols, ((0, P_eff - P), (0, 0), (0, 0)))
         pad = (-B0) % TB
         if pad:
-            x_cols = jnp.pad(x_cols, ((0, 0), (0, 0), (0, pad)))
+            x_cols = jnp.pad(x_cols, ((0, 0), (0, pad)))
         B = B0 + pad
         seed = jax.random.randint(key, (), 0, np.int32(2**31 - 1), dtype=jnp.int32)
         ext = None
         if external_bits_fn is not None:
             ext = external_bits_fn(key, P_eff, draws, B)
         shares, mask_tot = fused_mask_share_combine(
-            x_cols, seed, sp, m_host, t, masked,
+            x_cols, P_eff, seed, sp, m_host, t, masked,
             tile=TB, external_bits=ext, interpret=interpret, p_block=pb,
             p_tile=ptile_eff, tree_fold=tree_fold,
         )

@@ -310,7 +310,16 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     mask sum [d_loc] | None) on the fused Pallas kernel.
 
     Drop-in replacement for the _mask_stage + _share_sum_stage pair in the
-    pod/streamed local steps (fused HBM pass: pallas_round.py). The round
+    pod/streamed local steps. Fold first, lay out second, as
+    _share_sum_stage does: the residues fold over the participants on
+    their native [S, d_loc] layout (``sda.fold``: one read of the input,
+    exact), the column-per-batch relayout and the pad to the kernel's
+    column tile run on the folded [d_loc] vector (``sda.relayout``), and
+    the kernel (``sda.mask_share``, pallas_round.py) draws the S
+    participants' masks and share randomness on-core and shares the
+    folds. Nothing per participant is laid out, and with none/full
+    masking the compiler fuses the residue pass into the fold, so no
+    op writes S x d_loc elements (PERF.md §5). The round
     result is exact for ANY mask/share randomness — masks cancel in the
     final subtract and the random polynomial rows are annihilated by the
     reconstruction matrix — so swapping the XLA threefry draws for the
@@ -345,10 +354,13 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     S, d_loc = x.shape
     k, t = scheme.secret_count, scheme.privacy_threshold
     masked = isinstance(masking, FullMasking)
-    # sda.relayout: the XLA passes that put the input into the kernel's
-    # [S, k, B] tile layout (and take the mask sum back out of it, below)
+    with jax.named_scope("sda.fold"):
+        x_sum = f.sum(x, axis=0)                            # [d_loc]
+    # sda.relayout: the XLA passes that put the folded secrets into the
+    # kernel's [k, B] tile layout (and take the mask sum back out of it,
+    # below)
     with jax.named_scope("sda.relayout"):
-        x_cols = sharing.batch_columns(x, k)                # [S, k, B0]
+        x_cols = sharing.batch_columns(x_sum, k)            # [k, B0]
     B0 = x_cols.shape[-1]
     p_block, tile = pallas_knobs()
     # a SWEEP-sourced tile (tuned at flagship widths) must not inflate
@@ -363,7 +375,7 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     pad = (-B0) % tile
     if pad:  # padded columns are sliced off below; their shares never land
         with jax.named_scope("sda.relayout"):
-            x_cols = jnp.pad(x_cols, ((0, 0), (0, 0), (0, pad)))
+            x_cols = jnp.pad(x_cols, ((0, 0), (0, pad)))
     seed = jax.random.randint(dev_key, (), 0, np.int32(2**31 - 1),
                               dtype=jnp.int32)
     ext = None
@@ -372,7 +384,7 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
         ext = external_bits_fn(dev_key, S, draws, B0 + pad)
     with jax.named_scope("sda.mask_share"):
         shares, mask_tot = pallas_round.fused_mask_share_combine(
-            x_cols, seed, f.sp, M_host, t, masked,
+            x_cols, S, seed, f.sp, M_host, t, masked,
             tile=tile, external_bits=ext, interpret=interpret,
             p_block=p_block, tree_fold=tree_fold_knob(),
         )

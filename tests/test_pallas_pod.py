@@ -56,6 +56,49 @@ def test_pod_pallas_matches_sum(mesh_shape, masking):
     )
 
 
+def _one_chip_pod(scheme, mask, pallas: bool):
+    """A pod on a 1x1 mesh: the local step sees every row."""
+    step = dict(use_pallas=True, pallas_interpret=True,
+                pallas_external_bits_fn=external_bits) if pallas else {}
+    return SimulatedPod(scheme, masking_scheme=mask, mesh=make_mesh(1, 1),
+                        **step)
+
+
+@pytest.mark.parametrize("rows", [1, 7, 8, 9, 64, 65, 300])
+def test_pod_pallas_fold_at_the_uint32_edge(rows):
+    """Every input p - 1: the participant fold in front of the kernel
+    holds the largest sums uint32 residues can make, for row counts on
+    and off every grouping a fold could use. Reference in Python ints."""
+    s = fast_scheme()
+    p = s.prime_modulus
+    pod = _one_chip_pod(s, FullMasking(p), pallas=True)
+    inputs = np.full((rows, 48), p - 1, dtype=np.int64)
+    out = np.asarray(pod.aggregate(inputs, jax.random.PRNGKey(rows)))
+    assert out.tolist() == [rows * (p - 1) % p] * 48
+
+
+@pytest.mark.parametrize("rows", [16, 13], ids=["rows16", "rows13"])
+@pytest.mark.parametrize("dim", [48, 50], ids=["dim48", "dim50"])
+@pytest.mark.parametrize("masking", ["none", "full", "chacha"])
+def test_pod_pallas_equals_xla_equals_plain_sum(masking, dim, rows):
+    """Fold first, lay out second gives what the XLA step and the plain
+    sum give, for every masking in the lattice, a dimension the packing
+    width does and does not divide, and a row count that is and is not a
+    multiple of 8."""
+    s = fast_scheme()
+    mask = {"none": None, "full": FullMasking(s.prime_modulus),
+            "chacha": ChaChaMasking(s.prime_modulus, dim, 128)}[masking]
+    rng = np.random.default_rng(rows * dim)
+    inputs = rng.integers(0, s.prime_modulus, size=(rows, dim))
+    expected = inputs.sum(axis=0) % s.prime_modulus
+    for pallas in (True, False):
+        pod = _one_chip_pod(s, mask, pallas)
+        assert pod.pallas_active == pallas
+        np.testing.assert_array_equal(
+            np.asarray(pod.aggregate(inputs, jax.random.PRNGKey(6))),
+            expected)
+
+
 @needs_devices(8)
 def test_streamed_pod_pallas_matches_sum_and_xla():
     s = fast_scheme()

@@ -59,7 +59,7 @@ def test_pallas_kernel_matches_xla_shares_same_bits():
     bits = external_bits(jax.random.PRNGKey(30), P, k + t, B)
 
     shares, mask_tot = fused_mask_share_combine(
-        x_cols, 0, sp, m_host, t, True,
+        fastfield.modsum32(x_cols, sp, axis=0), P, 0, sp, m_host, t, True,
         tile=tile, external_bits=bits, interpret=True,
     )
 
@@ -113,7 +113,8 @@ def test_pallas_combined_shares_equal_per_participant_sum():
     bits = external_bits(jax.random.PRNGKey(44), P, t, B)  # unmasked: t rows
 
     shares, _ = fused_mask_share_combine(
-        batch_columns(x, k), 0, sp, m_host, t, False,
+        batch_columns(fastfield.modsum32(x, sp, axis=0), k), P, 0, sp,
+        m_host, t, False,
         tile=128, external_bits=bits, interpret=True, p_block=2,
     )
     # per-participant path from the identical bits
@@ -192,12 +193,12 @@ def test_tree_fold_shares_match_slice_shares_same_bits():
     rng = np.random.default_rng(33)
     x = jnp.asarray(
         rng.integers(0, s.prime_modulus, size=(P, d)).astype(np.uint32))
-    x_cols = batch_columns(x, k)
+    x_sum = batch_columns(fastfield.modsum32(x, sp, axis=0), k)
     bits = external_bits(jax.random.PRNGKey(34), P, k + t, B)
     got = {}
     for tree in (False, True):
         got[tree] = fused_mask_share_combine(
-            x_cols, 0, sp, m_host, t, True, tile=tile, external_bits=bits,
+            x_sum, P, 0, sp, m_host, t, True, tile=tile, external_bits=bits,
             interpret=True, p_block=4, tree_fold=tree)
     np.testing.assert_array_equal(
         np.asarray(got[True][0]), np.asarray(got[False][0]))

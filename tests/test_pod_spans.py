@@ -3,7 +3,11 @@
 with ``pod.pad`` before and ``pod.strip`` after it, byte counters at the
 same boundaries, the residue pass and the kernel's relayout named on the
 device side, and ``timed_phase`` as one span that also feeds the phase
-registry. Toy sizes on the CPU; the Pallas step is interpreted."""
+registry. Toy sizes on the CPU; the Pallas step is interpreted, or
+lowered for the TPU where only the program's text is read."""
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,11 +28,13 @@ CHILDREN = ("pod.feed", "pod.dispatch", "pod.wait")
 STEPS = ("xla", "pallas")
 
 
-def _pod(step: str) -> SimulatedPod:
+def _pod(step: str, interpret: bool = True) -> SimulatedPod:
     t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
     scheme = PackedShamirSharing(3, 8, t, p, w2, w3)
-    pallas = dict(use_pallas=True, pallas_interpret=True,
-                  pallas_external_bits_fn=external_bits)
+    pallas = dict(use_pallas=True)
+    if interpret:
+        pallas.update(pallas_interpret=True,
+                      pallas_external_bits_fn=external_bits)
     return SimulatedPod(scheme, FullMasking(p),
                         **(pallas if step == "pallas" else {}))
 
@@ -157,9 +163,56 @@ def test_lowered_step_names_the_device_stages(step):
         jax.ShapeDtypeStruct((rows, dim), jnp.int64),
         jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text(debug_info=True)
     assert "sda.residues" in text
-    # the relayout is the kernel's: the XLA step has none
+    # the fold in front of the kernel and the relayout are the kernel's:
+    # the XLA step has neither
+    assert ("sda.fold" in text) == (step == "pallas")
     assert ("sda.relayout" in text) == (step == "pallas")
     assert ("sda.mask_share" in text) == (step == "pallas")
+
+
+def _tensor_sizes(line: str) -> list:
+    """Element counts of the ranked tensor types on one line of MLIR."""
+    return [math.prod(int(n) for n in dims[:-1].split("x"))
+            for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]+\d+>", line)]
+
+
+@pytest.mark.parametrize("dim", [120, 384], ids=["tile-padded", "on-tile"])
+def test_lowered_pallas_step_folds_before_the_layout_change(dim):
+    """The participants fold on the input's native layout; the
+    column-per-batch relayout and the pad to the kernel's column tile run
+    on the folded [k, B] block, never on [S, k, B]. The Pallas step is
+    the real one (on-core PRNG), lowered for the TPU from here: the
+    kernel is the ``tpu_custom_call`` and its internals stay out of the
+    text."""
+    pod = _pod("pallas", interpret=False)
+    rows = 5 * pod.mesh.devices.shape[0]            # 5 rows per device
+    assert pod.padded_shape(rows, dim) == (rows, dim)
+    text = pod.aggregate_fn(rows, dim).trace(
+        jax.ShapeDtypeStruct((rows, dim), jnp.int64),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+    ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    k, columns = pod.scheme.secret_count, dim // pod.scheme.secret_count
+    padded = -(-columns // 128) * 128
+    # nothing per participant is laid out: no [5, k, ...] tensor at all,
+    # and the kernel takes the folded secrets [k, B_pad]
+    assert f"tensor<5x{k}x" not in text
+    (kernel,) = [line for line in text.splitlines()
+                 if "@tpu_custom_call" in line]
+    operands = kernel[kernel.rindex(" : ("):kernel.rindex(") -> ")]
+    assert f"tensor<{k}x{padded}xui32>" in operands
+    assert max(_tensor_sizes(operands)) == k * padded
+    # one reduce folds the native [5, dim] block under sda.fold ...
+    assert re.search(r'= loc\("sda\.fold/reduce"', text)
+    assert f"across dimensions = [0] : (tensor<5x{dim}xui32>, " \
+        f"tensor<ui32>) -> tensor<{dim}xui32>" in text
+    # ... and the relayout touches nothing larger than the padded [k, B]
+    # block, which is smaller than the 5 rows
+    relayout = set(re.findall(r'(#loc\d+) = loc\("sda\.relayout/', text))
+    sizes = [size for line in text.splitlines()
+             if (at := re.search(r"loc\((#loc\d+)\)$", line))
+             and at.group(1) in relayout
+             for size in _tensor_sizes(line)]
+    assert 0 < max(sizes) <= k * padded < 5 * dim
 
 
 def test_residue_pass_is_named_on_the_int64_path_too():
