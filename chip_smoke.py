@@ -7,6 +7,9 @@ checks every result bit-exact against the plaintext sum:
 - pod, flagship shape (100 x 999,999, packed Shamir n=8, full mask) through
   ``SimulatedPod`` — the XLA step, the Mosaic-compiled fused Pallas step with
   the on-core PRNG, and ``StreamingAggregator`` with device ChaCha masks;
+  and the same shape under upstream's other scheme, additive 3-of-3 sharing
+  with ChaCha masks from 128-bit seeds, through ``SimulatedPod``'s XLA step
+  (the fused kernel serves no additive scheme);
 - pod, a model's full width — MobileLite's default update vector (~3.7M)
   through ``StreamedPod`` and ``ModelScaleRound`` with the fused kernel, tile
   width from the live ``memory_stats()["bytes_limit"]``;
@@ -82,11 +85,11 @@ def _summary(record: dict, **extra) -> dict:
 
 
 def pod_round(participants: int, dim: int, *, clerks: int = 8,
-              mask: str = "full", pallas: bool = False,
-              streaming: bool = False) -> dict:
+              sharing: str = "packed", mask: str = "full",
+              pallas: bool = False, streaming: bool = False) -> dict:
     """One pod round at the given shape, verified against the plain sum."""
     argv = ["--participants", participants, "--dim", dim, "--clerks", clerks,
-            "--mask", mask, "--verify"]
+            "--sharing", sharing, "--mask", mask, "--verify"]
     if pallas:
         argv.append("--pallas")
     if streaming:
@@ -166,6 +169,9 @@ def main() -> int:
         ("pod.flagship.pallas", lambda: pod_round(**flagship, pallas=True)),
         ("federated.lenet", lambda: federated_rounds("lenet", 8, 2)),
         ("pod.flagship.xla", lambda: pod_round(**flagship)),
+        ("pod.flagship.additive_chacha",
+         lambda: pod_round(**flagship, clerks=3, sharing="additive",
+                           mask="chacha")),
         ("pod.model_scale.mobilelite",
          lambda: model_scale_round("mobilelite")),
         ("pod.flagship.streaming_chacha",

@@ -51,11 +51,14 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--dim", type=int, default=9999)
     parser.add_argument("--clerks", type=int, default=8,
                         help="committee size (packed sharing needs "
-                             "3^a - 1: 2, 8, 26, ...; basic takes any)")
-    parser.add_argument("--sharing", choices=["packed", "basic"],
+                             "3^a - 1: 2, 8, 26, ...; basic and additive "
+                             "take any)")
+    parser.add_argument("--sharing", choices=["packed", "basic", "additive"],
                         default="packed",
-                        help="packed (NTT Shamir, k secrets/poly) or basic "
-                             "(classic t+1-of-n Shamir, any committee size)")
+                        help="packed (NTT Shamir, k secrets/poly), basic "
+                             "(classic t+1-of-n Shamir, any committee size) "
+                             "or additive (n-of-n, every clerk's row needed; "
+                             "the XLA step only)")
     parser.add_argument("--secrets-per-batch", type=int, default=None,
                         help="packed sharing only (default 3)")
     parser.add_argument("--modulus-bits", type=int, default=28)
@@ -1300,7 +1303,18 @@ def main(argv=None) -> int:
     from ..utils.backend import arm_compile_cache
 
     arm_compile_cache()
-    if args.sharing == "basic":
+    if args.sharing == "additive":
+        from ..protocol import AdditiveSharing
+
+        if args.pallas:
+            print("error: --pallas serves the Shamir schemes; additive "
+                  "sharing runs the XLA step", file=sys.stderr)
+            return 1
+        # a ring is all additive sharing needs; the prime basic Shamir
+        # would get keeps the round on the same uint32 path
+        p = numtheory.find_prime_with_orders(1, 1, args.modulus_bits)
+        scheme = AdditiveSharing(args.clerks, p)
+    elif args.sharing == "basic":
         from ..protocol import BasicShamirSharing
 
         if args.secrets_per_batch is not None:

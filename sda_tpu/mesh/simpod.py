@@ -223,8 +223,13 @@ def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
             skey = key
             gids = pid_base + jnp.arange(S)
             seeds = _chacha_seed_words(round_key, gids, masking.seed_bitsize)
-            draws = chacha_jax.stream_u64_at(seeds, d_block0, dimension=d_loc)
-            masks = f.from_u64(draws)
+            # the cipher (block function + word pairing) and the 64-bit
+            # reduction are the stage's two costs: a scope each, so the
+            # device trace tells them apart (docs/observability.md)
+            with jax.named_scope("sda.mask.chacha"):
+                draws = chacha_jax.stream_u64_at(seeds, d_block0, dimension=d_loc)
+            with jax.named_scope("sda.mask.reduce"):
+                masks = f.from_u64(draws)
         else:
             return x, None, key
         masked = f.add(x, masks)
@@ -395,6 +400,13 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
         return shares, sharing.unbatch_columns(mask_tot[:, :B0], d_loc)
 
 
+def _scan_rows(rows: int, chunk: int) -> Tuple[int, int]:
+    """(block size, rows after the pad to whole blocks) of the XLA step's
+    participant scan over ``rows`` local rows."""
+    chunk = max(1, min(int(chunk), rows))
+    return chunk, -(-rows // chunk) * chunk
+
+
 def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
                   pid0, dblk0, chunk: int):
     """[P, d] canonical residues -> (acc_shares [n, B], acc_mask [d]|None).
@@ -406,8 +418,8 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
     Zero-padded rows aggregate as zero and their masks cancel.
     """
     P, d = x.shape
-    chunk = max(1, min(int(chunk), P))
-    pad = (-P) % chunk
+    chunk, padded_rows = _scan_rows(P, chunk)
+    pad = padded_rows - P
     if pad:
         x = jnp.concatenate([x, jnp.zeros((pad, d), x.dtype)], axis=0)
     nblk = x.shape[0] // chunk
@@ -449,6 +461,19 @@ def _reconstruct_stage(scheme, f: FieldOps, L_host, gathered, d_loc: int):
                 prime=scheme.prime_modulus, dimension=d_loc,
             )
         return f.sum(gathered, axis=0)  # additive: plain share sum
+
+
+def _chacha_blocks(masking, pallas_active: bool, rows: int, chunk: int,
+                   d_total: int, p_shards: int) -> int:
+    """ChaCha20 blocks one round asks of the mesh (8 u64 draws a block), 0
+    under any other masking. ``rows`` per p shard; the XLA step expands
+    whole scan blocks (``_scan_combine`` pads the rows to ``chunk``), the
+    Pallas step the rows as they are (``_pallas_stage``)."""
+    if not isinstance(masking, ChaChaMasking):
+        return 0
+    if not pallas_active:
+        rows = _scan_rows(rows, chunk)[1]
+    return p_shards * rows * (d_total // 8)
 
 
 def _dim_grain(scheme, masking) -> int:
@@ -511,6 +536,22 @@ class SimulatedPod:
     Committee size must be divisible by the ``p`` axis; participant and
     dimension counts are auto-padded to the mesh/scheme grain (zero rows
     and components aggregate as zero; padding is stripped from the output).
+
+    Two local steps compute the same round. The **XLA step** is the
+    default (``use_pallas=None`` without ``SDA_PALLAS=1``, or ``False``)
+    and serves every scheme and masking: ``_scan_combine`` streams the
+    rows in blocks of ``scan_chunk`` through ``_mask_stage`` and
+    ``_share_sum_stage``. The **fused Pallas kernel** (``use_pallas=True``)
+    serves packed and basic Shamir over a Solinas prime with
+    none/full/ChaCha masking (``_pallas_supported``); asked for on
+    additive sharing or a non-Solinas modulus it raises, so an
+    additive-sharing aggregation always runs the XLA step.
+    ``pallas_active`` says which step this pod took.
+
+    Under ChaCha masking every dispatch of the round counts
+    ``mesh.mask.chacha_calls`` and ``mesh.mask.chacha_blocks`` (the
+    ChaCha20 blocks asked of the mesh, from static shapes); a pod with
+    any other masking counts nothing.
     """
 
     def __init__(
@@ -640,9 +681,15 @@ class SimulatedPod:
         # cost analysis for the roofline block — one profile entry for the
         # whole SPMD round regardless of how many shapes get built. Every
         # holder of the callable (aggregate(), aggregate_fn() callers,
-        # multihost) gets the pod.dispatch span around its calls
+        # multihost) gets the pod.dispatch span around its calls, and under
+        # ChaCha masking the mask counters: static amounts, settled here
+        blocks = _chacha_blocks(self.masking, self.pallas_active,
+                                P_total // p_shards, self.scan_chunk,
+                                d_total, p_shards)
+        counts = {"mesh.mask.chacha_calls": 1,
+                  "mesh.mask.chacha_blocks": blocks} if blocks else None
         return devprof.instrument("mesh.simpod.round", jax.jit(fn),
-                                  span="pod.dispatch")
+                                  span="pod.dispatch", counts=counts)
 
     def padded_shape(self, P_total: int, d_total: int) -> Tuple[int, int]:
         p_shards, d_shards = self.mesh.devices.shape

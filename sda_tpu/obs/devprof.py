@@ -362,7 +362,8 @@ def _record_retrace(name: str, sig: Tuple, compiles: int) -> None:
             _trace.add_event("xla.retrace", **attrs)
 
 
-def instrument(name: str, fn, span: Optional[str] = None):
+def instrument(name: str, fn, span: Optional[str] = None,
+               counts: Optional[Dict[str, int]] = None):
     """Wrap a jitted callable with the compiled-shape registry.
 
     Repeated ``instrument`` calls with the same ``name`` (e.g. the
@@ -376,6 +377,12 @@ def instrument(name: str, fn, span: Optional[str] = None):
     of the call, registry bookkeeping included, on every path that holds
     the wrapper. A retrace event then lands on that span. Calls from
     inside an outer trace open none (they dispatch nothing).
+
+    ``counts`` maps counter names to what one top-level call adds to each
+    (``utils/metrics``), counted where the span opens: static amounts the
+    builder of the step knows from its shapes. Whether a call counts is
+    settled here, when the step is wrapped; a step without counts runs the
+    same wrapper as before.
     """
     profile(name)  # eager registration; the wrapper re-resolves per call
     # compile accounting and cost capture only make sense for jit-like
@@ -423,6 +430,15 @@ def instrument(name: str, fn, span: Optional[str] = None):
                 _record_retrace(name, sig, compiles_before)
         return out
 
+    run = dispatch
+    if counts:
+        amounts = tuple(counts.items())
+
+        def run(args, kwargs):
+            for counter, amount in amounts:
+                metrics.count(counter, amount)
+            return dispatch(args, kwargs)
+
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         if _is_traced(args, kwargs):
@@ -431,9 +447,9 @@ def instrument(name: str, fn, span: Optional[str] = None):
             with jax.named_scope(name):
                 return fn(*args, **kwargs)
         if span is None:
-            return dispatch(args, kwargs)
+            return run(args, kwargs)
         with _trace.span(span):
-            return dispatch(args, kwargs)
+            return run(args, kwargs)
 
     wrapper.__wrapped__ = fn
     for attr in ("lower", "_cache_size", "trace", "eval_shape"):

@@ -26,6 +26,18 @@ def test_streaming_chacha_phase_exact():
     assert result["exact"] is True and result["mode"] == "streaming"
 
 
+def test_additive_chacha_phase_exact_on_the_xla_step():
+    result = chip_smoke.pod_round(8, 99, clerks=3, sharing="additive",
+                                  mask="chacha")
+    assert result["exact"] is True and result["pallas"] is False
+    assert result["mode"].startswith("simpod mesh")
+
+
+def test_additive_sharing_refuses_the_kernel_before_any_round():
+    with pytest.raises(chip_smoke.PhaseFailed, match="rc=1"):
+        chip_smoke.pod_round(8, 99, clerks=3, sharing="additive", pallas=True)
+
+
 def test_pallas_pod_phase_refuses_a_cpu():
     with pytest.raises(chip_smoke.PhaseFailed, match="rc=1"):
         chip_smoke.pod_round(8, 99, pallas=True)
