@@ -47,12 +47,9 @@ def _quarter(s, a, b, c, d):
     s[b] = _rotl(s[b] ^ s[c], 7)
 
 
-@functools.partial(jax.jit, static_argnames=("nblocks",))
-def chacha_block_words(seed_words, counter0, *, nblocks: int):
-    """[nblocks, 16] uint32 keystream; mirrors chacha.chacha_block_words.
-
-    seed_words: [8] uint32 key (zero-padded); counter0: scalar int32/uint32.
-    """
+def _block_word_arrays(seed_words, counter0, nblocks: int):
+    """The block function as it computes: sixteen ``[nblocks]`` uint32
+    arrays, word ``i`` of every block in array ``i`` (block index minor)."""
     counters = jnp.asarray(counter0, _U32) + jnp.arange(nblocks, dtype=_U32)
     zeros = jnp.zeros((nblocks,), _U32)
     init = (
@@ -70,8 +67,63 @@ def chacha_block_words(seed_words, counter0, *, nblocks: int):
         _quarter(state, 1, 6, 11, 12)
         _quarter(state, 2, 7, 8, 13)
         _quarter(state, 3, 4, 9, 14)
-    words = [s + i for s, i in zip(state, init)]
-    return jnp.stack(words, axis=1)  # [nblocks, 16]
+    return [s + i for s, i in zip(state, init)]
+
+
+@functools.partial(jax.jit, static_argnames=("nblocks",))
+def chacha_block_words(seed_words, counter0, *, nblocks: int):
+    """[nblocks, 16] uint32 keystream; mirrors chacha.chacha_block_words.
+
+    seed_words: [8] uint32 key (zero-padded); counter0: scalar int32/uint32.
+    """
+    return jnp.stack(_block_word_arrays(seed_words, counter0, nblocks), axis=1)
+
+
+def _paired_u64(words, *, even_is_low: bool):
+    """Sixteen per-word arrays -> the blocks' 64-bit draws, word-major
+    ``[8, nblocks]``: draw ``j`` of a block pairs words ``2j`` and ``2j+1``.
+    The halves are picked from the Python list (no device op); which of the
+    two is the low half is the stream's (V1: even, rand 0.3: odd). One
+    stack of all sixteen, halves as its two contiguous slabs: stacked
+    apart, XLA's CPU fusion clones the whole cipher into each stack."""
+    halves = jnp.stack(words[0::2] + words[1::2]).astype(jnp.uint64)
+    even, odd = halves[:8], halves[8:]
+    low, high = (even, odd) if even_is_low else (odd, even)
+    return (high << jnp.uint64(32)) | low
+
+
+def element_order(x):
+    """Word-major ``[..., 8, nblocks]`` -> element order ``[..., 8 * nblocks]``
+    (element ``e = 8 * block + pair``), the order of the host stream.
+
+    An interleave of eight rows along the minor axis. Written as
+    ``swapaxes(-1, -2).reshape`` the TPU makes it a copy into an array whose
+    minor dimension 8 is padded to 128 lanes, a flatten and a row loop (a
+    quarter of the round it was measured in: PERF.md, PR 30). So the
+    permutation goes through the matrix unit, which a round of integer
+    arithmetic leaves idle: each tile of 8 x 128 words (pair j, block r)
+    times the one-hot ``[(j, r), 8r + j]`` is the tile's 1024 words in
+    element order. One matmul per byte of the words: a byte is exact in
+    bfloat16, every output is one product by 1 plus zeros, and the float32
+    accumulator holds it exactly -- on any backend, for any integer dtype
+    whose values are non-negative.
+    """
+    lead, (pairs, nblocks) = x.shape[:-2], x.shape[-2:]
+    tiles = -(-nblocks // 128)
+    if tiles * 128 != nblocks:  # whole lane tiles; the tail is cut below
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, tiles * 128 - nblocks)])
+    x = x.reshape(lead + (pairs, tiles, 128))
+    target = pairs * jnp.arange(128)[None, :] + jnp.arange(pairs)[:, None]
+    onehot = jax.nn.one_hot(target, pairs * 128, dtype=jnp.bfloat16)  # [pairs, 128, 128 pairs]
+    out = None
+    for byte in range(x.dtype.itemsize):
+        shift = jnp.asarray(8 * byte, x.dtype)
+        plane = ((x >> shift) & jnp.asarray(0xFF, x.dtype)).astype(jnp.bfloat16)
+        moved = jnp.einsum("...jqr,jrl->...ql", plane, onehot,
+                           preferred_element_type=jnp.float32)
+        moved = moved.astype(x.dtype) << shift
+        out = moved if out is None else out | moved
+    return out.reshape(lead + (tiles * pairs * 128,))[..., :pairs * nblocks]
 
 
 @functools.partial(jax.jit, static_argnames=("dimension", "modulus", "prg"))
@@ -85,24 +137,35 @@ def _expand_no_reject(seed_words, *, dimension: int, modulus: int, prg: str):
     """
     # match the host oracle's first-iteration overdraw: ceil(d/8)+1 blocks
     nblocks = max(1, -(-dimension // 8) + 1)
-    words = chacha_block_words(seed_words, 0, nblocks=nblocks).reshape(-1)
-    even = words[0::2].astype(jnp.uint64)
-    odd = words[1::2].astype(jnp.uint64)
+    if prg not in (chacha.CHACHA_PRG_V1, chacha.CHACHA_PRG_RAND03):
+        raise ValueError(f"unknown ChaCha PRG {prg!r}")
+    words = _block_word_arrays(seed_words, 0, nblocks)
+    v = element_order(_paired_u64(words, even_is_low=prg == chacha.CHACHA_PRG_V1))
+    first = v[:dimension]
     if prg == chacha.CHACHA_PRG_RAND03:
-        v = (even << jnp.uint64(32)) | odd
         u64_max = (1 << 64) - 1
         zone_excl = jnp.uint64(u64_max - u64_max % modulus)
-        first = v[:dimension]
         any_rejected = jnp.any(first >= zone_excl)
-    elif prg == chacha.CHACHA_PRG_V1:
-        v = (odd << jnp.uint64(32)) | even
-        zone = jnp.uint64(((1 << 64) // modulus) * modulus - 1)
-        first = v[:dimension]
-        any_rejected = jnp.any(first > zone)
     else:
-        raise ValueError(f"unknown ChaCha PRG {prg!r}")
+        zone = jnp.uint64(((1 << 64) // modulus) * modulus - 1)
+        any_rejected = jnp.any(first > zone)
     mask = jnp.mod(first, jnp.uint64(modulus)).astype(jnp.int64)
     return mask, any_rejected
+
+
+def stream_u64_words_at(seed_words, counter0, *, nblocks: int):
+    """[S, 8] uint32 seeds -> [S, 8, nblocks] uint64: the CHACHA_PRG_V1
+    draws of blocks [counter0, counter0 + nblocks) in the layout the block
+    function produces them, word-major with the block index minor:
+    ``out[s, j, b]`` is draw ``8 * (counter0 + b) + j`` of seed ``s``'s
+    stream. No layout change: whatever is elementwise in the draws (the
+    reduction mod m) runs on this form, and ``element_order`` puts the
+    result -- one narrow plane instead of the draws' two -- in the stream's
+    order. ``counter0`` may be traced."""
+    return jax.vmap(
+        lambda sw: _paired_u64(
+            _block_word_arrays(sw, counter0, nblocks), even_is_low=True)
+    )(seed_words)
 
 
 def stream_u64_at(seed_words, counter0, *, dimension: int):
@@ -116,18 +179,14 @@ def stream_u64_at(seed_words, counter0, *, dimension: int):
     under shard_map). Pod mode reduces draws mod m WITHOUT the host spec's
     rejection step — masks cancel within the round, so the aggregate is
     exact regardless; only the federated wire path needs rejection parity.
+
+    The element-order view of ``stream_u64_words_at``: the pod's mask stage
+    takes the word-major form and orders its residues (simpod._mask_stage).
     """
     if dimension % 8:
         raise ValueError("dimension must be a multiple of 8 (one ChaCha block)")
-    nblocks = dimension // 8
-
-    def one(sw):
-        words = chacha_block_words(sw, counter0, nblocks=nblocks).reshape(-1)
-        lo = words[0::2].astype(jnp.uint64)
-        hi = words[1::2].astype(jnp.uint64)
-        return (hi << jnp.uint64(32)) | lo
-
-    return jax.vmap(one)(seed_words)
+    return element_order(
+        stream_u64_words_at(seed_words, counter0, nblocks=dimension // 8))
 
 
 def _modsum_i64(x, modulus: int, axis: int = 0):

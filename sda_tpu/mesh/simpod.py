@@ -223,13 +223,22 @@ def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
             skey = key
             gids = pid_base + jnp.arange(S)
             seeds = _chacha_seed_words(round_key, gids, masking.seed_bitsize)
-            # the cipher (block function + word pairing) and the 64-bit
-            # reduction are the stage's two costs: a scope each, so the
-            # device trace tells them apart (docs/observability.md)
+            if d_loc % 8:
+                raise ValueError(
+                    "dimension must be a multiple of 8 (one ChaCha block)")
+            # The draws keep the block function's word-major layout
+            # [S, 8, d_loc/8] through pairing and reduction, both
+            # elementwise; only the residues -- one uint32 plane, not the
+            # draws' two -- are put in element order, once. A scope each,
+            # so the device trace tells cipher, reduction and layout change
+            # apart (docs/observability.md)
             with jax.named_scope("sda.mask.chacha"):
-                draws = chacha_jax.stream_u64_at(seeds, d_block0, dimension=d_loc)
+                draws = chacha_jax.stream_u64_words_at(
+                    seeds, d_block0, nblocks=d_loc // 8)
             with jax.named_scope("sda.mask.reduce"):
                 masks = f.from_u64(draws)
+            with jax.named_scope("sda.mask.relayout"):
+                masks = chacha_jax.element_order(masks)
         else:
             return x, None, key
         masked = f.add(x, masks)

@@ -144,9 +144,11 @@ def test_the_xla_step_names_the_cipher_and_the_reduction_only_under_chacha(maski
     assert "sda.mask" in text and "sda.share" in text
     assert ("sda.mask.chacha" in text) == (masking == "chacha")
     assert ("sda.mask.reduce" in text) == (masking == "chacha")
-    # nested: a trace's sda.mask total still holds both
+    assert ("sda.mask.relayout" in text) == (masking == "chacha")
+    # nested: a trace's sda.mask total still holds all three
     if masking == "chacha":
-        assert "sda.mask/sda.mask.chacha" in text and "sda.mask/sda.mask.reduce" in text
+        assert all(f"sda.mask/sda.mask.{part}" in text
+                   for part in ("chacha", "reduce", "relayout"))
 
 
 # -- (e) the counters -------------------------------------------------------------------

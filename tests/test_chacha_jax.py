@@ -75,3 +75,156 @@ def test_native_oracle_agreement():
     c = native.chacha_expand_mask(seed, dimension, modulus, prg=chacha.CHACHA_PRG_V1)
     np.testing.assert_array_equal(a, b)
     np.testing.assert_array_equal(a, c)
+
+
+# -- the word-major stream and the pod's mask stage (PR 30) ----------------------
+#
+# The pod's mask stage keeps the draws in the layout the block function
+# produces (word-major [S, 8, nblocks]) until they are residues, and puts
+# only those in element order. Every mask is still the draw it was:
+# mask[s, 8b + j] = ((w[2j+1] << 32) | w[2j]) mod p of block b.
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import PartitionSpec  # noqa: E402
+
+from sda_tpu.fields import numtheory  # noqa: E402
+from sda_tpu.fields.ops import FieldOps  # noqa: E402
+from sda_tpu.mesh import simpod  # noqa: E402
+from sda_tpu.protocol import ChaChaMasking, PackedShamirSharing  # noqa: E402
+
+from util import external_bits  # noqa: E402
+
+_SEEDS = {
+    "1word": [[0x9E3779B9], [7]],
+    "4words": [[1, 2, 3, 4], [0xDEADBEEF, 0x12345678, 0, 0xFFFFFFFF]],
+    "8words": [[0xFFFFFFFF] * 8, list(range(11, 19))],
+}
+
+
+def _seed_matrix(seeds) -> np.ndarray:
+    matrix = np.zeros((len(seeds), 8), dtype=np.uint32)
+    for row, seed in enumerate(seeds):
+        matrix[row, :len(seed)] = seed
+    return matrix
+
+
+def _host_stream(seed, block0: int, dimension: int) -> np.ndarray:
+    """The host oracle's draws [8 * block0, 8 * block0 + dimension)."""
+    words = chacha.chacha_block_words(seed, block0, dimension // 8)
+    words = words.reshape(-1).astype(np.uint64)
+    return (words[1::2] << np.uint64(32)) | words[0::2]
+
+
+@pytest.mark.parametrize("dimension", [8, 96, 1000])
+@pytest.mark.parametrize("window", ["counter0", "traced-window"])
+@pytest.mark.parametrize("words", list(_SEEDS))
+def test_word_major_stream_in_element_order_is_the_stream(words, window, dimension):
+    seeds = _SEEDS[words]
+    matrix = jnp.asarray(_seed_matrix(seeds))
+    nblocks = dimension // 8
+    block0 = 0 if window == "counter0" else 3 * nblocks + 5
+
+    def both(counter0):
+        return (chacha_jax.stream_u64_words_at(matrix, counter0, nblocks=nblocks),
+                chacha_jax.stream_u64_at(matrix, counter0, dimension=dimension))
+
+    # traced, as axis_index('d') * blocks_per_shard is under shard_map
+    wordmajor, stream = jax.jit(both)(block0) if block0 else both(0)
+    assert wordmajor.shape == (len(seeds), 8, nblocks) and wordmajor.dtype == jnp.uint64
+    ordered = np.asarray(chacha_jax.element_order(wordmajor))
+    np.testing.assert_array_equal(ordered, np.asarray(stream))
+    host = np.stack([_host_stream(seed, block0, dimension) for seed in seeds])
+    np.testing.assert_array_equal(ordered, host)
+    # draw j of block b sits at [j, b]: no transpose hides in the pairing
+    np.testing.assert_array_equal(
+        np.asarray(wordmajor)[:, 3, nblocks - 1], host[:, 8 * (nblocks - 1) + 3])
+
+
+@pytest.mark.parametrize("shape", [(8, 1), (8, 37), (3, 8, 128), (2, 5, 8, 130)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("dtype,top", [(np.uint32, 1 << 32), (np.uint64, 1 << 64),
+                                       (np.int64, 1 << 62)],
+                         ids=["uint32", "uint64", "int64"])
+def test_element_order_is_the_interleave_bit_for_bit(dtype, top, shape):
+    """The one-hot matmul moves every byte of every word where
+    ``swapaxes(-1, -2).reshape`` puts it: full-range words, block counts on
+    and off the 128-lane tile, any leading dimensions."""
+    rng = np.random.default_rng(len(shape) * 1000 + shape[-1])
+    words = rng.integers(0, top, size=shape, dtype=np.uint64).astype(dtype)
+    words[..., 0, 0], words[..., -1, -1] = 0, top - 1  # both ends of the range
+    got = np.asarray(chacha_jax.element_order(jnp.asarray(words)))
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(
+        got, np.swapaxes(words, -1, -2).reshape(shape[:-2] + (-1,)))
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs 2 virtual devices")
+@pytest.mark.parametrize("step", ["xla", "pallas-interpret"])
+def test_mask_stage_masks_are_the_stream_mod_p_row_for_row_on_a_sharded_dim(step):
+    """Two 'd' shards, each expanding its own window of every row's stream
+    at a traced block counter, as ``SimulatedPod._local_round`` calls the
+    stage. Zero inputs, so what comes out is the masks."""
+    t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
+    scheme = PackedShamirSharing(3, 8, t, p, w2, w3)  # the kernel's: a Solinas prime
+    rows, dim, first_id = 5, 96, 7
+    d_loc = dim // 2
+    field = FieldOps.create(p)
+    masking = ChaChaMasking(p, dim, 128)
+    round_key, dev_key = jax.random.PRNGKey(11), jax.random.PRNGKey(2)
+    matrices = simpod._build_matrices(scheme)
+
+    def local(x):
+        block0 = jax.lax.axis_index("d") * (d_loc // 8)
+        if step == "xla":
+            masked, mask_sum, _ = simpod._mask_stage(
+                masking, field, x, dev_key, round_key,
+                pid_base=first_id, d_block0=block0)
+            return masked, mask_sum
+        # the kernel's step returns the mask sum alone: one row a call
+        sums = [simpod._pallas_stage(
+            scheme, field, matrices[0], masking, x[row:row + 1], dev_key,
+            round_key=round_key, pid_base=first_id + row, d_block0=block0,
+            interpret=True, external_bits_fn=external_bits)[1]
+            for row in range(rows)]
+        _, mask_sum = simpod._pallas_stage(
+            scheme, field, matrices[0], masking, x, dev_key,
+            round_key=round_key, pid_base=first_id, d_block0=block0,
+            interpret=True, external_bits_fn=external_bits)
+        return jnp.stack(sums), mask_sum
+
+    sharded = simpod._shard_map(
+        local, mesh=simpod.make_mesh(1, 2), in_specs=PartitionSpec(None, "d"),
+        out_specs=(PartitionSpec(None, "d"), PartitionSpec("d")))
+    masks, mask_sum = jax.jit(sharded)(jnp.zeros((rows, dim), field.dtype))
+
+    seeds = simpod._chacha_seed_words(round_key, first_id + jnp.arange(rows), 128)
+    want = np.asarray(chacha_jax.stream_u64_at(seeds, 0, dimension=dim)) % np.uint64(p)
+    np.testing.assert_array_equal(np.asarray(masks).astype(np.uint64), want)
+    np.testing.assert_array_equal(
+        np.asarray(mask_sum).astype(np.uint64), want.sum(axis=0) % np.uint64(p))
+
+
+def test_mask_stage_reads_the_keystream_with_no_gather():
+    """Until PR 30 the stage paired the cipher's words with two strided
+    reads of the flat keystream, which the TPU runs as gathers over every
+    draw (two thirds of the round, PERF.md). Pairing the per-word arrays
+    needs none; the only gathers left read the [S, 8] seed words."""
+    p, rows, dim = 536870233, 8, 96
+    field = FieldOps.create(p)
+
+    def stage(x, key, round_key, block0):
+        return simpod._mask_stage(ChaChaMasking(p, dim, 128), field, x, key,
+                                  round_key, pid_base=0, d_block0=block0)[:2]
+
+    text = jax.jit(stage).lower(
+        jnp.zeros((rows, dim), field.dtype), jax.random.PRNGKey(0),
+        jax.random.PRNGKey(1), jnp.int32(0)).as_text(debug_info=True)
+    assert "sda.mask/sda.mask.relayout" in text
+    keystream = rows * dim * 2  # uint32 words of the block's draws
+    gathers = [line for line in text.splitlines() if "stablehlo.gather" in line]
+    for line in gathers:
+        # "... : (tensor<8x8xui32>, tensor<...xi32>) -> ..." : the operand
+        operand = line.split(" : (tensor<")[1].split(">")[0]
+        sizes = [int(n) for n in operand.split("x")[:-1]]
+        assert int(np.prod(sizes)) < keystream, line
