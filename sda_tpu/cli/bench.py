@@ -1,26 +1,19 @@
-"""`sda-bench` — bench runner front-end + regression gate.
+"""`sda-bench` — the regression gate's front-end.
 
-Two jobs:
+``sda-bench --check [records...]`` is the regression gate
+(``sda_tpu.obs.regress``): compare the newest committed bench record
+against its trailing window with noise-aware thresholds and exit
+nonzero on a confirmed regression. Defaults to the repo's
+``BENCH_r*.json`` trajectory. ``--advisory`` reports without gating
+(the CI CPU rung), ``--json`` emits the verdict as one JSON line.
 
-- ``sda-bench --check [records...]`` — the regression gate
-  (``sda_tpu.obs.regress``): compare the newest committed bench record
-  against its trailing window with noise-aware thresholds and exit
-  nonzero on a confirmed regression. Defaults to the repo's
-  ``BENCH_r*.json`` trajectory. ``--advisory`` reports without gating
-  (the CI CPU rung), ``--json`` emits the verdict as one JSON line.
-- ``sda-bench --run`` — invoke the repo's ``bench.py`` driver benchmark
-  in a subprocess (it owns its own rung/deadline robustness) and forward
-  its single JSON line.
-
-Every future perf PR is judged by this gate, so the flags mirror
-``python -m sda_tpu.obs.regress`` exactly — one implementation, two
-spellings.
+The flags mirror ``python -m sda_tpu.obs.regress`` exactly — one
+implementation, two spellings. Speed on the chip is measured by
+``python3 benchmarks/chip/run.py`` (``PERF.md``), not here.
 """
 
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 from typing import List, Optional
 
@@ -31,26 +24,12 @@ def build_parser():
     parser = regress.build_parser()
     parser.prog = "sda-bench"
     parser.add_argument("--check", action="store_true",
-                        help="run the regression gate (default action)")
-    parser.add_argument("--run", action="store_true",
-                        help="run the repo's bench.py driver benchmark "
-                             "instead and forward its JSON line")
+                        help="run the regression gate (the only action)")
     return parser
 
 
-def _run_bench() -> int:
-    bench = os.path.join(regress.repo_root(), "bench.py")
-    if not os.path.exists(bench):
-        print(f"bench driver not found at {bench}", file=sys.stderr)
-        return 2
-    return subprocess.run([sys.executable, bench]).returncode
-
-
 def main(argv: Optional[List[str]] = None) -> int:
-    args = build_parser().parse_args(argv)
-    if args.run:
-        return _run_bench()
-    return regress.run(args)
+    return regress.run(build_parser().parse_args(argv))
 
 
 if __name__ == "__main__":

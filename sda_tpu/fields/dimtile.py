@@ -4,11 +4,10 @@ A full-width program's intermediates grow with d and may spill where a
 narrower one stays fused (benchmarks/ROOFLINE.md "Width" — whether the
 full-width round is superlinear in d on the chip is not measured).
 Scanning fixed-width tiles bounds every tile's live set and makes round
-cost affine in d by construction. Shared by the
-XLA (mesh.single_chip_round) and Pallas (fields.pallas_round) drivers,
-and — via :func:`tile_plan` — by the model-scale sharded driver
-(mesh/devscale.py), so every tiled lane slices the dimension with ONE
-arithmetic.
+cost affine in d by construction. Shared by the XLA round
+(mesh.single_chip_round) and the model-scale sharded driver
+(mesh/devscale.py: the scan, and :func:`tile_plan` for its host-driven
+lane), so every tiled lane slices the dimension with ONE arithmetic.
 """
 
 from __future__ import annotations

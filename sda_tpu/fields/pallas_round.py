@@ -26,15 +26,15 @@ an input — it exists so the arithmetic is bit-checkable under
 also what a protocol-grade deployment would use to inject threefry/ChaCha
 streams (reference mask PRGs: client/src/crypto/masking/*.rs).
 
-Callers: ``mesh.simpod._pallas_stage`` (the pod, streamed and model-scale
-steps, with ``use_pallas=True`` or ``SDA_PALLAS=1``) and
-``single_chip_round_pallas`` below (``bench.py``). The XLA steps stay the
-default of the library; the chip benchmark's cells all run this kernel.
+Callers: ``mesh.simpod._pallas_stage``, the one stage around the kernel
+(the pod, streamed and model-scale steps, with ``use_pallas=True``). This
+module exports the kernel and its column-tile rule and drives no round.
+The XLA steps stay the default of the library; the chip benchmark's packed
+cells all run this kernel.
 """
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Optional
 
@@ -45,9 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import fastfield
-from .fastfield import SolinasPrime, canon32, modadd32, modsub32, mulmod32_const
-from . import numtheory
-from .sharing import batch_columns, unbatch_columns
+from .fastfield import SolinasPrime, canon32, modadd32, mulmod32_const
 
 _U32 = jnp.uint32
 
@@ -60,22 +58,17 @@ def _uniform_from_bits(hi_bits, lo_bits, sp: SolinasPrime):
     return modadd32(mulmod32_const(hi, r32, sp), lo, sp)
 
 
-def _share_rows_const(values_rows, m_host_row, sp: SolinasPrime):
-    """Sum_j M[i][j]*values[j] for one output row, all constants.
+def column_tile(B0: int) -> int:
+    """Lane-dim tile for ``B0`` batch columns, whole 128-lane vregs: large
+    tiles amortize the grid-step overhead, small B0 avoids padding waste.
+    The caller pads the column axis to a whole number of tiles."""
+    return 2048 if B0 >= 2048 else max(128, -(-B0 // 128) * 128)
 
-    Kept for reference/AB-testing: operates on [1, tile] row slices, which
-    uses 1 of 8 VPU sublanes; the default kernel path calls
-    fastfield.modmatmul32 on the full [m2-1, tile] block instead.
-    """
-    acc = None
-    for coeff, row in zip(m_host_row, values_rows):
-        if coeff % sp.p == 0:
-            continue
-        term = mulmod32_const(row, int(coeff), sp)
-        acc = term if acc is None else modadd32(acc, term, sp)
-    if acc is None:
-        acc = jnp.zeros_like(values_rows[0])
-    return acc
+
+def _participant_block(p_block: int, P: int) -> int:
+    """Participants folded per loop step: ``p_block`` clamped to P, shrunk
+    to a divisor of P (the accept-any-P contract: no draw is padded)."""
+    return math.gcd(max(1, min(int(p_block), P)), P)
 
 
 def _participant_tile(pb: int, rows_per_participant: int, tile: int) -> int:
@@ -88,17 +81,6 @@ def _participant_tile(pb: int, rows_per_participant: int, tile: int) -> int:
     re-blocking the participant axis is ROADMAP queue A."""
     cap = max(1, 3_000_000 // (rows_per_participant * tile * 4))
     return max(pb, (cap // pb) * pb)
-
-
-def _balanced_tiling(P: int, pb: int, tile_cap: int):
-    """(p_tile, P_eff): spread P over equal tiles instead of padding to a
-    whole multiple of tile_cap (P=113 at cap 112 pads to 128, not 224)."""
-    if P <= tile_cap:
-        p_tile = -(-P // pb) * pb
-        return p_tile, p_tile
-    ntiles = -(-P // tile_cap)
-    p_tile = -(-P // (ntiles * pb)) * pb
-    return p_tile, ntiles * p_tile
 
 
 def fused_mask_share_combine(
@@ -151,9 +133,7 @@ def fused_mask_share_combine(
         raise ValueError(f"participants={P} must be at least 1")
     if B % tile:
         raise ValueError(f"B={B} must be divisible by tile={tile}")
-    pb = max(1, min(int(p_block), P))
-    if P % pb:  # keep the accept-any-P contract: shrink to a divisor
-        pb = math.gcd(pb, P)
+    pb = _participant_block(p_block, P)
     draws = (k + t) if masked else t
     internal = external_bits is None
     if not internal and external_bits.shape != (P, 2 * draws, B):
@@ -343,101 +323,3 @@ def fused_mask_share_combine(
     # in the kernel is explicitly uint32/int32 so semantics are unchanged.
     with jax.enable_x64(False):
         return call(*args)
-
-
-def single_chip_round_pallas(
-    sharing_scheme,
-    masking_scheme=None,
-    tile: Optional[int] = None,
-    interpret: bool = False,
-    external_bits_fn=None,
-    p_block: int = 16,
-    p_tile: Optional[int] = None,
-    dim_tile: Optional[int] = None,
-    tree_fold: bool = False,
-):
-    """Drop-in alternative to mesh.single_chip_round on the fused kernel.
-
-    Requires a Solinas prime. external_bits_fn(key, P, draws, B) -> uint32
-    bits array enables deterministic/interpret-mode testing. ``dim_tile``
-    processes the dimension in fixed-width tiles via ``lax.scan`` — one
-    complete kernel round per tile — mirroring mesh.single_chip_round's
-    dim-tiled schedule (the full-width program measured superlinear in d
-    on chip; see that docstring).
-    """
-    from ..protocol import FullMasking, NoMasking
-
-    s = sharing_scheme
-    masking = masking_scheme or NoMasking()
-    if not isinstance(masking, (NoMasking, FullMasking)):
-        raise ValueError("pallas round masking: None or Full")
-    if isinstance(masking, FullMasking) and masking.modulus != s.prime_modulus:
-        raise ValueError("masking modulus must equal the sharing prime")
-    sp = SolinasPrime.try_from(s.prime_modulus)
-    if sp is None:
-        raise ValueError(f"prime {s.prime_modulus} is not Solinas-form")
-    masked = isinstance(masking, FullMasking)
-    # scheme-dispatched matrices: PackedShamir (NTT) or BasicShamir
-    # (Vandermonde/Lagrange, k=1) — the kernel is layout-agnostic
-    m_host = numtheory.share_matrix_for(s)
-    l_host = numtheory.reconstruct_matrix_for(s, tuple(range(s.share_count)))
-    k = s.secret_count
-    t = s.privacy_threshold
-    draws = (k + t) if masked else t
-
-    def one_tile(inputs, key):
-        P, d = inputs.shape
-        # fold first, lay out second: the column-per-batch relayout and
-        # the tile pad run on the folded [d] vector, never on [P, d]
-        x_sum = fastfield.modsum32(
-            fastfield.to_residues32(inputs, sp), sp, axis=0)
-        x_cols = batch_columns(x_sum, k)                           # [k, B0]
-        pb = max(1, min(p_block, P))
-        B0 = x_cols.shape[-1]
-        # lane-dim tile: multiples of 128 lanes; large tiles amortize the
-        # grid-step overhead, small B avoids padding waste
-        TB = tile if tile is not None else (
-            2048 if B0 >= 2048 else max(128, -(-B0 // 128) * 128)
-        )
-        # round the DRAW count up to a balanced tiling: the extra
-        # participants contribute no secrets and their masks cancel
-        rows = k if external_bits_fn is None else k + 2 * draws
-        if p_tile is None:
-            ptile_eff, P_eff = _balanced_tiling(
-                P, pb, _participant_tile(pb, rows, TB)
-            )
-        else:
-            ptile_eff = int(p_tile)
-            P_eff = -(-P // ptile_eff) * ptile_eff
-        pad = (-B0) % TB
-        if pad:
-            x_cols = jnp.pad(x_cols, ((0, 0), (0, pad)))
-        B = B0 + pad
-        seed = jax.random.randint(key, (), 0, np.int32(2**31 - 1), dtype=jnp.int32)
-        ext = None
-        if external_bits_fn is not None:
-            ext = external_bits_fn(key, P_eff, draws, B)
-        shares, mask_tot = fused_mask_share_combine(
-            x_cols, P_eff, seed, sp, m_host, t, masked,
-            tile=TB, external_bits=ext, interpret=interpret, p_block=pb,
-            p_tile=ptile_eff, tree_fold=tree_fold,
-        )
-        from .sharing import packed_reconstruct32
-
-        total = packed_reconstruct32(shares[:, :B0], l_host, sp, dimension=d)
-        if masked:
-            mask_flat = unbatch_columns(mask_tot[:, :B0], d)
-            total = modsub32(total, mask_flat, sp)
-        return total.astype(jnp.int64)
-
-    if dim_tile is None:
-        return one_tile
-
-    import math
-
-    from .dimtile import scan_dim_tiles
-
-    grain = k * 8 // math.gcd(k, 8)
-    return scan_dim_tiles(
-        lambda blk, round_key, tile_key, i, width: one_tile(blk, tile_key),
-        grain, dim_tile)

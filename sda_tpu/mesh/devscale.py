@@ -198,7 +198,7 @@ class ModelScaleRound:
         masking_scheme=None,
         mesh=None,
         dim_tile: Optional[int] = None,
-        use_pallas: Optional[bool] = None,
+        use_pallas: bool = False,
         pallas_interpret: bool = False,
         pallas_external_bits_fn=None,
         surviving_clerks=None,

@@ -43,7 +43,6 @@ federated HTTP mode keeps sealed boxes.
 from __future__ import annotations
 
 import math
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -76,11 +75,6 @@ def _shard_map(f, *, mesh, in_specs, out_specs):
     """``jax.shard_map`` with per-shard replication checking off."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-
-# re-export: lives in fields.fastfield (pure field arithmetic); kept under
-# the old name for existing importers
-_to_residues32 = fastfield.to_residues32
 
 
 def _scheme_modulus(scheme: LinearSecretSharingScheme) -> int:
@@ -299,16 +293,12 @@ def _pallas_supported(scheme, masking, f: FieldOps) -> bool:
     )
 
 
-def _pallas_env_default() -> bool:
-    return os.environ.get("SDA_PALLAS") == "1"
-
-
-def _resolve_pallas(scheme, masking, f: FieldOps, use_pallas, what: str) -> bool:
-    """Shared constructor gating for the three aggregators: ``use_pallas``
-    None takes the SDA_PALLAS=1 env default. Asked for — either way — on
-    a config the kernel does not serve raises; nothing falls back to the
-    XLA step behind the caller."""
-    want = _pallas_env_default() if use_pallas is None else bool(use_pallas)
+def _resolve_pallas(scheme, masking, f: FieldOps, use_pallas: bool,
+                    what: str) -> bool:
+    """Shared constructor gating for the aggregators: the kernel asked for
+    on a config it does not serve raises; nothing falls back to the XLA
+    step behind the caller."""
+    want = bool(use_pallas)
     if want and not _pallas_supported(scheme, masking, f):
         raise ValueError(
             f"pallas {what} step requires packed-Shamir over a Solinas "
@@ -355,7 +345,6 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     primitive is unavailable.
     """
     from ..fields import pallas_round
-    from ..utils.benchtime import pallas_knobs, tile_from_sweep, tree_fold_knob
 
     chacha_mask_sum = None
     if isinstance(masking, ChaChaMasking):
@@ -376,16 +365,7 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     with jax.named_scope("sda.relayout"):
         x_cols = sharing.batch_columns(x_sum, k)            # [k, B0]
     B0 = x_cols.shape[-1]
-    p_block, tile = pallas_knobs()
-    # a SWEEP-sourced tile (tuned at flagship widths) must not inflate
-    # SMALL shapes: a 2048 record at B0=8 would pad the kernel's column
-    # axis 256x — clamp it to the adaptive per-shape bound. An EXPLICIT
-    # user SDA_PALLAS_TILE is honored as-is (padding and all).
-    shape_tile = 2048 if B0 >= 2048 else max(128, -(-B0 // 128) * 128)
-    if tile is None:
-        tile = shape_tile
-    elif tile_from_sweep():
-        tile = min(tile, shape_tile)
+    tile = pallas_round.column_tile(B0)
     pad = (-B0) % tile
     if pad:  # padded columns are sliced off below; their shares never land
         with jax.named_scope("sda.relayout"):
@@ -400,7 +380,6 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
         shares, mask_tot = pallas_round.fused_mask_share_combine(
             x_cols, S, seed, f.sp, M_host, t, masked,
             tile=tile, external_bits=ext, interpret=interpret,
-            p_block=p_block, tree_fold=tree_fold_knob(),
         )
     shares = shares[:, :B0]
     if not masked:
@@ -547,13 +526,12 @@ class SimulatedPod:
     and components aggregate as zero; padding is stripped from the output).
 
     Two local steps compute the same round. The **XLA step** is the
-    default (``use_pallas=None`` without ``SDA_PALLAS=1``, or ``False``)
-    and serves every scheme and masking: ``_scan_combine`` streams the
-    rows in blocks of ``scan_chunk`` through ``_mask_stage`` and
-    ``_share_sum_stage``. The **fused Pallas kernel** (``use_pallas=True``)
-    serves packed and basic Shamir over a Solinas prime with
-    none/full/ChaCha masking (``_pallas_supported``); asked for on
-    additive sharing or a non-Solinas modulus it raises, so an
+    default (``use_pallas=False``) and serves every scheme and masking:
+    ``_scan_combine`` streams the rows in blocks of ``scan_chunk`` through
+    ``_mask_stage`` and ``_share_sum_stage``. The **fused Pallas kernel**
+    (``use_pallas=True``) serves packed and basic Shamir over a Solinas
+    prime with none/full/ChaCha masking (``_pallas_supported``); asked for
+    on additive sharing or a non-Solinas modulus it raises, so an
     additive-sharing aggregation always runs the XLA step.
     ``pallas_active`` says which step this pod took.
 
@@ -569,7 +547,7 @@ class SimulatedPod:
         masking_scheme: Optional[LinearMaskingScheme] = None,
         mesh: Optional[Mesh] = None,
         scan_chunk: int = 8,
-        use_pallas: Optional[bool] = None,
+        use_pallas: bool = False,
         pallas_interpret: bool = False,
         pallas_external_bits_fn=None,
         surviving_clerks=None,

@@ -166,7 +166,7 @@ def synthetic_block_provider(
         return z ^ (z >> np.uint64(31))
 
     # uint32 blocks when values fit: half the host->device bytes, and the
-    # device residue pass skips emulated 64-bit ops (_to_residues32)
+    # device residue pass skips emulated 64-bit ops (fastfield.to_residues32)
     out_dtype = np.uint32 if int(bound) <= (1 << 32) else np.int64
 
     def get_block(p0, p1, d0, d1):
@@ -437,7 +437,7 @@ class StreamingAggregator:
         masking_scheme: Optional[LinearMaskingScheme] = None,
         participants_chunk: int = 64,
         dim_chunk: int = 3 * (1 << 20),
-        use_pallas: Optional[bool] = None,
+        use_pallas: bool = False,
         pallas_interpret: bool = False,
         pallas_external_bits_fn=None,
         surviving_clerks=None,
@@ -621,7 +621,7 @@ class StreamedPod:
         mesh: Optional[Mesh] = None,
         participants_chunk: int = 64,
         dim_chunk: int = 3 * (1 << 20),
-        use_pallas: Optional[bool] = None,
+        use_pallas: bool = False,
         pallas_interpret: bool = False,
         pallas_external_bits_fn=None,
         surviving_clerks=None,

@@ -18,8 +18,8 @@ noise-aware per-metric gate:
 - **Noise awareness**: the threshold is
   ``max(floor, Z x relstd(window), Z x chain_rel)`` where ``relstd`` is
   the trailing window's empirical run-to-run variance and ``chain_rel``
-  is the per-record resolution of the chained-dispatch marginal method
-  (``utils/benchtime.py`` diagnostics: the un-cancelled
+  is the resolution a record states for itself where it carries
+  chained-dispatch diagnostics (``chain``: the un-cancelled
   ``fixed_overhead_s`` spread over the differenced chain). The floor
   (default 25%) absorbs the CPU rung's scheduler noise, which the
   committed r02-r05 spread shows runs to ~19%.

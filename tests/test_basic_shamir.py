@@ -19,7 +19,7 @@ from sda_tpu.fields import numtheory
 from sda_tpu.mesh import SimulatedPod, StreamedPod, StreamingAggregator, make_mesh
 from sda_tpu.protocol import BasicShamirSharing, ChaChaMasking, FullMasking
 
-from util import external_bits
+from util import external_bits, one_chip_pallas_pod
 
 
 def test_scheme_properties_match_reference_declaration():
@@ -135,18 +135,13 @@ def test_streaming_pallas_kernel():
 
 
 def test_single_chip_pallas_round():
-    """single_chip_round_pallas serves BasicShamir via the dispatched
-    matrices (interpret mode, external bits)."""
-    from sda_tpu.fields.pallas_round import single_chip_round_pallas
-
+    """The Pallas stage on one chip (a 1x1 pod) serves BasicShamir via
+    the dispatched matrices (interpret mode, external bits)."""
     s = fast_basic()
-    fn = single_chip_round_pallas(
-        s, FullMasking(s.prime_modulus), tile=128, interpret=True,
-        external_bits_fn=external_bits,
-    )
+    pod = one_chip_pallas_pod(s, FullMasking(s.prime_modulus))
     rng = np.random.default_rng(15)
     inputs = rng.integers(0, 1 << 20, size=(5, 500))
-    out = np.asarray(fn(jax.numpy.asarray(inputs), jax.random.PRNGKey(8)))
+    out = np.asarray(pod.aggregate(inputs, jax.random.PRNGKey(8)))
     np.testing.assert_array_equal(out, inputs.sum(axis=0) % s.prime_modulus)
 
 

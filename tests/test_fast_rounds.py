@@ -120,34 +120,12 @@ def test_single_chip_round_dim_tile_wider_than_dim_is_untiled():
     np.testing.assert_array_equal(out, inputs.sum(axis=0) % s.prime_modulus)
 
 
-@pytest.mark.parametrize("dim", [384, 250])
-def test_pallas_round_dim_tiled_exact(dim):
-    """Dim-tiled pallas driver (interpret mode): one kernel round per tile
-    scanned over the dim axis, exact incl. ragged tails off the grain."""
-    import jax.numpy as jnp
-
-    from sda_tpu.fields.pallas_round import single_chip_round_pallas
-    from util import external_bits as ext
-
-    s = fast_scheme()
-    p = s.prime_modulus
-    rng = np.random.default_rng(13)
-    x = rng.integers(0, 1 << 20, size=(6, dim)).astype(np.uint32)
-    out = single_chip_round_pallas(
-        s, FullMasking(p), tile=128, interpret=True, external_bits_fn=ext,
-        dim_tile=96,
-    )(jnp.asarray(x), jax.random.PRNGKey(9))
-    np.testing.assert_array_equal(
-        np.asarray(out), x.astype(np.int64).sum(axis=0) % p)
-
-
 @pytest.mark.parametrize("P", [1, 2])
 def test_single_participant_edge(P):
     """P=1/P=2 rounds: the smallest participant counts exercise pb-clamp
     and single-term folds in every single-chip path."""
     import jax.numpy as jnp
 
-    from sda_tpu.fields.pallas_round import single_chip_round_pallas
     from sda_tpu.mesh import StreamingAggregator
 
     s = fast_scheme()
@@ -155,14 +133,12 @@ def test_single_participant_edge(P):
     rng = np.random.default_rng(2)
     x = rng.integers(0, 1 << 20, size=(P, 384)).astype(np.uint32)
     exp = x.astype(np.int64).sum(axis=0) % p
-    from util import external_bits as ext
+    from util import one_chip_pallas_pod
 
     key = jax.random.PRNGKey(1)
 
     out_xla = jax.jit(single_chip_round(s, FullMasking(p)))(jnp.asarray(x), key)
-    out_pl = single_chip_round_pallas(
-        s, FullMasking(p), tile=128, interpret=True, external_bits_fn=ext
-    )(jnp.asarray(x), key)
+    out_pl = one_chip_pallas_pod(s, FullMasking(p)).aggregate(x, key)
     out_st = StreamingAggregator(
         s, FullMasking(p), participants_chunk=1, dim_chunk=96
     ).aggregate(x, key=key)

@@ -11,15 +11,17 @@ cannot run (pallas_round.py randomness contract).
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from sda_tpu.fields import numtheory
-from sda_tpu.mesh import SimulatedPod, StreamedPod, StreamingAggregator, make_mesh
+from sda_tpu.mesh import (ModelScaleRound, SimulatedPod, StreamedPod,
+                          StreamingAggregator, make_mesh)
 from sda_tpu.protocol import (AdditiveSharing, ChaChaMasking, FullMasking,
                               NoMasking, PackedShamirSharing)
 
-from util import external_bits
+from util import external_bits, one_chip_pallas_pod
 
 GOLDEN = PackedShamirSharing(3, 8, 4, 433, 354, 150)  # 433 is not Solinas
 
@@ -58,10 +60,9 @@ def test_pod_pallas_matches_sum(mesh_shape, masking):
 
 def _one_chip_pod(scheme, mask, pallas: bool):
     """A pod on a 1x1 mesh: the local step sees every row."""
-    step = dict(use_pallas=True, pallas_interpret=True,
-                pallas_external_bits_fn=external_bits) if pallas else {}
-    return SimulatedPod(scheme, masking_scheme=mask, mesh=make_mesh(1, 1),
-                        **step)
+    if pallas:
+        return one_chip_pallas_pod(scheme, mask)
+    return SimulatedPod(scheme, masking_scheme=mask, mesh=make_mesh(1, 1))
 
 
 @pytest.mark.parametrize("rows", [1, 7, 8, 9, 64, 65, 300])
@@ -267,13 +268,31 @@ def test_streamed_pod_chacha_with_dropout():
     np.testing.assert_array_equal(out, inputs.sum(axis=0) % 433)
 
 
-def test_pallas_env_default(monkeypatch):
-    s = fast_scheme()
+@pytest.mark.parametrize(
+    "driver", [SimulatedPod, StreamingAggregator, StreamedPod, ModelScaleRound])
+def test_pallas_env_default(monkeypatch, driver):
+    """``SDA_PALLAS=1`` in the environment selects nothing: the step is the
+    constructor's ``use_pallas``, the XLA step unless asked."""
     monkeypatch.setenv("SDA_PALLAS", "1")
-    assert StreamingAggregator(s).pallas_active
-    # asked for through the env and unsupported: raises, no silent XLA step
-    with pytest.raises(ValueError, match="requires packed-Shamir"):
-        StreamingAggregator(GOLDEN)
-    assert not StreamingAggregator(GOLDEN, use_pallas=False).pallas_active
-    monkeypatch.delenv("SDA_PALLAS")
-    assert not StreamingAggregator(s).pallas_active
+    assert not driver(fast_scheme()).pallas_active
+    assert not driver(GOLDEN).pallas_active  # and nothing raises for it
+    assert driver(fast_scheme(), use_pallas=True).pallas_active
+
+
+@pytest.mark.parametrize("name,value", [
+    ("SDA_PALLAS_PBLOCK", "64"), ("SDA_PALLAS_TREEFOLD", "1"),
+    ("SDA_PALLAS_TILE", "512")])
+def test_pallas_stage_takes_nothing_from_the_environment(monkeypatch, name, value):
+    """The kernel's parameters are its own: the round a Pallas pod lowers
+    to is the same text whatever ``SDA_PALLAS_*`` says (300 rows a chip,
+    as in the benchmark's packed cells)."""
+    def lowered():
+        s = fast_scheme()
+        pod = _one_chip_pod(s, FullMasking(s.prime_modulus), pallas=True)
+        return pod.aggregate_fn(300, 24).lower(
+            jnp.zeros((300, 24), jnp.uint32), jax.random.PRNGKey(0)).as_text()
+
+    monkeypatch.delenv(name, raising=False)
+    before = lowered()
+    monkeypatch.setenv(name, value)
+    assert lowered() == before

@@ -1,10 +1,13 @@
 """Fused Pallas round kernel — interpret-mode exactness on CPU.
 
 `external` randomness mode feeds deterministic bits so the kernel's uint32
-Solinas arithmetic is checkable without TPU hardware: the full round must
-equal the plain participant sum (masks and share randomness cancel), and
-the kernel's combined shares must equal the XLA fast-path shares computed
-from the same bits.
+Solinas arithmetic is checkable without TPU hardware. Round-level cases run
+the one stage around the kernel (``SimulatedPod`` on a 1x1 mesh): the round
+must equal the plain participant sum (masks and share randomness cancel).
+Cases about the kernel's own parameters (``p_block``, ``p_tile``, ``tile``,
+``tree_fold``) call it directly and hold its combined shares and mask
+totals to the XLA fast-path shares computed from the same bits: the round
+is exact for any randomness and so cannot see a wrong draw.
 """
 
 import jax
@@ -14,12 +17,16 @@ import pytest
 
 from sda_tpu.fields import fastfield, numtheory
 from sda_tpu.fields.pallas_round import (
+    _participant_block,
     _uniform_from_bits,
+    column_tile,
     fused_mask_share_combine,
-    single_chip_round_pallas,
 )
 from sda_tpu.fields.sharing import batch_columns
+from sda_tpu.mesh import SimulatedPod, make_mesh
 from sda_tpu.protocol import FullMasking, NoMasking, PackedShamirSharing
+
+from util import external_bits, one_chip_pallas_pod
 
 
 def fast_scheme():
@@ -27,131 +34,114 @@ def fast_scheme():
     return PackedShamirSharing(3, 8, t, p, w2, w3)
 
 
-from util import external_bits
+class SameBits:
+    """One set of residues and pre-drawn bits, the kernel's arguments for
+    them, and what the per-participant XLA share path makes of them."""
+
+    def __init__(self, P, seed, masked, d=384):
+        s = fast_scheme()
+        self.sp = fastfield.SolinasPrime.try_from(s.prime_modulus)
+        self.k, self.t = k, t = s.secret_count, s.privacy_threshold
+        self.m_host = numtheory.share_matrix_for(s)
+        self.P, self.masked = P, masked
+        rng = np.random.default_rng(seed)
+        self.x = jnp.asarray(
+            rng.integers(0, s.prime_modulus, size=(P, d)).astype(np.uint32))
+        self.bits = external_bits(
+            jax.random.PRNGKey(seed), P, (k + t) if masked else t, d // k)
+
+    def kernel(self, **params):
+        """(combined shares, mask totals) of the fused kernel."""
+        x_sum = batch_columns(fastfield.modsum32(self.x, self.sp, axis=0), self.k)
+        return fused_mask_share_combine(
+            x_sum, self.P, 0, self.sp, self.m_host, self.t, self.masked,
+            tile=128, external_bits=self.bits, interpret=True, **params)
+
+    def xla(self):
+        """The same draws through the fastfield helpers, shared participant
+        by participant and summed."""
+        k, t, sp, bits = self.k, self.t, self.sp, self.bits
+        cols = batch_columns(self.x, k)                         # [P, k, B]
+        mask_tot = jnp.zeros(cols.shape[1:], jnp.uint32)
+        if self.masked:
+            mask = _uniform_from_bits(bits[:, 0:k, :], bits[:, k:2 * k, :], sp)
+            cols = fastfield.modadd32(cols, mask, sp)
+            mask_tot = fastfield.modsum32(mask, sp, axis=0)
+            bits = bits[:, 2 * k:, :]
+        rand = _uniform_from_bits(bits[:, 0:t, :], bits[:, t:2 * t, :], sp)
+        zeros = jnp.zeros((self.P, 1, cols.shape[-1]), jnp.uint32)
+        per_part = fastfield.modmatmul32(
+            self.m_host, jnp.concatenate([zeros, cols, rand], axis=1), sp)
+        return fastfield.modsum32(per_part, sp, axis=0), mask_tot
+
+    def assert_kernel_matches_xla(self, **params):
+        got, want = self.kernel(**params), self.xla()
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        return got
 
 
 @pytest.mark.parametrize("masking", ["none", "full"])
 def test_pallas_round_equals_plain_sum(masking):
     s = fast_scheme()
     mask = FullMasking(s.prime_modulus) if masking == "full" else NoMasking()
-    fn = single_chip_round_pallas(
-        s, mask, tile=128, interpret=True, external_bits_fn=external_bits
-    )
+    pod = one_chip_pallas_pod(s, mask)
     rng = np.random.default_rng(21)
     inputs = rng.integers(0, 1 << 20, size=(5, 500))  # B=167 -> padded to 256
-    out = np.asarray(fn(jnp.asarray(inputs), jax.random.PRNGKey(8)))
+    out = np.asarray(pod.aggregate(inputs, jax.random.PRNGKey(8)))
     np.testing.assert_array_equal(out, inputs.sum(axis=0) % s.prime_modulus)
 
 
 def test_pallas_kernel_matches_xla_shares_same_bits():
     """Kernel combined-shares == XLA packed_share32 fed identical residues."""
-    s = fast_scheme()
-    sp = fastfield.SolinasPrime.try_from(s.prime_modulus)
-    k, t, n = s.secret_count, s.privacy_threshold, s.share_count
-    m_host = numtheory.packed_share_matrix(
-        k, n, t, s.prime_modulus, s.omega_secrets, s.omega_shares
-    )
-    P, d, tile = 4, 384, 128
-    B = d // k
-    rng = np.random.default_rng(22)
-    x = jnp.asarray(rng.integers(0, s.prime_modulus, size=(P, d)).astype(np.uint32))
-    x_cols = batch_columns(x, k)
-    bits = external_bits(jax.random.PRNGKey(30), P, k + t, B)
-
-    shares, mask_tot = fused_mask_share_combine(
-        fastfield.modsum32(x_cols, sp, axis=0), P, 0, sp, m_host, t, True,
-        tile=tile, external_bits=bits, interpret=True,
-    )
-
-    # reference: same draws through the fastfield helpers
-    mask = _uniform_from_bits(bits[:, 0:k, :], bits[:, k:2 * k, :], sp)
-    rand = _uniform_from_bits(bits[:, 2 * k:2 * k + t, :],
-                              bits[:, 2 * k + t:2 * (k + t), :], sp)
-    masked_cols = fastfield.modadd32(x_cols, mask, sp)
-    zeros = jnp.zeros((P, 1, B), jnp.uint32)
-    values = jnp.concatenate([zeros, masked_cols, rand], axis=1)
-    per_part = fastfield.modmatmul32(m_host, values, sp)        # [P, n, B]
-    expected_shares = fastfield.modsum32(per_part, sp, axis=0)
-    expected_mask_tot = fastfield.modsum32(mask, sp, axis=0)
-
-    np.testing.assert_array_equal(np.asarray(shares), np.asarray(expected_shares))
-    np.testing.assert_array_equal(np.asarray(mask_tot), np.asarray(expected_mask_tot))
+    SameBits(P=4, seed=22, masked=True).assert_kernel_matches_xla()
 
 
 def test_pallas_round_streams_participant_tiles():
-    """P larger than one VMEM participant tile: the kernel's second grid
-    axis must zero-init on the first visit and accumulate across revisits
-    of the same output block (the lenet-60k VMEM-OOM regression: all P in
-    one block). p_tile=32 with P=70 forces ceil(80/32)=3 grid-axis-1
-    steps — the auto tile would fit all of P in one block at these
-    shapes and never exercise the revisit path."""
-    s = fast_scheme()
-    fn = single_chip_round_pallas(
-        s, FullMasking(s.prime_modulus),
-        tile=128, interpret=True, external_bits_fn=external_bits,
-        p_tile=32,
-    )
-    rng = np.random.default_rng(23)
-    inputs = rng.integers(0, 1 << 20, size=(70, 500))
-    out = np.asarray(fn(jnp.asarray(inputs), jax.random.PRNGKey(9)))
-    np.testing.assert_array_equal(out, inputs.sum(axis=0) % s.prime_modulus)
+    """P larger than one participant tile: the kernel's second grid axis
+    must zero-init on the first visit and accumulate across revisits of
+    the same output block (the lenet-60k VMEM-OOM regression: all P in
+    one block). p_tile=32 with P=96 forces 3 grid-axis-1 steps — the auto
+    tile would fit all of P in one block at these shapes and never
+    exercise the revisit path."""
+    SameBits(P=96, seed=23, masked=True).assert_kernel_matches_xla(p_tile=32)
 
 
 def test_pallas_combined_shares_equal_per_participant_sum():
     """Linearity fusion (Σp M@v_p == M@Σp v_p): kernel combined shares must
     equal folding per-participant packed_share32 rows from the same bits."""
-    s = fast_scheme()
-    sp = fastfield.SolinasPrime.try_from(s.prime_modulus)
-    k, t = s.secret_count, s.privacy_threshold
-    m_host = numtheory.packed_share_matrix(
-        k, s.share_count, t, s.prime_modulus, s.omega_secrets, s.omega_shares
-    )
-    P, d = 6, 384
-    B = d // k
-    rng = np.random.default_rng(31)
-    x = jnp.asarray(rng.integers(0, s.prime_modulus, size=(P, d)).astype(np.uint32))
-    bits = external_bits(jax.random.PRNGKey(44), P, t, B)  # unmasked: t rows
-
-    shares, _ = fused_mask_share_combine(
-        batch_columns(fastfield.modsum32(x, sp, axis=0), k), P, 0, sp,
-        m_host, t, False,
-        tile=128, external_bits=bits, interpret=True, p_block=2,
-    )
-    # per-participant path from the identical bits
-    rand = _uniform_from_bits(bits[:, 0:t, :], bits[:, t:2 * t, :], sp)
-    per_part = fastfield.modmatmul32(
-        m_host,
-        jnp.concatenate(
-            [jnp.zeros((P, 1, B), jnp.uint32), batch_columns(x, k), rand],
-            axis=1,
-        ),
-        sp,
-    )
-    np.testing.assert_array_equal(
-        np.asarray(shares), np.asarray(fastfield.modsum32(per_part, sp, axis=0))
-    )
+    SameBits(P=6, seed=31, masked=False).assert_kernel_matches_xla(p_block=2)
 
 
 def test_pallas_round_rejects_generic_prime():
     s = PackedShamirSharing(3, 8, 4, 433, 354, 150)
     with pytest.raises(ValueError, match="Solinas"):
-        single_chip_round_pallas(s)
+        SimulatedPod(s, mesh=make_mesh(1, 1), use_pallas=True)
 
 
-@pytest.mark.parametrize("p_block", [50, 100])
-def test_pallas_round_divisor_p_blocks(p_block):
-    """p_block values dividing P exactly (the sweep's zero-padding points:
-    at P=100, p_block 16/32/64 pad the participant axis to 112/128 rows
-    while 50/100 pad none) stay exact."""
-    s = fast_scheme()
-    fn = single_chip_round_pallas(
-        s, FullMasking(s.prime_modulus), p_block=p_block, tile=128,
-        interpret=True, external_bits_fn=external_bits,
-    )
-    rng = np.random.default_rng(3)
-    inputs = rng.integers(0, 1 << 20, size=(100, 3 * 128))
-    out = np.asarray(fn(jnp.asarray(inputs), jax.random.PRNGKey(5)))
-    np.testing.assert_array_equal(out, inputs.sum(axis=0) % s.prime_modulus)
+@pytest.mark.parametrize("p_block,P,effective", [
+    (50, 100, 50), (100, 100, 100),   # divides P: taken as it is
+    (16, 300, 4),                     # the benchmark cells' rows a chip
+    (16, 24, 8), (16, 18, 2),         # shrinks to a divisor, never pads
+    (16, 7, 7),                       # clamps to P
+])
+def test_pallas_round_divisor_p_blocks(p_block, P, effective):
+    """The participant block is ``p_block`` clamped to P and shrunk to a
+    divisor of P; whatever it comes to, the kernel draws for exactly P
+    participants from the same bits as the XLA path."""
+    assert _participant_block(p_block, P) == effective
+    SameBits(P=P, seed=3, masked=True).assert_kernel_matches_xla(p_block=p_block)
+
+
+@pytest.mark.parametrize("B0,tile,padded", [
+    (8, 128, 128), (2047, 2048, 2048), (2048, 2048, 2048),
+    (333_333, 2048, 333_824),         # the benchmark cells' columns a chip
+])
+def test_column_tile(B0, tile, padded):
+    """The one rule for the kernel's lane-dim tile: whole vregs for a small
+    column count, 2048 from there on; the stage pads to whole tiles."""
+    assert column_tile(B0) == tile
+    assert B0 + (-B0) % tile == padded
 
 
 # -- tree fold: dense-sublane halving fold, bit-identical ------------------
@@ -161,60 +151,24 @@ def test_pallas_round_divisor_p_blocks(p_block):
 def test_tree_fold_bit_identical_to_slice_fold(masking, p_block):
     """tree_fold=True must reproduce the slice fold bit-for-bit from the
     same external bits (mod-p sums are order-free; the canon cadence
-    keeps raw partials inside uint32)."""
-    s = fast_scheme()
-    mask = FullMasking(s.prime_modulus) if masking == "full" else NoMasking()
-    rng = np.random.default_rng(31)
-    inputs = jnp.asarray(rng.integers(0, 1 << 20, size=(8, 504)))
-    key = jax.random.PRNGKey(14)
-    outs = {}
-    for tree in (False, True):
-        fn = single_chip_round_pallas(
-            s, mask, tile=128, interpret=True,
-            external_bits_fn=external_bits, p_block=p_block,
-            tree_fold=tree,
-        )
-        outs[tree] = np.asarray(fn(inputs, key))
-    np.testing.assert_array_equal(outs[True], outs[False])
-    np.testing.assert_array_equal(
-        outs[True], np.asarray(inputs).sum(axis=0) % s.prime_modulus)
+    keeps raw partials inside uint32), and both the XLA shares."""
+    same = SameBits(P=8, seed=14, masked=masking == "full")
+    tree = same.assert_kernel_matches_xla(p_block=p_block, tree_fold=True)
+    flat = same.kernel(p_block=p_block, tree_fold=False)
+    np.testing.assert_array_equal(np.asarray(tree[0]), np.asarray(flat[0]))
+    np.testing.assert_array_equal(np.asarray(tree[1]), np.asarray(flat[1]))
 
 
 def test_tree_fold_shares_match_slice_shares_same_bits():
-    """At the kernel seam: combined shares and mask totals identical."""
-    s = fast_scheme()
-    sp = fastfield.SolinasPrime.try_from(s.prime_modulus)
-    k, t = s.secret_count, s.privacy_threshold
-    m_host = numtheory.packed_share_matrix(
-        k, s.share_count, t, s.prime_modulus, s.omega_secrets,
-        s.omega_shares)
-    P, d, tile = 8, 384, 128
-    B = d // k
-    rng = np.random.default_rng(33)
-    x = jnp.asarray(
-        rng.integers(0, s.prime_modulus, size=(P, d)).astype(np.uint32))
-    x_sum = batch_columns(fastfield.modsum32(x, sp, axis=0), k)
-    bits = external_bits(jax.random.PRNGKey(34), P, k + t, B)
-    got = {}
-    for tree in (False, True):
-        got[tree] = fused_mask_share_combine(
-            x_sum, P, 0, sp, m_host, t, True, tile=tile, external_bits=bits,
-            interpret=True, p_block=4, tree_fold=tree)
-    np.testing.assert_array_equal(
-        np.asarray(got[True][0]), np.asarray(got[False][0]))
-    np.testing.assert_array_equal(
-        np.asarray(got[True][1]), np.asarray(got[False][1]))
+    """A block of 32: five halving levels, more than the raw adds one
+    canon interval allows (2^29-sized residues: 8 terms), so the tree
+    canonicalizes part-way."""
+    SameBits(P=32, seed=34, masked=True).assert_kernel_matches_xla(
+        p_block=32, tree_fold=True)
 
 
 def test_tree_fold_non_pow2_p_block_falls_back():
     """A non-power-of-two effective p_block silently runs the slice fold
-    (the knob is a no-op, never an error)."""
-    s = fast_scheme()
-    rng = np.random.default_rng(35)
-    inputs = jnp.asarray(rng.integers(0, 1 << 20, size=(6, 336)))
-    fn = single_chip_round_pallas(
-        s, FullMasking(s.prime_modulus), tile=112, interpret=True,
-        external_bits_fn=external_bits, p_block=3, tree_fold=True)
-    out = np.asarray(fn(inputs, jax.random.PRNGKey(15)))
-    np.testing.assert_array_equal(
-        out, np.asarray(inputs).sum(axis=0) % s.prime_modulus)
+    (the parameter is a no-op, never an error)."""
+    SameBits(P=6, seed=35, masked=True).assert_kernel_matches_xla(
+        p_block=3, tree_fold=True)

@@ -128,3 +128,13 @@ def external_bits(key, P, draws, B):
     import jax.numpy as jnp
 
     return jax.random.bits(key, (P, 2 * draws, B), dtype=jnp.uint32)
+
+
+def one_chip_pallas_pod(scheme, mask=None):
+    """The Pallas stage as a 1x1 pod sees it: every row on one device, the
+    kernel interpreted and fed ``external_bits`` (no TPU PRNG on the CPU)."""
+    from sda_tpu.mesh import SimulatedPod, make_mesh
+
+    return SimulatedPod(
+        scheme, mask, mesh=make_mesh(1, 1), use_pallas=True,
+        pallas_interpret=True, pallas_external_bits_fn=external_bits)
