@@ -15,7 +15,11 @@ intermediate provably fits uint32:
   conditional subtract), ~3 VPU ops — no 64-bit magic-multiply sequence;
 - products a*b split into 15-bit limbs: 4 uint32 multiplies whose scale
   streams (2^30, 2^15, 1) recombine through the Solinas congruence with
-  every partial sum < 2^32 (bounds in ``modmatmul32``).
+  every partial sum < 2^32 (bounds in ``modmatmul32``);
+- a 64-bit value is reduced from its two uint32 halves, ``hi * (2^32 mod
+  p) + lo`` (``reduce64``): random draws, ChaCha stream draws and int64
+  inputs never meet a 64-bit ``jnp.mod``, which the chip would emulate as
+  a multi-word division.
 
 ``generate_packed_params`` prefers such primes, so packed-Shamir rounds hit
 this path; arbitrary primes (e.g. the reference's p=433 conformance vector)
@@ -89,9 +93,16 @@ def canon32(v, sp: SolinasPrime):
 def to_residues32(inputs, sp: SolinasPrime):
     """Any-integer inputs -> canonical uint32 residues mod p.
 
-    uint32/int32 non-negative inputs skip the 64-bit pass entirely.
+    Every dtype skips the 64-bit pass entirely: what it returns is
+    ``jnp.mod(inputs.astype(int64), p)`` bit for bit, computed in uint32
+    lanes. Narrower integers widen to the 32-bit branches; anything else is
+    read as int64 (a uint64 of 2^63 or more wraps to a negative, as that
+    cast does) and reduced from its two halves.
     """
     inputs = jnp.asarray(inputs)
+    if inputs.dtype.itemsize < 4 and not jnp.issubdtype(inputs.dtype, jnp.floating):
+        signed = jnp.issubdtype(inputs.dtype, jnp.signedinteger)
+        inputs = inputs.astype(jnp.int32 if signed else jnp.uint32)
     if inputs.dtype == jnp.uint32:
         return canon32(inputs, sp)
     if inputs.dtype == jnp.int32:
@@ -99,7 +110,12 @@ def to_residues32(inputs, sp: SolinasPrime):
         r = canon32(bits, sp)
         r32 = jnp.uint32((1 << 32) % sp.p)
         return jnp.where(inputs < 0, modsub32(r, r32, sp), r)
-    return jnp.mod(inputs.astype(jnp.int64), sp.p).astype(jnp.uint32)
+    inputs = inputs.astype(jnp.int64)
+    hi = (inputs >> 32).astype(jnp.uint32)
+    r = reduce64(hi, inputs.astype(jnp.uint32), sp)
+    # two's complement again: a negative's halves read v + 2^64
+    r64 = jnp.uint32((1 << 64) % sp.p)
+    return jnp.where(hi >= np.uint32(1 << 31), modsub32(r, r64, sp), r)
 
 
 def modadd32(a, b, sp: SolinasPrime):
@@ -162,6 +178,20 @@ def modsum32(x, sp: SolinasPrime, axis: int = 0):
     return jax.lax.reduce(x, np.uint32(0), add, (axis % x.ndim,))
 
 
+def reduce64(hi, lo, sp: SolinasPrime):
+    """(hi*2^32 + lo) mod p, canonical, for any uint32 halves hi, lo.
+
+    The 64-bit value never exists: each half is canonicalized (``canon32``
+    takes any v < 2^32), the high one multiplied by the constant
+    ``2^32 mod p`` and the two added, all in uint32 lanes. Exact for every
+    prime ``SolinasPrime.try_from`` admits.
+    """
+    hi = canon32(hi, sp)
+    lo = canon32(lo, sp)
+    r32 = (1 << 32) % sp.p
+    return modadd32(mulmod32_const(hi, r32, sp), lo, sp)
+
+
 def uniform32(key, shape, sp: SolinasPrime):
     """Uniform canonical residues from 64 random bits per element.
 
@@ -169,10 +199,7 @@ def uniform32(key, shape, sp: SolinasPrime):
     <= p/2^64 statistical distance as the generic uniform_mod.
     """
     bits = jax.random.bits(key, shape=tuple(shape) + (2,), dtype=_U32)
-    hi = canon32(bits[..., 0], sp)
-    lo = canon32(bits[..., 1], sp)
-    r32 = (1 << 32) % sp.p
-    return modadd32(mulmod32_const(hi, r32, sp), lo, sp)
+    return reduce64(bits[..., 0], bits[..., 1], sp)
 
 
 # ---------------------------------------------------------------------------

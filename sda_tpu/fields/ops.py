@@ -65,7 +65,12 @@ class FieldOps:
         return x.astype(jnp.int64)
 
     def from_u64(self, v):
-        """uint64 stream draws -> canonical residues (no-reject reduction)."""
+        """uint64 stream draws -> canonical residues (no-reject reduction).
+        Over a Solinas modulus the draws' two halves are reduced in uint32
+        lanes (``fastfield.reduce64``); any other keeps the 64-bit modulo."""
+        if self.sp is not None:
+            hi = (v >> jnp.uint64(32)).astype(jnp.uint32)
+            return fastfield.reduce64(hi, v.astype(jnp.uint32), self.sp)
         r = jnp.mod(v, jnp.uint64(self.m))
         return r.astype(self.dtype)
 

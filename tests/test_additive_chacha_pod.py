@@ -4,6 +4,7 @@ held to the benchmark's plain reference -- its own ChaCha20, nothing of
 ``sda_tpu`` -- and not to ``fields/chacha.py``."""
 
 import importlib.util
+import re
 from pathlib import Path
 
 import jax
@@ -11,10 +12,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from sda_tpu.fields import numtheory
 from sda_tpu.fields.ops import FieldOps
 from sda_tpu.mesh import simpod
 from sda_tpu.mesh.simpod import SimulatedPod, default_mesh_shape, make_mesh
-from sda_tpu.protocol import AdditiveSharing, ChaChaMasking, FullMasking
+from sda_tpu.protocol import (AdditiveSharing, ChaChaMasking, FullMasking,
+                              PackedShamirSharing)
 from sda_tpu.utils import metrics
 
 MODULUS = 536870233  # 2^29 - 679: the uint32 fast path
@@ -97,6 +100,36 @@ def test_mask_stage_is_the_reference_stream_of_the_rounds_seeds(d_block0):
     assert np.array_equal(np.asarray(mask_sum), streams.sum(axis=0) % MODULUS)
 
 
+@pytest.mark.parametrize("participants", [3, 8, 13])
+def test_a_pod_rounds_masks_are_the_reference_stream_mod_p(participants):
+    """What the round adds to each participant's row, block by block as
+    ``_scan_combine`` asks for it, and the mask total the round subtracts,
+    against the reference's stream reduced mod p on the host: the aggregate
+    alone cannot tell (masks cancel whatever they are)."""
+    dim = 96
+    pod = _pod(dim)
+    field, chunk = pod._field, pod.scan_chunk
+    round_key = jax.random.PRNGKey(participants)
+    x = jnp.asarray(_inputs(participants, dim) % MODULUS, field.dtype)
+    chunk, scanned_rows = simpod._scan_rows(participants, chunk)
+    seeds = np.asarray(simpod._chacha_seed_words(
+        round_key, jnp.arange(scanned_rows), SEED_BITS))
+    want = np.stack([reference.mask_stream(seed[:SEED_BITS // 32], 0, dim, MODULUS)
+                     for seed in seeds])
+    for first in range(0, participants, chunk):
+        rows = x[first:first + chunk]
+        masked, _, _ = simpod._mask_stage(
+            pod.masking, field, rows, jax.random.PRNGKey(0), round_key,
+            pid_base=first, d_block0=0)
+        masks = (np.asarray(masked).astype(np.int64) - np.asarray(rows)) % MODULUS
+        assert np.array_equal(masks, want[first:first + rows.shape[0]])
+    _, mask_total = simpod._scan_combine(
+        field, pod.scheme, pod.masking, None, x, jax.random.PRNGKey(0), round_key,
+        pid0=0, dblk0=0, chunk=chunk)
+    # the scan expands whole blocks: padded rows carry masks too
+    assert np.array_equal(np.asarray(mask_total), want.sum(axis=0) % MODULUS)
+
+
 def test_a_128_bit_seed_fills_four_key_words_and_leaves_four_zero():
     seeds = np.asarray(simpod._chacha_seed_words(
         jax.random.PRNGKey(5), jnp.arange(6), SEED_BITS))
@@ -149,6 +182,40 @@ def test_the_xla_step_names_the_cipher_and_the_reduction_only_under_chacha(maski
     if masking == "chacha":
         assert all(f"sda.mask/sda.mask.{part}" in text
                    for part in ("chacha", "reduce", "relayout"))
+
+
+def _remainders_on_64_bits(text: str) -> list:
+    return [line for line in text.splitlines()
+            if "stablehlo.remainder" in line and re.search(r"[<x]u?i64>", line)]
+
+
+@pytest.mark.parametrize("round_, modulus, on_64_bits", [
+    ("additive-chacha", MODULUS, False),
+    ("packed-int64-fed", MODULUS, False),
+    ("additive-chacha", 433, True),   # off the fast path: the generic modulo
+    ("packed-int64-fed", 433, True),
+])
+def test_a_round_over_a_solinas_modulus_lowers_no_64_bit_remainder(
+        round_, modulus, on_64_bits):
+    """The chip has no 64-bit integers: a ``remainder`` on one is a
+    multi-word division emulated in 32-bit lanes (55 % of the additive
+    round and 33 % of the host-fed one until PR 32). Over a Solinas modulus
+    the mask draws (``FieldOps.from_u64``) and int64 inputs
+    (``fastfield.to_residues32``) are reduced from their uint32 halves."""
+    dim, mesh = 96, make_mesh(*default_mesh_shape(1, SHARES))
+    if round_ == "additive-chacha":
+        pod = SimulatedPod(AdditiveSharing(SHARES, modulus),
+                           ChaChaMasking(modulus, dim, SEED_BITS), mesh=mesh)
+        inputs = jnp.zeros((8, dim), jnp.uint32)
+    else:
+        t, prime, w2, w3 = ((4, 433, 354, 150) if modulus == 433 else
+                            numtheory.generate_packed_params(3, 8, 28))
+        pod = SimulatedPod(PackedShamirSharing(3, 8, t, prime, w2, w3),
+                           FullMasking(prime), mesh=mesh)
+        inputs = jnp.zeros((8, dim), jnp.int64)  # as aggregate() feeds a host matrix
+    assert (pod._sp is None) == on_64_bits
+    text = pod.aggregate_fn(8, dim).lower(inputs, jax.random.PRNGKey(0)).as_text()
+    assert bool(_remainders_on_64_bits(text)) == on_64_bits
 
 
 # -- (e) the counters -------------------------------------------------------------------

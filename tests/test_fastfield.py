@@ -12,6 +12,7 @@ import pytest
 
 from sda_tpu.fields import fastfield as ff
 from sda_tpu.fields import numtheory
+from sda_tpu.fields.ops import FieldOps
 
 P29 = 536870233   # 2^29 - 679, ≡ 1 mod 72
 P28 = 268435009   # 2^28 - 447, ≡ 1 mod 72
@@ -121,6 +122,111 @@ def test_uniform32_range_and_mean(sp):
     assert u.dtype == np.uint32
     assert int(u.max()) < sp.p
     assert abs(u.mean() / sp.p - 0.5) < 0.01
+
+
+# -- 64-bit values from their uint32 halves --------------------------------------
+
+P20 = 1048573     # 2^20 - 3: the narrowest width try_from admits
+
+
+@pytest.fixture(params=[P29, P28, P20])
+def sp64(request):
+    sp = ff.SolinasPrime.try_from(request.param)
+    assert sp is not None
+    return sp
+
+
+def test_reduce64_edge_halves(sp64):
+    p = sp64.p
+    edge = [0, 1, p - 1, p, 2**32 - 1]
+    hi, lo = np.meshgrid(np.asarray(edge, np.uint32), np.asarray(edge, np.uint32),
+                         indexing="ij")
+    got = np.asarray(ff.reduce64(jnp.asarray(hi), jnp.asarray(lo), sp64))
+    assert got.dtype == np.uint32
+    want = [[((h << 32) | l) % p for l in edge] for h in edge]
+    assert want[-1][-1] == (2**64 - 1) % p
+    assert got.tolist() == want
+
+
+def test_reduce64_random_pairs(sp64):
+    rng = np.random.default_rng(64)
+    hi = rng.integers(0, 1 << 32, size=100_000, dtype=np.uint64).astype(np.uint32)
+    lo = rng.integers(0, 1 << 32, size=100_000, dtype=np.uint64).astype(np.uint32)
+    got = np.asarray(ff.reduce64(jnp.asarray(hi), jnp.asarray(lo), sp64))
+    want = ((hi.astype(object) << 32) | lo.astype(object)) % sp64.p
+    np.testing.assert_array_equal(got.astype(object), want)
+
+
+def test_uniform32_is_reduce64_of_its_two_words(sp64):
+    key = jax.random.PRNGKey(32)
+    bits = jax.random.bits(key, shape=(3, 1000, 2), dtype=jnp.uint32)
+    want = ff.reduce64(bits[..., 0], bits[..., 1], sp64)
+    np.testing.assert_array_equal(
+        np.asarray(ff.uniform32(key, (3, 1000), sp64)), np.asarray(want))
+
+
+def _int64_samples(p):
+    rng = np.random.default_rng(p)
+    edge = [0, 1, -1, p, -p, p - 1, 1 - p, 2**32, -2**32, 2**63 - 1, -2**63]
+    return np.concatenate([
+        np.asarray(edge, np.int64),
+        rng.integers(-2**63, 2**63 - 1, size=50_000, dtype=np.int64, endpoint=True),
+        rng.integers(-2**33, 2**33, size=10_000, dtype=np.int64),
+    ])
+
+
+def test_to_residues32_int64_is_the_python_modulo(sp64):
+    x = _int64_samples(sp64.p)
+    got = np.asarray(ff.to_residues32(jnp.asarray(x), sp64))
+    assert got.dtype == np.uint32
+    np.testing.assert_array_equal(got.astype(object), x.astype(object) % sp64.p)
+    # and what the 64-bit pass returned, bit for bit
+    np.testing.assert_array_equal(
+        got, np.asarray(jnp.mod(jnp.asarray(x), sp64.p)).astype(np.uint32))
+
+
+@pytest.mark.parametrize("dtype", [np.int8, np.int16, np.uint8, np.uint16,
+                                   np.uint64, np.bool_])
+def test_to_residues32_other_dtypes_read_as_int64(sp64, dtype):
+    # astype wraps: every bit pattern of the narrow types, and uint64s on
+    # both sides of 2^63 (those above read as negatives, as the cast does)
+    x = jnp.asarray(_int64_samples(sp64.p).astype(dtype))
+    got = ff.to_residues32(x, sp64)
+    assert got.dtype == jnp.uint32
+    want = jnp.mod(x.astype(jnp.int64), sp64.p).astype(jnp.uint32)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _traces_rem(fn, *args) -> bool:
+    return " rem " in str(jax.make_jaxpr(fn)(*args))
+
+
+def test_to_residues32_traces_no_remainder_for_any_dtype(sp64):
+    for dtype in (jnp.int8, jnp.uint16, jnp.int32, jnp.uint32, jnp.int64, jnp.uint64):
+        assert not _traces_rem(lambda x: ff.to_residues32(x, sp64),
+                               jnp.zeros((4,), dtype))
+
+
+def test_from_u64_over_a_solinas_modulus_is_the_64_bit_modulo(sp64):
+    field = FieldOps.create(sp64.p)
+    assert field.sp is not None
+    v = jnp.asarray(_int64_samples(sp64.p).view(np.uint64))
+    got = field.from_u64(v)
+    assert got.dtype == jnp.uint32
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(jnp.mod(v, jnp.uint64(sp64.p))).astype(np.uint32))
+    assert not _traces_rem(field.from_u64, v)
+
+
+def test_from_u64_keeps_the_generic_modulo_for_a_modulus_off_the_fast_path():
+    field = FieldOps.create(433)
+    assert field.sp is None
+    v = jnp.asarray(_int64_samples(433).view(np.uint64))
+    got = field.from_u64(v)
+    assert got.dtype == jnp.int64
+    np.testing.assert_array_equal(
+        np.asarray(got).astype(object), np.asarray(v).astype(object) % 433)
+    assert _traces_rem(field.from_u64, v)
 
 
 def test_generated_packed_params_prefer_solinas():

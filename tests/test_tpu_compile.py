@@ -94,6 +94,19 @@ def test_mask_stage_changes_layout_with_no_gather_no_row_loop_and_no_padded_plan
     assert "sda.mask.relayout" in text and "dot_general" in text
 
 
+def test_mask_stage_reduces_its_draws_with_no_remainder_and_no_64_bit_array(
+        mask_stage_compiled):
+    """Until PR 32 the draws were glued to uint64 and reduced by ``jnp.mod``:
+    one op, an emulated multi-word division, 55 % of the round. The glue
+    (``chacha_jax._paired_u64``) and the split (``FieldOps.from_u64``)
+    cancel in the compiler's 64-bit rewriting: what is left is uint32
+    arithmetic on the two half planes (``fastfield.reduce64``)."""
+    text = mask_stage_compiled.as_text()
+    assert "sda.mask.reduce" in text
+    assert "remainder" not in text and "/rem" not in text
+    assert not re.search(r"\b[us]64\[", text)
+
+
 def test_mask_stage_block_holds_under_a_hundred_megabytes_of_temporaries(
         mask_stage_compiled):
     # 578 MB before PR 30, 515 MB with transpose + reshape; the planes of
