@@ -10,6 +10,9 @@ checks every result bit-exact against the plaintext sum:
   and the same shape under upstream's other scheme, additive 3-of-3 sharing
   with ChaCha masks from 128-bit seeds, through ``SimulatedPod``'s XLA step
   (the fused kernel serves no additive scheme);
+- pod, a cohort streamed in blocks — 600 rows of that width through
+  ``StreamingAggregator`` in two blocks of 300 with the fused kernel (the
+  chip benchmark's configuration ``stream-packed8``);
 - pod, a model's full width — MobileLite's default update vector (~3.7M)
   through ``StreamedPod`` and ``ModelScaleRound`` with the fused kernel, tile
   width from the live ``memory_stats()["bytes_limit"]``;
@@ -86,14 +89,18 @@ def _summary(record: dict, **extra) -> dict:
 
 def pod_round(participants: int, dim: int, *, clerks: int = 8,
               sharing: str = "packed", mask: str = "full",
-              pallas: bool = False, streaming: bool = False) -> dict:
-    """One pod round at the given shape, verified against the plain sum."""
+              pallas: bool = False, streaming: bool = False,
+              participants_chunk: int | None = None) -> dict:
+    """One pod round at the given shape, verified against the plain sum.
+    ``participants_chunk``: the rows a streamed round folds at a time."""
     argv = ["--participants", participants, "--dim", dim, "--clerks", clerks,
             "--sharing", sharing, "--mask", mask, "--verify"]
     if pallas:
         argv.append("--pallas")
     if streaming:
         argv.append("--streaming")
+    if participants_chunk is not None:
+        argv += ["--participants-chunk", participants_chunk]
     record = run_sim(argv)
     what = f"pod {record.get('mode')} (pallas={pallas})"
     _require(record, what, rc=0, exact=True, pallas=pallas)
@@ -176,6 +183,11 @@ def main() -> int:
          lambda: model_scale_round("mobilelite")),
         ("pod.flagship.streaming_chacha",
          lambda: pod_round(**flagship, mask="chacha", streaming=True)),
+        # the configuration stream-packed8 (benchmarks/chip): two blocks of
+        # 300 int64 rows through the fused kernel under traced tile offsets
+        ("stream.packed_pallas",
+         lambda: pod_round(600, 999_999, pallas=True, streaming=True,
+                           participants_chunk=300)),
     ]
     cache = {"hit": 0, "miss": 0}
     t0 = time.perf_counter()

@@ -7,8 +7,9 @@ of per-participant shares, so it streams: tile the participant axis and
 the dimension axis, push each [P_chunk, d_chunk] block through
 mask -> share -> local combine on device, and fold it into running
 [n, B_chunk] share and [d_chunk] mask accumulators. Peak memory is one
-block plus accumulators, independent of P. Per dim-tile, reconstruction
-and unmasking run once at the end.
+step's working set plus the block in transfer behind it, independent of P
+(``BLOCKS_IN_FLIGHT``). Per dim-tile, reconstruction and unmasking run once
+at the end.
 
 Two drivers share that structure:
 
@@ -31,6 +32,7 @@ remainder), with the uint32 Solinas fast path when the prime qualifies.
 
 from __future__ import annotations
 
+import collections
 from typing import Callable, Optional
 
 import jax
@@ -46,7 +48,7 @@ from ..protocol import (
     LinearMaskingScheme,
     NoMasking,
 )
-from ..utils import timed_phase
+from ..utils import metrics, timed_phase
 from .simpod import (
     _check_collective_headroom,
     _check_mask_modulus,
@@ -66,6 +68,14 @@ from .simpod import (
 
 #: get_block(p0, p1, d0, d1) -> integer array [p1-p0, d1-d0]
 BlockProvider = Callable[[int, int, int, int], np.ndarray]
+
+#: Blocks the tile loop keeps live on the device: the one its step reads
+#: and the one in transfer behind it. Derived, not a parameter: one would
+#: serialize transfer and step, and a third buys nothing -- a block's
+#: transfer already runs under the step before it, and a second transfer
+#: in flight only queues behind the first (on the v5e, 4 GiB or more of
+#: outstanding transfers fall from 10 GB/s to 0.4: PERF.md, PR 33).
+BLOCKS_IN_FLIGHT = 2
 
 
 def array_block_provider(inputs) -> BlockProvider:
@@ -288,19 +298,51 @@ class _FileCheckpointer:
             pass
 
 
-def _drive_stream(owner, participants, dimension, key, *, make_block,
-                  make_accs, fetch, checkpoint_path=None,
-                  checkpoint_every_chunks=16, restore_accs=None,
-                  checkpointer=None):
+def _drive_stream(owner, participants, dimension, key, **tile_loop):
+    """One streamed round of ``owner`` (StreamingAggregator, StreamedPod
+    and, via StreamedPod.drive_tiles, the multihost driver): the tile loop
+    of :func:`_drive_tiles` under the root span ``stream.round``.
+
+    Spans: ``stream.round`` (attributes ``participants``, ``dimension``,
+    ``participants_chunk``, ``dim_chunk``, ``tiles``) over, per block,
+    ``stream.feed`` (attributes ``bytes``, ``dtype``, ``shape``: the wait
+    for the block before to land, then the hand-over of this one) and
+    ``stream.dispatch``; ``stream.steps_sync`` wherever the loop waits for
+    a step; per dim tile ``stream.finale`` and ``stream.readback``;
+    ``stream.checkpoint`` where a snapshot is written. Counters at the
+    same boundaries: ``mesh.stream.{rounds,blocks,bytes}``
+    (docs/observability.md)."""
+    pc, dc = owner.participants_chunk, owner.dim_chunk
+    metrics.count("mesh.stream.rounds")
+    with timed_phase("stream.round") as root:
+        root.attributes.update(
+            participants=int(participants), dimension=int(dimension),
+            participants_chunk=pc, dim_chunk=dc,
+            tiles=-(-participants // pc) * -(-dimension // dc))
+        return _drive_tiles(owner, participants, dimension, key, **tile_loop)
+
+
+def _drive_tiles(owner, participants, dimension, key, *, make_block,
+                 make_accs, fetch, checkpoint_path=None,
+                 checkpoint_every_chunks=16, restore_accs=None,
+                 checkpointer=None):
     """THE streamed tile loop — one definition of the tile/key derivation
-    and of the checkpoint/resume state machine, shared by
-    StreamingAggregator, StreamedPod, and (via StreamedPod.drive_tiles)
-    the multihost driver. d-tiles outer, participant tiles inner, one
-    accumulate step per tile, one finale per d-tile; snapshots every
-    ``checkpoint_every_chunks`` chunks (0 = boundaries only) and at every
-    d-tile boundary, removed on completion. Mask windows and share
-    randomness depend on the tile indexing here — any change breaks
-    resume bit-identity.
+    and of the checkpoint/resume state machine. d-tiles outer, participant
+    tiles inner, one accumulate step per tile, one finale per d-tile;
+    snapshots every ``checkpoint_every_chunks`` chunks (0 = boundaries
+    only) and at every d-tile boundary, removed on completion. Mask
+    windows and share randomness depend on the tile indexing here — any
+    change breaks resume bit-identity.
+
+    Back-pressure: transfers and steps are asynchronous and a block is
+    live from its transfer until its step has run, so before block ``i``
+    is made the loop waits for step ``i - BLOCKS_IN_FLIGHT`` -- on the
+    scalar every step returns beside its accumulators, which are donated
+    to the next step and cannot be waited on once that is dispatched --
+    and for block ``i - 1`` to have landed. At most ``BLOCKS_IN_FLIGHT``
+    blocks of the loop's are on the device and one of them is in transfer,
+    whatever the number of chunks; block ``i`` still lands under step
+    ``i - 1``.
     """
     if key is None:
         from ..crypto.core import fresh_prng_key
@@ -351,11 +393,17 @@ def _drive_stream(owner, participants, dimension, key, *, make_block,
         else:
             acc_shares, acc_mask = make_accs(d_size)
             start_pi = 0
+        in_flight = collections.deque()  # the dispatched steps' scalars
+        block = None
         for pi, p0 in enumerate(range(0, participants, pc)):
             if pi < start_pi:
                 continue  # chunk already folded into the snapshot accs
             p1 = min(p0 + pc, participants)
-            with timed_phase("stream.feed"):
+            if len(in_flight) == BLOCKS_IN_FLIGHT:
+                with timed_phase("stream.steps_sync"):
+                    jax.block_until_ready(in_flight.popleft())
+            with timed_phase("stream.feed") as feed:
+                jax.block_until_ready(block)  # one transfer at a time
                 block = make_block(p0, p1, d0, d1, d_size)
                 if uniform_p and block.shape[0] < pc:
                     # ragged participant tail: zero rows aggregate as
@@ -364,15 +412,21 @@ def _drive_stream(owner, participants, dimension, key, *, make_block,
                     block = jnp.pad(
                         jnp.asarray(block),
                         ((0, pc - block.shape[0]), (0, 0)))
+                metrics.count("mesh.stream.blocks")
+                metrics.count("mesh.stream.bytes", block.nbytes)
+                feed.attributes.update(bytes=block.nbytes,
+                                       dtype=str(block.dtype),
+                                       shape=list(block.shape))
             step = owner._steps.get(block.shape)
             if step is None:
                 step = owner._steps[block.shape] = owner._step_fn(block.shape)
             with timed_phase("stream.dispatch"):
-                acc_shares, acc_mask = step(
+                acc_shares, acc_mask, done = step(
                     block, _tile_key(key, pi, di), key,
                     jnp.int32(p0), jnp.int32(d0 // 8),
                     acc_shares, acc_mask,
                 )
+            in_flight.append(done)
             if (checkpointer is not None
                     and checkpoint_every_chunks > 0
                     and (pi + 1) % checkpoint_every_chunks == 0):
@@ -388,7 +442,9 @@ def _drive_stream(owner, participants, dimension, key, *, make_block,
         if final is None:
             final = owner._finals[d_size] = owner._final_fn(d_size)
         with timed_phase("stream.finale"):
-            out[d0:d1] = fetch(final(acc_shares, acc_mask))[: d1 - d0]
+            total = jax.block_until_ready(final(acc_shares, acc_mask))
+        with timed_phase("stream.readback"):
+            out[d0:d1] = fetch(total)[: d1 - d0]
         if checkpointer is not None:
             with timed_phase("stream.checkpoint"):
                 checkpointer.save(out, d1, di + 1, 0, empty, empty)
@@ -429,6 +485,14 @@ class StreamingAggregator:
     additive sharing x none/full/chacha masking — ChaCha seed masks are
     expanded on device per tile at the tile's (participant, dim) offset,
     so every tiling of the same round key sees the same masks.
+
+    The memory bound, as kept: one step's working set (a block, its
+    residue temporaries, the [n, B] and [d] accumulators) plus the block
+    in transfer behind it — ``BLOCKS_IN_FLIGHT`` = 2 blocks of the tile
+    loop's on the device at most, one of them in transfer, whatever the
+    number of chunks (``_drive_tiles`` waits for the step two blocks back
+    and for the block before to land before it makes a block). A provider
+    that returns device arrays holds its own.
     """
 
     def __init__(
@@ -500,10 +564,12 @@ class StreamingAggregator:
                 # share + participant-combine fused via linearity
                 # (simpod._share_sum_stage): no [S, n, B] tensor in HBM
                 shares = _share_sum_stage(s, f, M_host, masked, skey)
-            acc_shares = f.add(acc_shares, shares)
-            if mask_sum is not None:
-                acc_mask = f.add(acc_mask, mask_sum)
-            return acc_shares, acc_mask
+            with jax.named_scope("sda.stream.acc"):
+                acc_shares = f.add(acc_shares, shares)
+                if mask_sum is not None:
+                    acc_mask = f.add(acc_mask, mask_sum)
+            # the undonated scalar _drive_tiles waits on
+            return acc_shares, acc_mask, acc_shares[0, 0]
 
         # one "stream.step" profile for every block shape: the compiled-
         # shape registry is how the "at most 2-3 shapes per axis" claim
@@ -716,16 +782,20 @@ class StreamedPod:
                     d_block0=d_block_base + di * (d_loc // 8),
                 )
                 shares = _share_sum_stage(s, f, self._M_host, masked, skey)
-            acc_shares = f.add(acc_shares, shares)
-            if local_mask_sum is not None:
-                acc_mask = f.add(acc_mask, local_mask_sum[None, :])
-            return acc_shares, acc_mask
+            with jax.named_scope("sda.stream.acc"):
+                acc_shares = f.add(acc_shares, shares)
+                if local_mask_sum is not None:
+                    acc_mask = f.add(acc_mask, local_mask_sum[None, :])
+            # the undonated handle _drive_tiles waits on: one element a
+            # device, so the wait covers every device and needs no
+            # collective
+            return acc_shares, acc_mask, acc_shares[:1, :1]
 
         fn = _shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(P("p", "d"), P(), P(), P(), P(), P("p", "d"), P("p", "d")),
-            out_specs=(P("p", "d"), P("p", "d")),
+            out_specs=(P("p", "d"), P("p", "d"), P("p", "d")),
         )
         return devprof.instrument("stream.pod.step",
                                   jax.jit(fn, donate_argnums=(5, 6)))

@@ -26,6 +26,17 @@ def test_streaming_chacha_phase_exact():
     assert result["exact"] is True and result["mode"] == "streaming"
 
 
+def test_streamed_blocks_phase_exact_and_refuses_a_cpu_kernel():
+    # stream.packed_pallas at toy size: three blocks, the last ragged; the
+    # kernel's PRNG is the chip's, so its phase fails here as it must
+    result = chip_smoke.pod_round(8, 99, streaming=True, participants_chunk=3)
+    assert result["exact"] is True and result["mode"] == "streaming"
+    assert result["pallas"] is False and result["round_is_warm"] is False
+    with pytest.raises(chip_smoke.PhaseFailed, match="rc=1"):
+        chip_smoke.pod_round(8, 99, pallas=True, streaming=True,
+                             participants_chunk=3)
+
+
 def test_additive_chacha_phase_exact_on_the_xla_step():
     result = chip_smoke.pod_round(8, 99, clerks=3, sharing="additive",
                                   mask="chacha")
