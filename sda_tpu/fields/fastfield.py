@@ -192,14 +192,37 @@ def reduce64(hi, lo, sp: SolinasPrime):
     return modadd32(mulmod32_const(hi, r32, sp), lo, sp)
 
 
-def uniform32(key, shape, sp: SolinasPrime):
-    """Uniform canonical residues from 64 random bits per element.
+def random_bits64(key, shape):
+    """64 threefry bits an element: ONE ``uint64`` draw of ``shape`` -- the
+    draw under ``uniform32`` and ``modular.uniform_mod``.
 
-    (hi*2^32 + lo) mod p with exact constant-multiply reduction — same
-    <= p/2^64 statistical distance as the generic uniform_mod.
+    JAX's (partitionable) threefry runs a threefry-2x32 block on a counter
+    per drawn element: a ``uint64`` element keeps both output words of its
+    block, a ``uint32`` one XORs them into one word -- so the same 64 bits
+    asked for as ``shape + (2,)`` ``uint32`` words cost two blocks an
+    element, half of each thrown away. On the chip no 64-bit array is
+    made: the combine inside the draw and ``uniform32``'s split cancel in
+    the TPU compiler's 64-bit rewriting, and the words fuse into whatever
+    reduces them.
     """
-    bits = jax.random.bits(key, shape=tuple(shape) + (2,), dtype=_U32)
-    return reduce64(bits[..., 0], bits[..., 1], sp)
+    if not jax.config.jax_enable_x64:
+        # the draw would be narrowed to uint32: 32 bits an element
+        raise RuntimeError("a 64-bit draw needs jax_enable_x64 "
+                           "(importing sda_tpu sets it)")
+    return jax.random.bits(key, shape=tuple(shape), dtype=jnp.uint64)
+
+
+def uniform32(key, shape, sp: SolinasPrime):
+    """Uniform canonical residues, each from 64 random bits of its own.
+
+    An element is the full output block of one threefry counter
+    (``random_bits64``): its high and low words go to ``reduce64``,
+    ``(hi*2^32 + lo) mod p`` by exact constant-multiply reduction --
+    <= p/2^64 from uniform, and element for element what the generic
+    ``modular.uniform_mod`` gives for the same key, shape and prime.
+    """
+    bits = random_bits64(key, shape)
+    return reduce64((bits >> np.uint64(32)).astype(_U32), bits.astype(_U32), sp)
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import fastfield
+
 
 def canon(x, m):
     """Canonical representative in [0, m) of any int64 residues."""
@@ -120,14 +122,16 @@ def modmatmul(a, b, p: int):
 def uniform_mod(key, shape, m: int):
     """Uniform draws in [0, m) from threefry bits; m < 2^62.
 
-    64 random bits reduced mod m: statistical distance from uniform is
-    <= m / 2^64 (< 2^-33 for 31-bit moduli) — the TPU-native replacement for
-    the reference's OsRng.gen_range (additive.rs:42-44, full.rs:25-27).
+    64 random bits an element -- the output block of one threefry counter,
+    the same draw ``fastfield.uniform32`` reduces (``random_bits64``), so
+    the two agree element for element at a Solinas prime -- reduced mod m:
+    statistical distance from uniform is <= m / 2^64 (< 2^-33 for 31-bit
+    moduli) — the TPU-native replacement for the reference's
+    OsRng.gen_range (additive.rs:42-44, full.rs:25-27).
     """
     if not 0 < m < (1 << 62):
         raise ValueError(f"modulus {m} out of range for uniform_mod")
-    bits = jax.random.bits(key, shape=shape + (2,), dtype=jnp.uint32)
-    v = (bits[..., 0].astype(jnp.uint64) << jnp.uint64(32)) | bits[..., 1].astype(jnp.uint64)
+    v = fastfield.random_bits64(key, shape)
     return jnp.mod(v, jnp.uint64(m)).astype(jnp.int64)
 
 

@@ -253,6 +253,15 @@ def _share_sum_stage(scheme, f: FieldOps, M_host, masked, skey):
     federated client path): the same randomness shapes are drawn from the
     same key and mod-m arithmetic is exact, so fold order is free —
     tests/test_mesh.py and test_fast_rounds.py pin this equivalence.
+
+    What is drawn: ``f.uniform`` rows, every element reduced from the 64
+    bits of one threefry block of its own — ``[S, t, B]`` for a Shamir
+    scheme, ``[S, n - 1, d]`` free rows for the additive one (the n-th row
+    of a participant is its secret less the others). Neither tensor
+    reaches HBM: each has ONE consumer, the fold over participants, and
+    the draw, its reduction and the fold compile to one fusion. That is
+    why the additive branch subtracts the folded ``dsum`` rows one by one
+    and asks for no total of the draws (tests/test_tpu_compile.py).
     """
     S, d = masked.shape
     with jax.named_scope("sda.share"):
@@ -273,7 +282,14 @@ def _share_sum_stage(scheme, f: FieldOps, M_host, masked, skey):
         n = scheme.share_count
         draws = f.uniform(skey, (S, n - 1, d))
         dsum = f.sum(draws, axis=0)                                # [n-1, d]
-        last = f.sub(f.sum(masked, axis=0), f.sum(dsum, axis=0))   # [d]
+        # the folded rows come off one by one, n - 1 subtractions on [d]:
+        # the compiler turns f.sum(dsum, axis=0) into a second reduce over
+        # the draws themselves, and a draw with two consumers is written
+        # to HBM and read twice (or made twice) instead of fusing into its
+        # fold
+        last = f.sum(masked, axis=0)                               # [d]
+        for i in range(n - 1):
+            last = f.sub(last, dsum[i])
         return jnp.concatenate([dsum, last[None, :]], axis=0)
 
 

@@ -165,6 +165,41 @@ def test_additive_share_rows_sum_to_the_masked_sum_and_are_redrawn_per_key():
     assert not np.array_equal(out[0][:SHARES - 1], out[1][:SHARES - 1])
 
 
+@pytest.mark.parametrize("shares", [2, 3, 8])
+@pytest.mark.parametrize("modulus", [MODULUS, 433])
+def test_additive_share_stage_is_the_fold_of_each_participants_shares(modulus, shares):
+    # the stage folds the free rows where they are drawn and subtracts the
+    # folded rows from the secrets' sum (shares = 2: one subtraction, no
+    # other); the federated participant draws the same rows from the same
+    # key through the generic uniform_mod, on either field path
+    from sda_tpu.fields import sharing
+
+    rows, dim = 5, 40
+    field = FieldOps.create(modulus)
+    assert (field.sp is not None) == (modulus == MODULUS)
+    key = jax.random.PRNGKey(shares)
+    masked = field.to_residues(_inputs(rows, dim) % modulus)
+    stage = np.asarray(simpod._share_sum_stage(
+        AdditiveSharing(shares, modulus), field, None, masked, key)).astype(np.int64)
+    per = sharing.additive_share(key, jnp.asarray(masked, jnp.int64),
+                                 share_count=shares, modulus=modulus)
+    assert per.shape == (rows, shares, dim)
+    assert np.array_equal(stage, np.asarray(per).sum(axis=0) % modulus)
+
+
+@pytest.mark.parametrize("shares", [2, 8])
+@pytest.mark.parametrize("entry", ["aggregate", "aggregate_fn"])
+def test_the_xla_steps_aggregate_is_the_plain_sum_for_two_and_eight_clerks(entry, shares):
+    participants, dim = 11, 96
+    mesh = make_mesh(*default_mesh_shape(1, shares))
+    pod = SimulatedPod(AdditiveSharing(shares, MODULUS),
+                       ChaChaMasking(MODULUS, dim, SEED_BITS), mesh=mesh,
+                       use_pallas=False)
+    inputs = _inputs(participants, dim)
+    got = _through(pod, entry, inputs, jax.random.PRNGKey(shares))
+    assert np.array_equal(got, inputs.sum(axis=0) % MODULUS)
+
+
 # -- (d) the scopes in the lowered round ---------------------------------------------
 
 @pytest.mark.parametrize("masking", ["chacha", "full"])

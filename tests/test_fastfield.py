@@ -157,12 +157,79 @@ def test_reduce64_random_pairs(sp64):
     np.testing.assert_array_equal(got.astype(object), want)
 
 
+def _words(key, shape):
+    """High and low uint32 words of the one uint64 draw of ``shape``."""
+    bits = np.asarray(jax.random.bits(key, shape=shape, dtype=jnp.uint64))
+    return (bits >> np.uint64(32)).astype(np.uint32), bits.astype(np.uint32)
+
+
 def test_uniform32_is_reduce64_of_its_two_words(sp64):
+    # the words are the high and the low half of ONE uint64 draw of `shape`:
+    # a threefry block an element, both of its output words kept
     key = jax.random.PRNGKey(32)
-    bits = jax.random.bits(key, shape=(3, 1000, 2), dtype=jnp.uint32)
-    want = ff.reduce64(bits[..., 0], bits[..., 1], sp64)
+    hi, lo = _words(key, (3, 1000))
+    want = ff.reduce64(jnp.asarray(hi), jnp.asarray(lo), sp64)
     np.testing.assert_array_equal(
         np.asarray(ff.uniform32(key, (3, 1000), sp64)), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(ff.random_bits64(key, (3, 1000))),
+        (hi.astype(np.uint64) << np.uint64(32)) | lo.astype(np.uint64))
+
+
+@pytest.mark.parametrize("shape", [(5,), (3, 1000), (2, 2, 257)])
+def test_uniform32_equals_uniform_mod_element_for_element(sp64, shape):
+    from sda_tpu.fields import modular
+
+    key = jax.random.PRNGKey(sp64.b)
+    fast = np.asarray(ff.uniform32(key, shape, sp64))
+    generic = np.asarray(modular.uniform_mod(key, shape, sp64.p))
+    assert fast.dtype == np.uint32 and generic.dtype == np.int64
+    np.testing.assert_array_equal(fast.astype(np.int64), generic)
+
+
+@pytest.mark.parametrize("foreign", ["hi", "lo"])
+def test_uniform32_takes_both_halves_of_its_draw(sp64, foreign):
+    key, other = jax.random.PRNGKey(1), jax.random.PRNGKey(2)
+    hi, lo = _words(key, (4096,))
+    ohi, olo = _words(other, (4096,))
+    assert (hi != lo).mean() > 0.99  # two words, not one word twice
+    mixed = (ohi, lo) if foreign == "hi" else (hi, olo)
+    got = np.asarray(ff.reduce64(jnp.asarray(mixed[0]), jnp.asarray(mixed[1]), sp64))
+    own = np.asarray(ff.uniform32(key, (4096,), sp64))
+    assert (got != own).mean() > 0.99
+
+
+def test_uniform32_every_residue_bit_is_balanced(sp64):
+    u = np.asarray(ff.uniform32(jax.random.PRNGKey(11), (100_000,), sp64))
+    assert int(u.max()) < sp64.p and int(u.min()) >= 0
+    assert abs(u.mean() / sp64.p - 0.5) < 0.01
+    # bits under the top one of p = 2^b - delta are fair coins to delta/2^b;
+    # 1e5 draws put a fair coin's mean within 0.008 of a half at 5 sigma
+    for bit in range(sp64.b - 1):
+        assert abs(((u >> bit) & 1).mean() - 0.5) < 0.008, bit
+    top = ((u >> (sp64.b - 1)) & 1).mean()
+    assert abs(top - (sp64.p - (1 << (sp64.b - 1))) / sp64.p) < 0.008
+
+
+def test_uniform32_rows_differ_across_keys_and_leading_indices(sp64):
+    # every participant's every free row is a draw of its own: no two rows
+    # of one [S, n - 1, d] draw agree, nor do two keys' draws
+    one = np.asarray(ff.uniform32(jax.random.PRNGKey(3), (2, 4, 512), sp64))
+    two = np.asarray(ff.uniform32(jax.random.PRNGKey(4), (2, 4, 512), sp64))
+    rows = np.concatenate([one.reshape(-1, 512), two.reshape(-1, 512)])
+    for i in range(len(rows)):
+        for j in range(i):
+            assert (rows[i] == rows[j]).mean() < 0.02, (i, j)
+
+
+def test_a_64_bit_draw_is_refused_where_it_would_be_narrowed(sp64):
+    from sda_tpu.fields import modular
+
+    with jax.enable_x64(False):
+        with pytest.raises(RuntimeError, match="jax_enable_x64"):
+            ff.uniform32(jax.random.PRNGKey(0), (8,), sp64)
+        with pytest.raises(RuntimeError, match="jax_enable_x64"):
+            modular.uniform_mod(jax.random.PRNGKey(0), (8,), sp64.p)
 
 
 def _int64_samples(p):

@@ -209,6 +209,7 @@ def test_share_sum_stage_equals_per_participant_fold():
             *numtheory.generate_packed_params(3, 8, 28)[1:],
         ),
         AdditiveSharing(share_count=8, modulus=433),
+        AdditiveSharing(share_count=3, modulus=536870233),  # uint32 path
     ):
         mod = getattr(scheme, "prime_modulus", getattr(scheme, "modulus", None))
         f = FieldOps.create(mod)

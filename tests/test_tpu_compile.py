@@ -7,6 +7,7 @@ may load the TPU's library, so the worker that is given this file loads it
 and every other worker collects the same tests without touching it.
 """
 
+import math
 import os
 import re
 
@@ -16,7 +17,7 @@ import pytest
 
 from sda_tpu.fields.ops import FieldOps
 from sda_tpu.mesh import simpod
-from sda_tpu.protocol import ChaChaMasking
+from sda_tpu.protocol import AdditiveSharing, ChaChaMasking
 
 MODULUS = 536870233  # 2^29 - 679: the uint32 field path
 
@@ -34,20 +35,11 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def mask_stage_compiled(one_chip):
-    """``_mask_stage``'s ChaCha branch on one scan block of the cell
-    ``additive-chacha-1m``: 8 rows x 1,000,000, a traced block counter."""
-    rows, dim = 8, 1_000_000
-    field = FieldOps.create(MODULUS)
+ROWS, DIM = 8, 1_000_000  # one scan block of the cell ``additive-chacha-1m``
 
-    def stage(x, key, round_key, pid_base, block0):
-        return simpod._mask_stage(ChaChaMasking(MODULUS, dim, 128), field, x, key,
-                                  round_key, pid_base=pid_base, d_block0=block0)[:2]
 
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+def _compile_for(one_chip, stage, *args):
+    """``stage`` compiled for the described chip on ``(shape, dtype)`` args."""
     from jax.experimental.compilation_cache import compilation_cache
 
     # a compile for a described chip is written to the persistent cache but
@@ -56,12 +48,39 @@ def mask_stage_compiled(one_chip):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        return jax.jit(stage).lower(
-            arg((rows, dim), jnp.uint32), arg((2,), jnp.uint32), arg((2,), jnp.uint32),
-            arg((), jnp.int32), arg((), jnp.int32)).compile()
+        return jax.jit(stage).lower(*(
+            jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in args)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def mask_stage_compiled(one_chip):
+    """``_mask_stage``'s ChaCha branch on one scan block: 8 rows x
+    1,000,000, a traced block counter."""
+    field = FieldOps.create(MODULUS)
+
+    def stage(x, key, round_key, pid_base, block0):
+        return simpod._mask_stage(ChaChaMasking(MODULUS, DIM, 128), field, x, key,
+                                  round_key, pid_base=pid_base, d_block0=block0)[:2]
+
+    return _compile_for(
+        one_chip, stage, ((ROWS, DIM), jnp.uint32), ((2,), jnp.uint32),
+        ((2,), jnp.uint32), ((), jnp.int32), ((), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def share_stage_compiled(one_chip):
+    """``_share_sum_stage``'s additive branch, 3 shares, on one scan block."""
+    field = FieldOps.create(MODULUS)
+
+    def stage(masked, key):
+        return simpod._share_sum_stage(AdditiveSharing(3, MODULUS), field, None,
+                                       masked, key)
+
+    return _compile_for(one_chip, stage, ((ROWS, DIM), jnp.uint32), ((2,), jnp.uint32))
 
 
 def _lane_padded(text: str, least: int = 1 << 20):
@@ -72,10 +91,7 @@ def _lane_padded(text: str, least: int = 1 << 20):
     for dims, order in re.findall(r"\w+\[([\d,]+)\]\{([\d,]+):T\(8,128\)", text):
         dims = [int(n) for n in dims.split(",")]
         minor = dims[int(order.split(",")[0])]
-        count = 1
-        for n in dims:
-            count *= n
-        if count >= least and minor < 128:
+        if math.prod(dims) >= least and minor < 128:
             found.add((tuple(dims), order))
     return found
 
@@ -112,3 +128,54 @@ def test_mask_stage_block_holds_under_a_hundred_megabytes_of_temporaries(
     # 578 MB before PR 30, 515 MB with transpose + reshape; the planes of
     # one block are 32 MB each
     assert mask_stage_compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+# -- the additive share stage: one threefry block a draw, made, reduced and
+# folded in one fusion (PR 34). The parent's block drew (8, 2, 1000000, 2)
+# uint32 words -- two cipher blocks an element, half of each thrown away --
+# into a 128 MB array with two consumers.
+
+def test_share_stage_block_writes_no_draw_to_memory(share_stage_compiled):
+    # 64,512 B; the parent held 128,072,704 B of bits
+    assert share_stage_compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def _written_shapes(text: str):
+    """Shapes of the arrays a compiled program holds in memory: results
+    and parameters of every computation but the bodies of fusions, whose
+    values live in registers."""
+    shapes = set()
+    for header, body in re.findall(r"^(\S[^\n]*)\{\n(.*?)^\}", text, re.M | re.S):
+        if header.startswith("%fused_computation"):
+            continue
+        for dims in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", header + body):
+            shapes.add(tuple(int(n) for n in dims.split(",")))
+    return shapes
+
+
+def test_share_stage_holds_no_array_of_the_draws_width_but_its_rows(
+        share_stage_compiled):
+    """The only arrays of 2^20 elements or more along the 1,000,000 axis are
+    the input, the folded free rows, the share rows and the [d] vectors:
+    nothing with a participant axis AND a share axis, nothing per word."""
+    allowed = {(ROWS, DIM), (2, DIM), (3, DIM), (1, DIM), (DIM,)}
+    wide = {dims for dims in _written_shapes(share_stage_compiled.as_text())
+            if DIM in dims and math.prod(dims) >= (1 << 20)}
+    assert (ROWS, DIM) in wide and (3, DIM) in wide
+    assert wide <= allowed, wide - allowed
+
+
+def test_share_stage_makes_no_64_bit_array(share_stage_compiled):
+    # the uint64 draw's combine and uniform32's split cancel in the
+    # compiler's 64-bit rewriting, as from_u64's did (PR 32)
+    text = share_stage_compiled.as_text()
+    assert "sda.share" in text
+    assert not re.search(r"\b[us]64\[", text)
+
+
+def test_share_stage_runs_one_cipher_block_a_draw_once(share_stage_compiled):
+    """Cost analysis of the block: 2.778e9 flops. Two blocks a draw (the
+    (..., 2) uint32 words) read 5.607e9; one block a draw with the draws'
+    total taken by a second reduce, which recomputes the cipher in both
+    consumers, 5.523e9."""
+    assert 2.0e9 < share_stage_compiled.cost_analysis()["flops"] < 3.2e9
