@@ -9,7 +9,12 @@ checks every result bit-exact against the plaintext sum:
   the on-core PRNG, and ``StreamingAggregator`` with device ChaCha masks;
   and the same shape under upstream's other scheme, additive 3-of-3 sharing
   with ChaCha masks from 128-bit seeds, through ``SimulatedPod``'s XLA step
-  (the fused kernel serves no additive scheme);
+  (the fused kernel serves no additive scheme). ChaCha masks run under both
+  steps: ``pod.flagship.packed_chacha_pallas`` is the KERNEL under them (the
+  masks' sum expanded 8 rows at a time in front of the kernel's mask-free
+  variant: the chip benchmark's configuration ``pod-packed8-chacha``);
+  ``pod.flagship.streaming_chacha`` and ``pod.flagship.additive_chacha`` are
+  the XLA step;
 - pod, a cohort streamed in blocks — 600 rows of that width through
   ``StreamingAggregator`` in two blocks of 300 with the fused kernel (the
   chip benchmark's configuration ``stream-packed8``);
@@ -183,6 +188,10 @@ def main() -> int:
          lambda: model_scale_round("mobilelite")),
         ("pod.flagship.streaming_chacha",
          lambda: pod_round(**flagship, mask="chacha", streaming=True)),
+        # the configuration pod-packed8-chacha (benchmarks/chip) at the
+        # flagship's rows: the fused kernel behind blocked ChaCha masks
+        ("pod.flagship.packed_chacha_pallas",
+         lambda: pod_round(**flagship, pallas=True, mask="chacha")),
         # the configuration stream-packed8 (benchmarks/chip): two blocks of
         # 300 int64 rows through the fused kernel under traced tile offsets
         ("stream.packed_pallas",

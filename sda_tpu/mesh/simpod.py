@@ -198,6 +198,36 @@ def _chacha_seed_words(key, global_ids, seed_bitsize: int):
     return jax.vmap(one)(global_ids)
 
 
+#: participant rows a block of the XLA step's scan holds by default, and
+#: the rows the kernel path expands ChaCha masks for at a time
+_SCAN_CHUNK = 8
+
+
+def _chacha_masks(masking, f: FieldOps, round_key, pid_base, rows: int,
+                  d_loc: int, d_block0):
+    """-> masks [rows, d_loc] of the participants ``pid_base .. + rows``:
+    each one's CHACHA_PRG_V1 stream from block ``d_block0`` on, reduced
+    modulo the field's modulus, in element order."""
+    gids = pid_base + jnp.arange(rows)
+    seeds = _chacha_seed_words(round_key, gids, masking.seed_bitsize)
+    if d_loc % 8:
+        raise ValueError(
+            "dimension must be a multiple of 8 (one ChaCha block)")
+    # The draws keep the block function's word-major layout
+    # [S, 8, d_loc/8] through pairing and reduction, both
+    # elementwise; only the residues -- one uint32 plane, not the
+    # draws' two -- are put in element order, once. A scope each,
+    # so the device trace tells cipher, reduction and layout change
+    # apart (docs/observability.md)
+    with jax.named_scope("sda.mask.chacha"):
+        draws = chacha_jax.stream_u64_words_at(
+            seeds, d_block0, nblocks=d_loc // 8)
+    with jax.named_scope("sda.mask.reduce"):
+        masks = f.from_u64(draws)
+    with jax.named_scope("sda.mask.relayout"):
+        return chacha_jax.element_order(masks)
+
+
 def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
     """-> (masked [S, d_loc], local_mask_sum [d_loc] or None, share_key).
 
@@ -215,28 +245,38 @@ def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
             masks = f.uniform(mkey, (S, d_loc))
         elif isinstance(masking, ChaChaMasking):
             skey = key
-            gids = pid_base + jnp.arange(S)
-            seeds = _chacha_seed_words(round_key, gids, masking.seed_bitsize)
-            if d_loc % 8:
-                raise ValueError(
-                    "dimension must be a multiple of 8 (one ChaCha block)")
-            # The draws keep the block function's word-major layout
-            # [S, 8, d_loc/8] through pairing and reduction, both
-            # elementwise; only the residues -- one uint32 plane, not the
-            # draws' two -- are put in element order, once. A scope each,
-            # so the device trace tells cipher, reduction and layout change
-            # apart (docs/observability.md)
-            with jax.named_scope("sda.mask.chacha"):
-                draws = chacha_jax.stream_u64_words_at(
-                    seeds, d_block0, nblocks=d_loc // 8)
-            with jax.named_scope("sda.mask.reduce"):
-                masks = f.from_u64(draws)
-            with jax.named_scope("sda.mask.relayout"):
-                masks = chacha_jax.element_order(masks)
+            masks = _chacha_masks(masking, f, round_key, pid_base, S, d_loc,
+                                  d_block0)
         else:
             return x, None, key
-        masked = f.add(x, masks)
-        return masked, f.sum(masks, axis=0), skey
+        with jax.named_scope("sda.mask.fold"):
+            masked = f.add(x, masks)
+            return masked, f.sum(masks, axis=0), skey
+
+
+def _chacha_mask_sum(masking, f: FieldOps, round_key, pid_base, rows: int,
+                     d_loc: int, d_block0):
+    """-> [d_loc] sum of the ChaCha masks of ``rows`` participants, expanded
+    ``_SCAN_CHUNK`` rows at a time under a running sum: what is live is one
+    block's draws, whatever ``rows`` (the whole [rows, d_loc] block at once
+    is 22 MB of temporaries a row at a million elements, and 1200 rows do
+    not compile for a v5e: PERF.md, PR 35). Rows that do not fill the last
+    block are expanded too, as ``_scan_combine`` expands its zero rows: the
+    sum is added to the fold of the inputs and subtracted from the reveal,
+    so every mask in it cancels."""
+    chunk, padded_rows = _scan_rows(rows, _SCAN_CHUNK)
+
+    def body(acc, i):
+        masks = _chacha_masks(masking, f, round_key, pid_base + i * chunk,
+                              chunk, d_loc, d_block0)
+        with jax.named_scope("sda.mask.fold"):
+            return f.add(acc, f.sum(masks, axis=0)), None
+
+    with jax.named_scope("sda.mask"):
+        acc, _ = jax.lax.scan(
+            body, jnp.zeros((d_loc,), f.dtype),
+            jnp.arange(padded_rows // chunk, dtype=jnp.int32))
+    return acc
 
 
 def _share_sum_stage(scheme, f: FieldOps, M_host, masked, skey):
@@ -296,12 +336,12 @@ def _share_sum_stage(scheme, f: FieldOps, M_host, masked, skey):
 def _pallas_supported(scheme, masking, f: FieldOps) -> bool:
     """The fused kernel serves packed-Shamir over a Solinas prime with any
     masking in the lattice. None/Full draw inside the kernel; ChaCha masks
-    are expanded from the CHACHA_PRG_V1 stream in a fused XLA pass FIRST
-    and the kernel runs mask-free on the pre-masked input — see
-    _pallas_stage. Pod-internal masks are generated AND cancelled inside
-    the round (never wire-visible), so this choice is independent of the
-    scheme's ``prg`` tag — any prg-tagged ChaChaMasking is accepted and
-    the aggregate is exact either way."""
+    are expanded from the CHACHA_PRG_V1 stream in an XLA pass FIRST, a
+    block of rows at a time, and the kernel runs mask-free on the masked
+    fold — see _pallas_stage. Pod-internal masks are generated AND
+    cancelled inside the round (never wire-visible), so this choice is
+    independent of the scheme's ``prg`` tag — any prg-tagged ChaChaMasking
+    is accepted and the aggregate is exact either way."""
     return (
         isinstance(scheme, SHAMIR_SCHEMES)
         and f.sp is not None
@@ -347,9 +387,11 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     aggregate; tests pin pallas-pod == xla-pod == plain sum.
 
     ChaCha masking: the mask is the CHACHA_PRG_V1 stream, a function of
-    (round key, global participant id, dim offset) — it is applied by the
-    existing fused XLA _mask_stage pass first, and the kernel then runs
-    mask-free on the pre-masked input; ``round_key``/``pid_base``/
+    (round key, global participant id, dim offset). The masks' sum is made
+    ``_SCAN_CHUNK`` rows at a time (``_chacha_mask_sum``: the XLA step's
+    expansion, under a running sum) and added to the fold, and the kernel
+    then runs mask-free on that masked fold: no [S, d_loc] array of draws,
+    masks or masked inputs exists. ``round_key``/``pid_base``/
     ``d_block0`` locate this tile in the global stream exactly like the
     XLA path. This is prg-tag-independent by the same cancellation
     argument as above: pod masks never leave the round, so the scheme's
@@ -362,19 +404,19 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     """
     from ..fields import pallas_round
 
-    chacha_mask_sum = None
-    if isinstance(masking, ChaChaMasking):
-        x, chacha_mask_sum, _ = _mask_stage(
-            masking, f, x, dev_key, round_key,
-            pid_base=pid_base, d_block0=d_block0,
-        )
-        masking = NoMasking()
-
     S, d_loc = x.shape
     k, t = scheme.secret_count, scheme.privacy_threshold
     masked = isinstance(masking, FullMasking)
     with jax.named_scope("sda.fold"):
         x_sum = f.sum(x, axis=0)                            # [d_loc]
+    chacha_mask_sum = None
+    if isinstance(masking, ChaChaMasking):
+        # sum_p (x_p + m_p) = sum_p x_p + sum_p m_p mod p, bit for bit: the
+        # masks never meet the [S, d_loc] input, only its fold
+        chacha_mask_sum = _chacha_mask_sum(
+            masking, f, round_key, pid_base, S, d_loc, d_block0)
+        with jax.named_scope("sda.mask"), jax.named_scope("sda.mask.fold"):
+            x_sum = f.add(x_sum, chacha_mask_sum)
     # sda.relayout: the XLA passes that put the folded secrets into the
     # kernel's [k, B] tile layout (and take the mask sum back out of it,
     # below)
@@ -470,13 +512,12 @@ def _reconstruct_stage(scheme, f: FieldOps, L_host, gathered, d_loc: int):
 def _chacha_blocks(masking, pallas_active: bool, rows: int, chunk: int,
                    d_total: int, p_shards: int) -> int:
     """ChaCha20 blocks one round asks of the mesh (8 u64 draws a block), 0
-    under any other masking. ``rows`` per p shard; the XLA step expands
-    whole scan blocks (``_scan_combine`` pads the rows to ``chunk``), the
-    Pallas step the rows as they are (``_pallas_stage``)."""
+    under any other masking. ``rows`` per p shard; both steps expand whole
+    blocks of rows: the XLA step its scan's (``_scan_combine`` pads the rows
+    to ``chunk``), the Pallas step ``_chacha_mask_sum``'s."""
     if not isinstance(masking, ChaChaMasking):
         return 0
-    if not pallas_active:
-        rows = _scan_rows(rows, chunk)[1]
+    rows = _scan_rows(rows, _SCAN_CHUNK if pallas_active else chunk)[1]
     return p_shards * rows * (d_total // 8)
 
 
@@ -562,7 +603,7 @@ class SimulatedPod:
         sharing_scheme: LinearSecretSharingScheme,
         masking_scheme: Optional[LinearMaskingScheme] = None,
         mesh: Optional[Mesh] = None,
-        scan_chunk: int = 8,
+        scan_chunk: int = _SCAN_CHUNK,
         use_pallas: bool = False,
         pallas_interpret: bool = False,
         pallas_external_bits_fn=None,

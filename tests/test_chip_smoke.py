@@ -44,6 +44,16 @@ def test_additive_chacha_phase_exact_on_the_xla_step():
     assert result["mode"].startswith("simpod mesh")
 
 
+def test_packed_chacha_pallas_phase_refuses_a_cpu_and_is_exact_on_the_xla_step():
+    # pod.flagship.packed_chacha_pallas at toy size: the kernel's PRNG is
+    # the chip's, so the phase fails here as it must; the same scheme and
+    # masks on the XLA step reveal the plain sum
+    with pytest.raises(chip_smoke.PhaseFailed, match="rc=1"):
+        chip_smoke.pod_round(8, 99, pallas=True, mask="chacha")
+    result = chip_smoke.pod_round(8, 99, mask="chacha")
+    assert result["exact"] is True and result["pallas"] is False
+
+
 def test_additive_sharing_refuses_the_kernel_before_any_round():
     with pytest.raises(chip_smoke.PhaseFailed, match="rc=1"):
         chip_smoke.pod_round(8, 99, clerks=3, sharing="additive", pallas=True)

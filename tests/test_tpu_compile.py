@@ -179,3 +179,48 @@ def test_share_stage_runs_one_cipher_block_a_draw_once(share_stage_compiled):
     total taken by a second reduce, which recomputes the cipher in both
     consumers, 5.523e9."""
     assert 2.0e9 < share_stage_compiled.cost_analysis()["flops"] < 3.2e9
+
+
+# -- packed Shamir under ChaCha masks on the kernel path (PR 35): the masks'
+# sum is made 8 rows at a time in front of the kernel. Until then the whole
+# [S, d] block went through ``_mask_stage`` at once: 22 MB of temporaries a
+# row, 6.16 GB at 300 rows, and 1200 rows were refused by the compiler.
+
+PADDED_DIM = 1_000_008  # 999,999 at the grain lcm(3, 8)
+
+
+@pytest.fixture(scope="module")
+def packed_chacha_rounds_compiled(one_chip):
+    """``SimulatedPod(packed 3/8/4, ChaChaMasking, use_pallas=True)``'s
+    round at 64 and at 128 rows of the benchmark's padded width."""
+    from jax.sharding import Mesh
+
+    from sda_tpu.fields import numtheory
+    from sda_tpu.protocol import PackedShamirSharing
+
+    t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
+    assert p == MODULUS
+    mesh = Mesh([[one_chip._device]], ("p", "d"))
+    pod = simpod.SimulatedPod(PackedShamirSharing(3, 8, t, p, w2, w3),
+                              ChaChaMasking(p, 999_999, 128), mesh=mesh, use_pallas=True)
+    return {rows: _compile_for(one_chip, pod.aggregate_fn(rows, PADDED_DIM),
+                               ((rows, PADDED_DIM), jnp.uint32), ((2,), jnp.uint32))
+            for rows in (64, 128)}
+
+
+def test_packed_chacha_round_holds_one_block_of_masks_whatever_the_rows(
+        packed_chacha_rounds_compiled):
+    """Twice the rows, the same temporaries to within one block's (they are
+    equal: 727,917,056 B at 300, 600 and 1200 rows, PERF.md), the kernel in
+    the program, and no array of the rows' extent but the input: no
+    ``[S, ...]`` draws, masks or masked inputs are written."""
+    small, large = (packed_chacha_rounds_compiled[rows] for rows in (64, 128))
+    temporaries = [c.memory_analysis().temp_size_in_bytes for c in (small, large)]
+    assert abs(temporaries[1] - temporaries[0]) < 100e6, temporaries
+    assert max(temporaries) < 1e9, temporaries
+    for rows, compiled in packed_chacha_rounds_compiled.items():
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and "sda.mask.fold" in text
+        wide = {dims for dims in _written_shapes(text)
+                if math.prod(dims) >= rows * PADDED_DIM}
+        assert wide == {(rows, PADDED_DIM)}, wide
