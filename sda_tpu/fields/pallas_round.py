@@ -11,7 +11,9 @@ tile the kernel takes that block once, draws every participant's masks and
 share randomness on-core (pltpu PRNG), folds the draws in VMEM and runs
 the share contraction on the folds into ``[n, TB]`` accumulators. It reads
 ``[k, B]`` and writes accumulator-sized outputs; masks and share
-randomness never touch HBM.
+randomness never touch HBM. The participants run as whole blocks of 16 and
+one tail of what is left: one draw, one fold and one contraction a block,
+whatever divides their number.
 
 Algebra is the uint32 Solinas fast field (see fastfield.py — same bounds,
 same helpers; fastfield's jnp ops compose inside Pallas kernels). The
@@ -19,12 +21,16 @@ share matrix M is host-side, so every multiply in the unrolled row loop is
 a constant mulmod.
 
 Randomness: `internal` mode uses the TPU per-core PRNG
-(pltpu.prng_random_bits) seeded per (seed, tile); masks cancel within the
-round, so the round stays exact. `external` mode takes pre-drawn bits as
-an input — it exists so the arithmetic is bit-checkable under
-``interpret=True`` on CPU (the TPU PRNG primitive is hardware-only) and is
-also what a protocol-grade deployment would use to inject threefry/ChaCha
-streams (reference mask PRGs: client/src/crypto/masking/*.rs).
+(pltpu.prng_random_bits) seeded per (seed, dim tile); masks cancel within
+the round, so the round stays exact. Nothing streams along the
+participants there, so the grid is the dim tiles alone. `external` mode
+takes pre-drawn bits as an input, streamed through VMEM a tile of
+participants at a time along a second grid axis — it exists so the
+arithmetic is bit-checkable under ``interpret=True`` on CPU (the TPU PRNG
+primitive is hardware-only; each grid step folds the same blocks and tail
+the chip folds) and is also what a protocol-grade deployment would use to
+inject threefry/ChaCha streams (reference mask PRGs:
+client/src/crypto/masking/*.rs).
 
 Callers: ``mesh.simpod._pallas_stage``, the one stage around the kernel
 (the pod, streamed and model-scale steps, with ``use_pallas=True``). This
@@ -65,22 +71,24 @@ def column_tile(B0: int) -> int:
     return 2048 if B0 >= 2048 else max(128, -(-B0 // 128) * 128)
 
 
-def _participant_block(p_block: int, P: int) -> int:
-    """Participants folded per loop step: ``p_block`` clamped to P, shrunk
-    to a divisor of P (the accept-any-P contract: no draw is padded)."""
-    return math.gcd(max(1, min(int(p_block), P)), P)
+def _participant_block(p_block: int, count: int):
+    """(block, tail) for ``count`` participants: whole blocks of ``p_block``
+    clamped to the count fold in the loop, and the ``count mod block`` left
+    over fold once after it, in a draw of their own size. The
+    accept-any-P contract: no draw is padded, and the block does not depend
+    on the count's divisors."""
+    pb = max(1, min(int(p_block), count))
+    return pb, count % pb
 
 
-def _participant_tile(pb: int, rows_per_participant: int, tile: int) -> int:
-    """Participants per grid step along the participant axis, sized as if
-    ``rows_per_participant`` uint32 rows per participant streamed through
-    (double-buffered) ~3MB VMEM blocks: k, plus 2*draws bit rows in
-    external-bits mode (which therefore tiles more finely). The k secret
-    rows no longer stream — the secrets arrive folded — but the count is
-    kept so the blocking (and the PRNG stream per tile) is what it was;
-    re-blocking the participant axis is ROADMAP queue A."""
+def _participant_tile(P: int, rows_per_participant: int, tile: int) -> int:
+    """External-bits mode: participants whose bits one step of the
+    participant grid axis streams — the largest divisor of P whose
+    ``rows_per_participant`` (2*draws) uint32 bit rows fit (double-buffered)
+    ~3MB VMEM blocks. The internal-bits kernel streams nothing along the
+    participants and has no such axis."""
     cap = max(1, 3_000_000 // (rows_per_participant * tile * 4))
-    return max(pb, (cap // pb) * pb)
+    return next(c for c in range(min(P, cap), 0, -1) if P % c == 0)
 
 
 def fused_mask_share_combine(
@@ -107,21 +115,29 @@ def fused_mask_share_combine(
     (2 words per drawn residue; mask rows first when masked) — used for
     interpret-mode tests and injectable PRG streams.
 
-    ``p_block`` participants fold per loop step (fewer, larger PRNG draws
-    and one matmul per block); it shrinks to a divisor of P when needed.
-    ``p_tile`` (a multiple of the effective p_block dividing P; derived
-    from the VMEM budget when None) sets how many participants each
-    grid-axis-1 step draws for (and, in external-bits mode, streams bits
-    for). The mod-p algebra is exact, so neither size ever changes
-    results.
+    The participants fold in whole blocks of ``p_block`` (clamped to their
+    number) inside one loop — one PRNG draw and one share contraction a
+    block — and those left over, ``P mod p_block``, in one tail step after
+    it that draws for exactly that many (`_participant_block`): the block
+    is the same whatever P's divisors are, and no participant is drawn for
+    twice or in vain. With the on-core PRNG nothing streams along the
+    participants, so the grid is the dim tiles alone, each seeded once
+    with (seed, dim tile). External bits do stream: there a second,
+    innermost grid axis takes ``p_tile`` participants' bits a step (a
+    divisor of P; the largest the VMEM budget holds when None) onto the
+    same output block, and each step folds its ``p_tile`` in blocks and a
+    tail as above. The mod-p algebra is exact, so neither size ever
+    changes results from the same bits; the on-core stream's share rows
+    and mask totals depend on both the seeding and the draw shapes, and
+    are pinned by nothing but their cancelling in the aggregate.
 
     ``tree_fold`` replaces the per-slice participant fold (adds on
     [rows, TB] slices, rows = k or t of 8 sublanes per vreg) with a
     halving tree over the flat [pb*rows, TB] block — every add at full
     sublane density, log2(pb) rounds. Bit-identical output (mod-p sums
     are order-free; canon cadence keeps raw partials < 2^32). Applied
-    only when the effective p_block is a power of two >= 2; otherwise
-    the slice fold runs as before.
+    only to a block (or tail) of a power of two >= 2 participants;
+    otherwise the slice fold runs as before.
     """
     P = int(participants)
     k, B = x_sum.shape
@@ -133,46 +149,37 @@ def fused_mask_share_combine(
         raise ValueError(f"participants={P} must be at least 1")
     if B % tile:
         raise ValueError(f"B={B} must be divisible by tile={tile}")
-    pb = _participant_block(p_block, P)
     draws = (k + t) if masked else t
     internal = external_bits is None
-    if not internal and external_bits.shape != (P, 2 * draws, B):
-        raise ValueError(
-            f"external_bits {external_bits.shape} != "
-            f"[P, 2*draws, B] = {(P, 2 * draws, B)}"
-        )
-    # participants are drawn for in tiles of p_tile along a second
-    # (reduction) grid axis — external bits for all P in one block OOM
-    # VMEM beyond a few hundred participants
-    rows = k if internal else k + 2 * draws
-    if p_tile is None:
-        p_tile = min(P, _participant_tile(pb, rows, tile))
-        p_tile = math.gcd(p_tile, P) if P % p_tile else p_tile
-    p_tile = int(p_tile)
-    if P % p_tile or p_tile % pb:
-        raise ValueError(
-            f"p_tile={p_tile} must divide P={P} and be a multiple of "
-            f"p_block={pb}"
-        )
+    if internal:
+        per_step = P
+    else:
+        if external_bits.shape != (P, 2 * draws, B):
+            raise ValueError(
+                f"external_bits {external_bits.shape} != "
+                f"[P, 2*draws, B] = {(P, 2 * draws, B)}"
+            )
+        # external bits for all P in one block OOM VMEM beyond a few
+        # hundred participants: they stream in tiles of p_tile along a
+        # second (reduction) grid axis
+        per_step = int(
+            _participant_tile(P, 2 * draws, tile) if p_tile is None else p_tile)
+        if P % per_step:
+            raise ValueError(f"p_tile={per_step} must divide P={P}")
+    pb, tail = _participant_block(p_block, per_step)
 
     def kernel(*refs):
         if internal:
             seed_ref, x_ref, mh_ref, ml_ref, shares_ref, masktot_ref = refs
+            # one distinct stream per dim tile
+            pltpu.prng_seed(seed_ref[0], pl.program_id(0))
         else:
             seed_ref, x_ref, mh_ref, ml_ref, bits_ref, shares_ref, masktot_ref = refs
-        if internal:
-            # one distinct stream per (dim tile, participant tile); Mosaic
-            # caps prng_seed at 2 values, so flatten the grid coordinates
-            pltpu.prng_seed(
-                seed_ref[0],
-                pl.program_id(0) * jnp.int32(P // p_tile) + pl.program_id(1),
-            )
 
         # raw uint32 partial sums stay exact for `fan` canonical residues
         fan = max(1, 0xFFFFFFFF // (sp.p - 1))
         # tree mode: raw-add levels between canons (2^L canonical terms
-        # stay < 2^32); slice-fold applies when pb is not a power of two
-        use_tree = tree_fold and pb >= 2 and (pb & (pb - 1)) == 0
+        # stay < 2^32)
         max_lvl = max(1, int(math.floor(math.log2(fan))))
 
         def fold_slices(get, count):
@@ -204,30 +211,29 @@ def fused_mask_share_combine(
                     lvl = 0
             return arr
 
-        def fold_block(arr, group_rows):
-            """Σ of the pb stacked [group_rows, TB] slices (canonical)."""
-            if use_tree:
-                return tree_fold_block(arr, group_rows)
-            return fold_slices(
-                lambda i: arr[i * group_rows: (i + 1) * group_rows], pb)
-
-        def draw_sum(rows, row0, p0):
-            """Σ over the pb participants of [rows, TB] uniform residues."""
+        def draw_sum(rows, row0, p0, nb):
+            """Σ over the ``nb`` participants from ``p0`` of [rows, TB]
+            uniform residues."""
+            # the slice fold applies when nb is not a power of two
+            use_tree = tree_fold and nb >= 2 and (nb & (nb - 1)) == 0
             if internal:
                 bits = pltpu.bitcast(
-                    pltpu.prng_random_bits((2 * pb * rows, tile)), _U32
+                    pltpu.prng_random_bits((2 * nb * rows, tile)), _U32
                 )
-                hi = bits[: pb * rows, :]
-                lo = bits[pb * rows :, :]
-                res = _uniform_from_bits(hi, lo, sp)          # [pb*rows, TB]
-                return fold_block(res, rows)
-            blk = bits_ref[pl.ds(p0, pb)]                     # [pb, 2*draws, TB]
+                hi = bits[: nb * rows, :]
+                lo = bits[nb * rows :, :]
+                res = _uniform_from_bits(hi, lo, sp)          # [nb*rows, TB]
+                if use_tree:
+                    return tree_fold_block(res, rows)
+                return fold_slices(
+                    lambda i: res[i * rows: (i + 1) * rows], nb)
+            blk = bits_ref[pl.ds(p0, nb)]                     # [nb, 2*draws, TB]
             hi = blk[:, 2 * row0 : 2 * row0 + rows, :]
             lo = blk[:, 2 * row0 + rows : 2 * (row0 + rows), :]
-            res = _uniform_from_bits(hi, lo, sp)              # [pb, rows, TB]
+            res = _uniform_from_bits(hi, lo, sp)              # [nb, rows, TB]
             if use_tree:
-                return tree_fold_block(res.reshape(pb * rows, tile), rows)
-            return fold_slices(lambda i: res[i], pb)
+                return tree_fold_block(res.reshape(nb * rows, tile), rows)
+            return fold_slices(lambda i: res[i], nb)
 
         # matrix limb columns: first k drive the (masked) secrets, last t
         # the share randomness
@@ -243,40 +249,51 @@ def fused_mask_share_combine(
         # vs the per-participant XLA path given the same bits: mod-p
         # arithmetic is exact, so fold order is free.
 
-        # the participant axis (grid dim 1) revisits the same output block:
-        # on the first visit it takes the share of the folded secrets
-        # (their block index ignores that axis: one fetch per dim tile),
-        # the rest accumulate the draws' shares onto it
-        @pl.when(pl.program_id(1) == 0)
-        def _init():
+        def start():
+            # the outputs start from the share of the folded secrets.
             # canon at first touch: the contraction's limb bounds need
             # terms < p, and the docstring contract is otherwise unenforced
             shares_ref[...] = fastfield.modmatmul32_limbs(
                 mh_k, ml_k, canon32(x_ref[...], sp), sp)      # [n, TB]
             masktot_ref[...] = jnp.zeros_like(masktot_ref)
 
-        def body(b_ix, carry):
-            p0 = b_ix * np.int32(pb)
+        if internal:
+            start()
+        else:
+            # the participant axis (grid dim 1) revisits the same output
+            # block: the first visit starts it (the secrets' block index
+            # ignores that axis: one fetch per dim tile), every visit
+            # accumulates its tile's draws onto it
+            pl.when(pl.program_id(1) == 0)(start)
+
+        def fold_in(p0, nb):
+            """The draws of the ``nb`` participants from ``p0`` (of this
+            step's), shared and accumulated onto the outputs."""
             if masked:
-                masksum = draw_sum(k, 0, p0)                  # [k, TB]
+                masksum = draw_sum(k, 0, p0, nb)              # [k, TB]
                 masktot_ref[...] = modadd32(masktot_ref[...], masksum, sp)
                 contrib = modadd32(
                     fastfield.modmatmul32_limbs(mh_k, ml_k, masksum, sp),
                     fastfield.modmatmul32_limbs(
-                        mh_t, ml_t, draw_sum(t, k, p0), sp),
+                        mh_t, ml_t, draw_sum(t, k, p0, nb), sp),
                     sp,
                 )                                             # [n, TB]
             else:
                 contrib = fastfield.modmatmul32_limbs(
-                    mh_t, ml_t, draw_sum(t, 0, p0), sp)
+                    mh_t, ml_t, draw_sum(t, 0, p0, nb), sp)
             shares_ref[...] = modadd32(shares_ref[...], contrib, sp)
+
+        def body(b_ix, carry):
+            fold_in(b_ix * np.int32(pb), pb)
             return carry  # int32 zero: Mosaic cannot legalize an i64 carry
 
         # int32 bounds AND carry: under x64, Python-int bounds make the loop
         # index i64, which Mosaic cannot legalize
         jax.lax.fori_loop(
-            jnp.int32(0), jnp.int32(p_tile // pb), body, jnp.int32(0)
+            jnp.int32(0), jnp.int32(per_step // pb), body, jnp.int32(0)
         )
+        if tail:  # static: traced once, after the loop, at its own size
+            fold_in(per_step - tail, tail)
 
     # host-side limb split of the active share-matrix columns (minus the
     # fixed zero column 0); tiny [n, m2-1] blocks, same in every grid step
@@ -284,26 +301,32 @@ def fused_mask_share_combine(
     mh_np = (m_active >> 15).astype(np.uint32)
     ml_np = (m_active & 0x7FFF).astype(np.uint32)
 
-    # grid dim 0: dim tiles; grid dim 1 (innermost): participant tiles
-    # accumulated into the same output block
-    grid = (B // tile, P // p_tile)
+    # grid dim 0: dim tiles. External bits add grid dim 1 (innermost): the
+    # participant tiles, accumulated into the same output block; the index
+    # maps of everything else ignore it
+    def block(shape, index):
+        return pl.BlockSpec(
+            shape, lambda i, *j: index(i), memory_space=pltpu.VMEM)
+
+    grid = (B // tile,)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                     # seed
-        pl.BlockSpec((k, tile), lambda i, j: (0, i), memory_space=pltpu.VMEM),
-        pl.BlockSpec(mh_np.shape, lambda i, j: (0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec(ml_np.shape, lambda i, j: (0, 0), memory_space=pltpu.VMEM),
+        block((k, tile), lambda i: (0, i)),
+        block(mh_np.shape, lambda i: (0, 0)),
+        block(ml_np.shape, lambda i: (0, 0)),
     ]
     args = [jnp.asarray([seed], jnp.int32), x_sum,
             jnp.asarray(mh_np), jnp.asarray(ml_np)]
     if not internal:
+        grid += (P // per_step,)
         in_specs.append(
-            pl.BlockSpec((p_tile, 2 * draws, tile), lambda i, j: (j, 0, i),
+            pl.BlockSpec((per_step, 2 * draws, tile), lambda i, j: (j, 0, i),
                          memory_space=pltpu.VMEM)
         )
         args.append(external_bits)
     out_specs = [
-        pl.BlockSpec((n, tile), lambda i, j: (0, i), memory_space=pltpu.VMEM),
-        pl.BlockSpec((k, tile), lambda i, j: (0, i), memory_space=pltpu.VMEM),
+        block((n, tile), lambda i: (0, i)),
+        block((k, tile), lambda i: (0, i)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((n, B), _U32),

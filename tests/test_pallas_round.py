@@ -18,6 +18,7 @@ import pytest
 from sda_tpu.fields import fastfield, numtheory
 from sda_tpu.fields.pallas_round import (
     _participant_block,
+    _participant_tile,
     _uniform_from_bits,
     column_tile,
     fused_mask_share_combine,
@@ -97,14 +98,25 @@ def test_pallas_kernel_matches_xla_shares_same_bits():
     SameBits(P=4, seed=22, masked=True).assert_kernel_matches_xla()
 
 
-def test_pallas_round_streams_participant_tiles():
-    """P larger than one participant tile: the kernel's second grid axis
-    must zero-init on the first visit and accumulate across revisits of
-    the same output block (the lenet-60k VMEM-OOM regression: all P in
-    one block). p_tile=32 with P=96 forces 3 grid-axis-1 steps — the auto
-    tile would fit all of P in one block at these shapes and never
-    exercise the revisit path."""
-    SameBits(P=96, seed=23, masked=True).assert_kernel_matches_xla(p_tile=32)
+@pytest.mark.parametrize("masked", [True, False], ids=["masked", "mask-free"])
+@pytest.mark.parametrize("P,p_tile", [
+    (96, 32),    # three visits of two whole blocks of 16
+    (100, 50),   # two visits, each three blocks and a tail of 2
+    (100, 20),   # five visits, each one block and a tail of 4
+])
+def test_pallas_round_streams_participant_tiles(P, p_tile, masked):
+    """P larger than one participant tile: with external bits the kernel's
+    second grid axis must start the output block on the first visit and
+    accumulate across revisits of it (the lenet-60k VMEM-OOM regression:
+    all P in one block). An explicit ``p_tile`` forces the revisits — the
+    auto tile would fit all of P in one block at these shapes — and one
+    that is no multiple of 16 makes every visit fold a tail too."""
+    SameBits(P=P, seed=23, masked=masked).assert_kernel_matches_xla(p_tile=p_tile)
+
+
+def test_pallas_round_refuses_a_p_tile_that_does_not_divide_p():
+    with pytest.raises(ValueError, match="p_tile=32 must divide P=100"):
+        SameBits(P=100, seed=23, masked=True).kernel(p_tile=32)
 
 
 def test_pallas_combined_shares_equal_per_participant_sum():
@@ -119,18 +131,34 @@ def test_pallas_round_rejects_generic_prime():
         SimulatedPod(s, mesh=make_mesh(1, 1), use_pallas=True)
 
 
-@pytest.mark.parametrize("p_block,P,effective", [
-    (50, 100, 50), (100, 100, 100),   # divides P: taken as it is
-    (16, 300, 4),                     # the benchmark cells' rows a chip
-    (16, 24, 8), (16, 18, 2),         # shrinks to a divisor, never pads
-    (16, 7, 7),                       # clamps to P
+@pytest.mark.parametrize("masked", [True, False], ids=["masked", "mask-free"])
+@pytest.mark.parametrize("p_block,P,block,tail", [
+    (50, 100, 50, 0), (100, 100, 100, 0),  # divides P: no tail
+    (16, 300, 16, 12),                # the benchmark cells' rows a chip
+    (16, 24, 16, 8), (16, 18, 16, 2),  # never shrinks to a divisor
+    (16, 7, 7, 0), (16, 1, 1, 0), (16, 15, 15, 0),  # clamps to P: one block
+    (16, 16, 16, 0),                  # one block, no tail
+    (16, 17, 16, 1), (16, 33, 16, 1),  # a tail of one
 ])
-def test_pallas_round_divisor_p_blocks(p_block, P, effective):
-    """The participant block is ``p_block`` clamped to P and shrunk to a
-    divisor of P; whatever it comes to, the kernel draws for exactly P
-    participants from the same bits as the XLA path."""
-    assert _participant_block(p_block, P) == effective
-    SameBits(P=P, seed=3, masked=True).assert_kernel_matches_xla(p_block=p_block)
+def test_pallas_round_divisor_p_blocks(p_block, P, block, tail, masked):
+    """The participants fold in whole blocks of ``p_block`` clamped to P,
+    whatever P's divisors, and ``P mod block`` of them in one tail; however
+    it splits, the kernel draws for exactly P participants from the same
+    bits as the XLA path."""
+    assert _participant_block(p_block, P) == (block, tail)
+    SameBits(P=P, seed=3, masked=masked).assert_kernel_matches_xla(p_block=p_block)
+
+
+@pytest.mark.parametrize("P,rows,tile,p_tile", [
+    (300, 14, 2048, 25),      # the cells' shape: 26 fit, 25 divides
+    (60_000, 14, 512, 100),   # lenet-60k
+    (100, 14, 128, 100), (7, 14, 2048, 7),  # all of P fits
+    (1201, 14, 2048, 1),      # a prime over the budget
+])
+def test_external_bits_participant_tile_is_the_largest_divisor_that_fits(
+        P, rows, tile, p_tile):
+    assert _participant_tile(P, rows, tile) == p_tile
+    assert P % p_tile == 0 and p_tile * rows * tile * 4 <= 3_000_000
 
 
 @pytest.mark.parametrize("B0,tile,padded", [
@@ -167,8 +195,15 @@ def test_tree_fold_shares_match_slice_shares_same_bits():
         p_block=32, tree_fold=True)
 
 
+@pytest.mark.parametrize("P", [20, 22])
+def test_tree_fold_folds_a_tail_by_its_own_rule(P):
+    """A block of 16 through the tree, and a tail of 4 through it too or
+    one of 6 through the slice fold: each by its own count."""
+    SameBits(P=P, seed=36, masked=True).assert_kernel_matches_xla(tree_fold=True)
+
+
 def test_tree_fold_non_pow2_p_block_falls_back():
-    """A non-power-of-two effective p_block silently runs the slice fold
-    (the parameter is a no-op, never an error)."""
+    """A block (or tail) of a non-power-of-two count silently runs the
+    slice fold (the parameter is a no-op there, never an error)."""
     SameBits(P=6, seed=35, masked=True).assert_kernel_matches_xla(
         p_block=3, tree_fold=True)

@@ -224,3 +224,76 @@ def test_packed_chacha_round_holds_one_block_of_masks_whatever_the_rows(
         wide = {dims for dims in _written_shapes(text)
                 if math.prod(dims) >= rows * PADDED_DIM}
         assert wide == {(rows, PADDED_DIM)}, wide
+
+
+# -- the fused kernel's grid and draws (PR 37): with the on-core PRNG nothing
+# streams along the participants, so the grid is the dim tiles alone and the
+# participants fold in blocks of 16 whatever their number's divisors; only
+# external bits keep a participant axis. Interpret mode has no on-core PRNG:
+# the internal-bits kernel is reached by Mosaic alone. The participant count
+# is what matters, so two narrow dim tiles keep a compile to about a second.
+
+KERNEL_TILE, KERNEL_COLUMNS = 128, 256
+
+
+def _mosaic_modules(text: str):
+    """The Mosaic modules of a compiled program's kernels, as text."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    context = mlir.make_ir_context()
+    context.allow_unregistered_dialects = True  # the serialised dialect's name
+    with context:
+        return [ir.Module.parse(base64.b64decode(body)).operation.get_asm()
+                for body in re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text)]
+
+
+def _kernel_compiled(one_chip, participants: int, masked: bool, external: bool):
+    from sda_tpu.fields import fastfield, numtheory
+    from sda_tpu.fields.pallas_round import fused_mask_share_combine
+    from sda_tpu.protocol import PackedShamirSharing
+
+    t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
+    scheme = PackedShamirSharing(3, 8, t, p, w2, w3)
+    k = scheme.secret_count
+
+    def kernel(x_sum, seed, *bits):
+        return fused_mask_share_combine(
+            x_sum, participants, seed, fastfield.SolinasPrime.try_from(p),
+            numtheory.share_matrix_for(scheme), t, masked, tile=KERNEL_TILE,
+            external_bits=bits[0] if bits else None)
+
+    args = [((k, KERNEL_COLUMNS), jnp.uint32), ((), jnp.int32)]
+    if external:
+        rows = 2 * ((k + t) if masked else t)
+        args.append(((participants, rows, KERNEL_COLUMNS), jnp.uint32))
+    [module] = _mosaic_modules(_compile_for(one_chip, kernel, *args).as_text())
+    [grid] = re.findall(r"iteration_bounds = array<i64: ([\d, ]+)>", module)
+    draws = re.findall(r"tpu\.prng_random_bits.*?vector<(\d+)x(\d+)xi32>", module)
+    return ([int(n) for n in grid.split(",")],
+            {(int(rows), int(lanes)) for rows, lanes in draws})
+
+
+@pytest.mark.parametrize("participants,masked,draw_rows", [
+    # packed-1m's rows a chip: 18 blocks of 16 (2 words x 16 x 3 mask rows
+    # = 96, x 4 share rows = 128) and one tail of 12 (72 and 96)
+    (300, True, {72, 96, 128}),
+    # packed-chacha-1m's: 75 blocks of 16 share rows, no tail
+    (1200, False, {128}),
+    (7, True, {42, 56}),     # fewer than a block: one draw of their size
+])
+def test_internal_bits_kernel_has_a_one_axis_grid_and_draws_for_blocks_of_16(
+        one_chip, participants, masked, draw_rows):
+    grid, draws = _kernel_compiled(one_chip, participants, masked, external=False)
+    assert grid == [KERNEL_COLUMNS // KERNEL_TILE]
+    assert draws == {(rows, KERNEL_TILE) for rows in draw_rows}
+
+
+def test_external_bits_kernel_keeps_its_participant_axis(one_chip):
+    """1200 participants' bits do not fit one VMEM block: they stream in
+    tiles of 400 (the largest divisor under the budget) along grid axis 1."""
+    grid, draws = _kernel_compiled(one_chip, 1200, True, external=True)
+    assert grid == [KERNEL_COLUMNS // KERNEL_TILE, 3]
+    assert not draws
