@@ -140,11 +140,16 @@ def packed_share32(key, secrets32, share_matrix_host, sp: "fastfield.SolinasPrim
 
 def packed_reconstruct32(shares32, recon_matrix_host, sp: "fastfield.SolinasPrime",
                          *, dimension: int):
-    """[r, B] canonical uint32 clerk rows -> [d] canonical secrets."""
-    zeros = jnp.zeros((1,) + shares32.shape[1:], shares32.dtype)
-    values = jnp.concatenate([zeros, shares32], axis=0)          # [r+1, B]
-    secrets = fastfield.modmatmul32(recon_matrix_host, values, sp)
-    return unbatch_columns(secrets, dimension)
+    """[r, B] canonical uint32 clerk rows -> [d] canonical secrets.
+
+    Two device stages, a scope each (docs/observability.md): the Lagrange
+    product on the column layout, and the layout change back to [d]."""
+    with jax.named_scope("sda.reconstruct.lagrange"):
+        zeros = jnp.zeros((1,) + shares32.shape[1:], shares32.dtype)
+        values = jnp.concatenate([zeros, shares32], axis=0)      # [r+1, B]
+        secrets = fastfield.modmatmul32(recon_matrix_host, values, sp)
+    with jax.named_scope("sda.reconstruct.unbatch"):
+        return unbatch_columns(secrets, dimension)
 
 
 @functools.partial(jax.jit, static_argnames=("prime", "dimension"))
@@ -153,12 +158,14 @@ def packed_reconstruct(shares, recon_matrix, *, prime: int, dimension: int):
 
     recon_matrix is built for the surviving index set
     (numtheory.packed_reconstruct_matrix); the implicit point-1 zero row is
-    prepended here.
+    prepended here. The same two scopes as :func:`packed_reconstruct32`.
     """
-    zeros = jnp.zeros((1,) + shares.shape[1:], shares.dtype)
-    values = jnp.concatenate([zeros, shares], axis=0)            # [r+1, B]
-    secrets = modmatmul(recon_matrix, values, prime)             # [k, B]
-    return unbatch_columns(secrets, dimension)
+    with jax.named_scope("sda.reconstruct.lagrange"):
+        zeros = jnp.zeros((1,) + shares.shape[1:], shares.dtype)
+        values = jnp.concatenate([zeros, shares], axis=0)        # [r+1, B]
+        secrets = modmatmul(recon_matrix, values, prime)         # [k, B]
+    with jax.named_scope("sda.reconstruct.unbatch"):
+        return unbatch_columns(secrets, dimension)
 
 
 packed_reconstruct = devprof.instrument(

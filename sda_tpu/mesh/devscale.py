@@ -61,6 +61,7 @@ from .simpod import (
     _check_mask_modulus,
     _check_masking_supported,
     _dim_grain,
+    _draw_scope,
     _mask_stage,
     _normalize_survivors,
     _pallas_stage,
@@ -255,24 +256,28 @@ class ModelScaleRound:
     def _local_round(self, inputs, key):
         """Per-device body: scan the local [P_loc, d_loc] shard in tiles."""
         import jax
-        import jax.numpy as jnp
 
         f, s, masking = self._field, self.scheme, self.masking
         P_loc, d_loc = inputs.shape
-        pi = jax.lax.axis_index("p")
-        di = jax.lax.axis_index("d")
+        draws = _draw_scope(self.pallas_active)
+        with jax.named_scope(draws):
+            pi = jax.lax.axis_index("p")
+            di = jax.lax.axis_index("d")
 
         def one_tile(blk, round_key, tile_key, i, width):
-            # per-(seed, shard, tile) randomness: scan_dim_tiles folded
-            # the tile index into tile_key; _tile_key separates shards
-            dev_key = _tile_key(tile_key, pi, di)
-            # global stream coordinates of this tile (ChaCha windows)
-            d_block0 = (di * d_loc + i * width) // 8
+            with jax.named_scope(draws):
+                # per-(seed, shard, tile) randomness: scan_dim_tiles folded
+                # the tile index into tile_key; _tile_key separates shards
+                dev_key = _tile_key(tile_key, pi, di)
+                # global stream coordinates of this tile (ChaCha windows)
+                d_block0 = (di * d_loc + i * width) // 8
             x = f.to_residues(blk)
+            with jax.named_scope(draws):
+                pid0 = pi * P_loc
             if self.pallas_active:
                 shares, mask_sum = _pallas_stage(
                     s, f, self._M_host, masking, x, dev_key,
-                    round_key=round_key, pid_base=pi * P_loc,
+                    round_key=round_key, pid_base=pid0,
                     d_block0=d_block0,
                     interpret=self._pallas_interpret,
                     external_bits_fn=self._pallas_bits_fn,
@@ -280,7 +285,7 @@ class ModelScaleRound:
             else:
                 masked, mask_sum, skey = _mask_stage(
                     masking, f, x, dev_key, round_key,
-                    pid_base=pi * P_loc, d_block0=d_block0,
+                    pid_base=pid0, d_block0=d_block0,
                 )
                 shares = _share_sum_stage(s, f, self._M_host, masked, skey)
             with jax.named_scope("sda.clerk_combine"):
@@ -288,9 +293,8 @@ class ModelScaleRound:
                     shares, "p", scatter_dimension=0, tiled=True)
                 rows = f.canon(rows)
                 gathered = jax.lax.all_gather(rows, "p", axis=0, tiled=True)
-            if self.surviving_clerks is not None:
-                gathered = gathered[jnp.asarray(self.surviving_clerks), :]
-            total = _reconstruct_stage(s, f, self._L_host, gathered, width)
+            total = _reconstruct_stage(s, f, self._L_host, gathered, width,
+                                       self.surviving_clerks)
             with jax.named_scope("sda.unmask"):
                 if mask_sum is None:
                     return f.to_int64(total)

@@ -169,6 +169,13 @@ def _tile_key(round_key, *indices):
     return k
 
 
+def _draw_scope(pallas_active: bool) -> str:
+    """The stage scope a local step derives its place on the mesh and its
+    keys under: the stage that draws from them -- the kernel, or the XLA
+    step's share stage (docs/observability.md, "Stage scopes")."""
+    return "sda.mask_share" if pallas_active else "sda.share"
+
+
 def _check_masking_supported(masking) -> None:
     if not isinstance(masking, (NoMasking, FullMasking, ChaChaMasking)):
         raise ValueError(
@@ -428,18 +435,20 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     if pad:  # padded columns are sliced off below; their shares never land
         with jax.named_scope("sda.relayout"):
             x_cols = jnp.pad(x_cols, ((0, 0), (0, pad)))
-    seed = jax.random.randint(dev_key, (), 0, np.int32(2**31 - 1),
-                              dtype=jnp.int32)
-    ext = None
-    if external_bits_fn is not None:
-        draws = (k + t) if masked else t
-        ext = external_bits_fn(dev_key, S, draws, B0 + pad)
+    # the kernel with what it draws from -- its seed (and a test's bits) --
+    # and its share rows cut to the real columns
     with jax.named_scope("sda.mask_share"):
+        seed = jax.random.randint(dev_key, (), 0, np.int32(2**31 - 1),
+                                  dtype=jnp.int32)
+        ext = None
+        if external_bits_fn is not None:
+            draws = (k + t) if masked else t
+            ext = external_bits_fn(dev_key, S, draws, B0 + pad)
         shares, mask_tot = pallas_round.fused_mask_share_combine(
             x_cols, S, seed, f.sp, M_host, t, masked,
             tile=tile, external_bits=ext, interpret=interpret,
         )
-    shares = shares[:, :B0]
+        shares = shares[:, :B0]
     if not masked:
         return shares, chacha_mask_sum
     with jax.named_scope("sda.relayout"):
@@ -466,10 +475,13 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
     P, d = x.shape
     chunk, padded_rows = _scan_rows(P, chunk)
     pad = padded_rows - P
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad, d), x.dtype)], axis=0)
-    nblk = x.shape[0] // chunk
-    xb = x.reshape(nblk, chunk, d)
+    # sda.blocks: the cohort cut into the scan's blocks -- the zero rows,
+    # the reshape, the block counter and a block's place in the cohort
+    with jax.named_scope("sda.blocks"):
+        if pad:
+            x = jnp.concatenate([x, jnp.zeros((pad, d), x.dtype)], axis=0)
+        nblk = x.shape[0] // chunk
+        xb = x.reshape(nblk, chunk, d)
     n = scheme.output_size
     B = d // scheme.input_size
     has_mask = not isinstance(masking, NoMasking)
@@ -477,26 +489,41 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
     def body(carry, blk_i):
         acc_s, acc_m = carry
         blk, i = blk_i
-        bkey = jax.random.fold_in(key, i)
+        with jax.named_scope("sda.share"):
+            bkey = jax.random.fold_in(key, i)
+        with jax.named_scope("sda.blocks"):
+            pid_base = pid0 + i * chunk
         masked, mask_sum, skey = _mask_stage(
             masking, f, blk, bkey, round_key,
-            pid_base=pid0 + i * chunk, d_block0=dblk0,
+            pid_base=pid_base, d_block0=dblk0,
         )
-        acc_s = f.add(acc_s, _share_sum_stage(scheme, f, M_host, masked, skey))
+        # an accumulator's add stands under the stage whose result it adds
+        shares = _share_sum_stage(scheme, f, M_host, masked, skey)
+        with jax.named_scope("sda.share"):
+            acc_s = f.add(acc_s, shares)
         if mask_sum is not None:
-            acc_m = f.add(acc_m, mask_sum)
+            with jax.named_scope("sda.mask"), jax.named_scope("sda.mask.fold"):
+                acc_m = f.add(acc_m, mask_sum)
         return (acc_s, acc_m), None
 
-    init = (jnp.zeros((n, B), f.dtype), jnp.zeros((d,), f.dtype))
-    (acc_s, acc_m), _ = jax.lax.scan(
-        body, init, (xb, jnp.arange(nblk, dtype=jnp.int32))
-    )
+    with jax.named_scope("sda.share"):
+        init_s = jnp.zeros((n, B), f.dtype)
+    with jax.named_scope("sda.mask"), jax.named_scope("sda.mask.fold"):
+        init_m = jnp.zeros((d,), f.dtype)
+    with jax.named_scope("sda.blocks"):
+        counter = jnp.arange(nblk, dtype=jnp.int32)
+    (acc_s, acc_m), _ = jax.lax.scan(body, (init_s, init_m), (xb, counter))
     return acc_s, (acc_m if has_mask else None)
 
 
-def _reconstruct_stage(scheme, f: FieldOps, L_host, gathered, d_loc: int):
-    """[n, B] clerk rows -> [d_loc] masked totals."""
+def _reconstruct_stage(scheme, f: FieldOps, L_host, gathered, d_loc: int,
+                       survivors=None):
+    """[n, B] clerk rows -> [d_loc] masked totals, from the ``survivors``
+    rows alone where a quorum is given (clerk dropout: the rows a lost
+    device or process hosted never enter the reconstruction)."""
     with jax.named_scope("sda.reconstruct"):
+        if survivors is not None:
+            gathered = gathered[jnp.asarray(survivors), :]
         if isinstance(scheme, SHAMIR_SCHEMES):
             if f.sp is not None:
                 return sharing.packed_reconstruct32(
@@ -654,20 +681,23 @@ class SimulatedPod:
         """Per-device body under shard_map: inputs [P_loc, d_loc]."""
         f = self._field
         P_loc, d_loc = inputs.shape
-        pi = jax.lax.axis_index("p")
-        di = jax.lax.axis_index("d")
-        # distinct randomness per device block, domain-separated from the
-        # ChaCha seed stream; seeds fold the raw round key so every dim
-        # shard derives the same per-participant seed
-        dev_key = _tile_key(key, pi, di)
+        draws = _draw_scope(self.pallas_active)
+        with jax.named_scope(draws):
+            pi = jax.lax.axis_index("p")
+            di = jax.lax.axis_index("d")
+            # distinct randomness per device block, domain-separated from
+            # the ChaCha seed stream; seeds fold the raw round key so every
+            # dim shard derives the same per-participant seed
+            dev_key = _tile_key(key, pi, di)
 
         x = f.to_residues(inputs)
+        with jax.named_scope(draws):  # behind the residue pass, as it lowers
+            pid0, dblk0 = pi * P_loc, di * (d_loc // 8)
         if self.pallas_active:
             # fused mask+share+combine in one HBM pass (pallas_round.py)
             local_sum, local_mask_sum = _pallas_stage(
                 self.scheme, f, self._M_host, self.masking, x, dev_key,
-                round_key=key, pid_base=pi * P_loc,
-                d_block0=di * (d_loc // 8),
+                round_key=key, pid_base=pid0, d_block0=dblk0,
                 interpret=self._pallas_interpret,
                 external_bits_fn=self._pallas_bits_fn,
             )                                                      # [n, B_loc]
@@ -676,8 +706,7 @@ class SimulatedPod:
             # tensor stays [chunk, n, B_loc], never [P_loc, n, B_loc])
             local_sum, local_mask_sum = _scan_combine(
                 f, self.scheme, self.masking, self._M_host, x, dev_key, key,
-                pid0=pi * P_loc, dblk0=di * (d_loc // 8),
-                chunk=self.scan_chunk,
+                pid0=pid0, dblk0=dblk0, chunk=self.scan_chunk,
             )                                                      # [n, B_loc]
 
         # snapshot transpose + clerk combine == one psum_scatter over ICI:
@@ -691,12 +720,9 @@ class SimulatedPod:
             # recipient gathers all clerk rows (clerk -> recipient leg)
             gathered = jax.lax.all_gather(clerk_rows, "p", axis=0, tiled=True)
 
-        if self.surviving_clerks is not None:
-            # clerk dropout: reveal from the quorum's rows only — lost
-            # rows (dead device/process) never enter the reconstruct
-            gathered = gathered[jnp.asarray(self.surviving_clerks), :]
         masked_total = _reconstruct_stage(
-            self.scheme, f, self._L_host, gathered, d_loc
+            self.scheme, f, self._L_host, gathered, d_loc,
+            self.surviving_clerks,
         )                                                          # [d_loc]
 
         with jax.named_scope("sda.unmask"):

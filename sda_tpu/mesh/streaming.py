@@ -54,6 +54,7 @@ from .simpod import (
     _check_mask_modulus,
     _check_masking_supported,
     _dim_grain,
+    _draw_scope,
     _build_matrices,
     _mask_stage,
     _normalize_survivors,
@@ -568,8 +569,8 @@ class StreamingAggregator:
                 acc_shares = f.add(acc_shares, shares)
                 if mask_sum is not None:
                     acc_mask = f.add(acc_mask, mask_sum)
-            # the undonated scalar _drive_tiles waits on
-            return acc_shares, acc_mask, acc_shares[0, 0]
+                # the undonated scalar _drive_tiles waits on
+                return acc_shares, acc_mask, acc_shares[0, 0]
 
         # one "stream.step" profile for every block shape: the compiled-
         # shape registry is how the "at most 2-3 shapes per axis" claim
@@ -582,13 +583,12 @@ class StreamingAggregator:
         mask = not isinstance(self.masking, NoMasking)
 
         def final(acc_shares, acc_mask):
-            if self.surviving_clerks is not None:
-                # clerk dropout: reveal from the quorum's rows only
-                acc_shares = acc_shares[jnp.asarray(self.surviving_clerks), :]
-            total = _reconstruct_stage(s, f, self._L_host, acc_shares, d_size)
-            if mask:
-                total = f.sub(total, acc_mask)
-            return f.to_int64(total)
+            total = _reconstruct_stage(s, f, self._L_host, acc_shares, d_size,
+                                       self.surviving_clerks)
+            with jax.named_scope("sda.unmask"):
+                if mask:
+                    total = f.sub(total, acc_mask)
+                return f.to_int64(total)
 
         return devprof.instrument("stream.finale",
                                   jax.jit(final, donate_argnums=(0, 1)))
@@ -760,36 +760,39 @@ class StreamedPod:
         def local_step(block, tile_key, round_key, tile_base, d_block_base,
                        acc_shares, acc_mask):
             # block [Pc_loc, d_loc]; acc_shares [n, B_loc]; acc_mask [1, d_loc]
-            pi = jax.lax.axis_index("p")
-            di = jax.lax.axis_index("d")
+            draws = _draw_scope(self.pallas_active)
             Pc_loc, d_loc = block.shape
-            dev_key = jax.random.fold_in(jax.random.fold_in(tile_key, pi), di)
+            with jax.named_scope(draws):
+                pi = jax.lax.axis_index("p")
+                di = jax.lax.axis_index("d")
+                dev_key = jax.random.fold_in(
+                    jax.random.fold_in(tile_key, pi), di)
             x = f.to_residues(block)
+            with jax.named_scope(draws):
+                pid0 = tile_base + pi * Pc_loc
+                dblk0 = d_block_base + di * (d_loc // 8)
             if self.pallas_active:
                 # fused mask+share+combine in one HBM pass (pallas_round.py)
                 shares, local_mask_sum = _pallas_stage(
                     s, f, self._M_host, masking, x, dev_key,
-                    round_key=round_key,
-                    pid_base=tile_base + pi * Pc_loc,
-                    d_block0=d_block_base + di * (d_loc // 8),
+                    round_key=round_key, pid_base=pid0, d_block0=dblk0,
                     interpret=self._pallas_interpret,
                     external_bits_fn=self._pallas_bits_fn,
                 )
             else:
                 masked, local_mask_sum, skey = _mask_stage(
                     masking, f, x, dev_key, round_key,
-                    pid_base=tile_base + pi * Pc_loc,
-                    d_block0=d_block_base + di * (d_loc // 8),
+                    pid_base=pid0, d_block0=dblk0,
                 )
                 shares = _share_sum_stage(s, f, self._M_host, masked, skey)
             with jax.named_scope("sda.stream.acc"):
                 acc_shares = f.add(acc_shares, shares)
                 if local_mask_sum is not None:
                     acc_mask = f.add(acc_mask, local_mask_sum[None, :])
-            # the undonated handle _drive_tiles waits on: one element a
-            # device, so the wait covers every device and needs no
-            # collective
-            return acc_shares, acc_mask, acc_shares[:1, :1]
+                # the undonated handle _drive_tiles waits on: one element
+                # a device, so the wait covers every device and needs no
+                # collective
+                return acc_shares, acc_mask, acc_shares[:1, :1]
 
         fn = _shard_map(
             local_step,
@@ -813,17 +816,14 @@ class StreamedPod:
                 clerk_rows = f.canon(clerk_rows)
                 gathered = jax.lax.all_gather(
                     clerk_rows, "p", axis=0, tiled=True)
-            if self.surviving_clerks is not None:
-                # clerk dropout: rows hosted on a lost device/process never
-                # enter the reconstruct — the quorum reveals exactly
-                gathered = gathered[jnp.asarray(self.surviving_clerks), :]
             masked_total = _reconstruct_stage(
-                s, f, self._L_host, gathered, d_loc
+                s, f, self._L_host, gathered, d_loc, self.surviving_clerks
             )
-            if not masked:
-                return f.to_int64(masked_total)
-            mask_total = f.canon(jax.lax.psum(acc_mask[0], "p"))
-            return f.to_int64(f.sub(masked_total, mask_total))
+            with jax.named_scope("sda.unmask"):
+                if not masked:
+                    return f.to_int64(masked_total)
+                mask_total = f.canon(jax.lax.psum(acc_mask[0], "p"))
+                return f.to_int64(f.sub(masked_total, mask_total))
 
         fn = _shard_map(
             local_final,

@@ -30,11 +30,12 @@ to show up only as "the round got slower". Three instruments:
   None for a device the table has no complete, sourced row for (today:
   every device, the v5e included).
 
-- **Device-lane attribution** — the round stages run under
-  ``jax.named_scope`` (``sda.mask``/``sda.share``/``sda.clerk_combine``/
-  ``sda.reconstruct``/``sda.unmask``, see ``mesh/simpod.py``), so XProf
-  device lanes merged via ``obs.merge_chrome_traces`` attribute device
-  time to protocol phases by name.
+- **Device-lane attribution** — every device op of a round stands under
+  one ``jax.named_scope`` of a closed list of stage scopes
+  (docs/observability.md, "Stage scopes", holds the list and where each
+  is opened), so XProf device lanes merged via
+  ``obs.merge_chrome_traces`` attribute device time to protocol phases
+  by name.
 
 No ``jax`` import happens at module import time: the HTTP/loadgen
 profiles use ``obs`` without JAX, and a bare import must stay free.

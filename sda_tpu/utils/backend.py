@@ -61,6 +61,12 @@ def arm_compile_cache() -> str | None:
     ``cpu_aot_loader`` "machine type ... doesn't match" line (~3 KB) for
     every executable it reloads, on the very machine that compiled it
     (seen with ``sda-sim`` run twice on this installation, PR 22).
+
+    Wherever a cache is left in force its key takes the ops' metadata
+    in: the stage scopes (docs/observability.md) are the program's
+    device-side spans and live in that metadata, which JAX's key leaves
+    out by default, so an executable cached by a program with other
+    scopes would be loaded with those scopes on every op of the trace.
     """
     import jax
 
@@ -68,12 +74,12 @@ def arm_compile_cache() -> str | None:
 
     devprof.install_monitoring()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if placed:
-        return placed
-    if jax.default_backend() == "cpu":
-        return None
-    jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
-    return _CHECKOUT_CACHE
+    if not placed:
+        if jax.default_backend() == "cpu":
+            return None
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    return placed or _CHECKOUT_CACHE
 
 
 def force_cpu(n_devices: int = 1) -> None:

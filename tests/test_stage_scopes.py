@@ -1,0 +1,194 @@
+"""The stage scopes of a round (docs/observability.md, "Stage scopes"):
+one closed list of ``jax.named_scope`` names, and every device op a round's
+own statements make stands under exactly one of them. Four rounds on the
+CPU (the kernel interpreted and fed external bits where the step is the
+kernel), each held to the plain sum bit for bit; and the compile cache,
+which the program keys on those scopes wherever it leaves one in force."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sda_tpu.fields import numtheory
+from sda_tpu.mesh import StreamingAggregator
+from sda_tpu.mesh.simpod import SimulatedPod, make_mesh
+from sda_tpu.protocol import (AdditiveSharing, ChaChaMasking, FullMasking,
+                              PackedShamirSharing)
+from sda_tpu.utils import backend
+
+from util import external_bits
+
+MODULUS = 536870233  # 2^29 - 679: the uint32 fast path
+ROWS, DIM = 13, 96   # a ragged second scan block; whole ChaCha blocks
+INTERPRETED = dict(pallas_interpret=True, pallas_external_bits_fn=external_bits)
+
+#: the list, as docs/observability.md holds it
+STAGES = {"sda.residues", "sda.fold", "sda.blocks", "sda.mask", "sda.share",
+          "sda.relayout", "sda.mask_share", "sda.clerk_combine",
+          "sda.reconstruct", "sda.unmask", "sda.stream.acc"}
+CHILDREN = {"sda.mask.chacha", "sda.mask.reduce", "sda.mask.relayout",
+            "sda.mask.fold", "sda.reconstruct.lagrange",
+            "sda.reconstruct.unbatch"}
+CHACHA = {"sda.mask", "sda.mask.chacha", "sda.mask.reduce",
+          "sda.mask.relayout", "sda.mask.fold"}
+LAGRANGE = {"sda.reconstruct", "sda.reconstruct.lagrange",
+            "sda.reconstruct.unbatch"}
+KERNEL = {"sda.residues", "sda.fold", "sda.relayout", "sda.mask_share"}
+#: what each round's lowered programs should name, and nothing else
+EXPECTED = {
+    "packed-full-kernel":
+        KERNEL | LAGRANGE | {"sda.clerk_combine", "sda.unmask"},
+    "packed-chacha-kernel":
+        KERNEL | CHACHA | LAGRANGE | {"sda.clerk_combine", "sda.unmask"},
+    # additive: the reconstruction is a plain sum of the rows, no product
+    "additive-chacha-xla":
+        {"sda.residues", "sda.blocks", "sda.share", "sda.clerk_combine",
+         "sda.reconstruct", "sda.unmask"} | CHACHA,
+    "streamed-step-and-finale":
+        KERNEL | LAGRANGE | {"sda.stream.acc", "sda.unmask"},
+}
+#: what ``lax.scan`` lowers to around a body that stands under no stage (the
+#: XLA step's): its counter, its test, the slice of a block: no statement's
+SCAN_OWN = re.compile(
+    r"^(jit\([^/]*\)/)*while/(cond/lt|body/(add|dynamic_slice|squeeze))$")
+
+
+def _packed() -> PackedShamirSharing:
+    t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
+    assert p == MODULUS
+    return PackedShamirSharing(3, 8, t, p, w2, w3)
+
+
+def _inputs() -> np.ndarray:
+    return np.random.default_rng(38).integers(
+        0, 1 << 20, size=(ROWS, DIM), dtype=np.int64)
+
+
+def _round(name: str):
+    """-> (lowered programs, the round's aggregate of ``_inputs()``)."""
+    inputs, key = _inputs(), jax.random.PRNGKey(38)
+    if name == "streamed-step-and-finale":
+        agg = StreamingAggregator(_packed(), FullMasking(MODULUS),
+                                  participants_chunk=8, use_pallas=True,
+                                  **INTERPRETED)
+        dtype, scalar = agg._field.dtype, jax.ShapeDtypeStruct((), jnp.int32)
+        keys = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        accs = (jax.ShapeDtypeStruct((8, DIM // 3), dtype),
+                jax.ShapeDtypeStruct((DIM,), dtype))
+        lowered = [
+            agg._step_fn((8, DIM)).lower(
+                jax.ShapeDtypeStruct((8, DIM), jnp.int64), keys, keys,
+                scalar, scalar, *accs),
+            agg._final_fn(DIM).lower(*accs)]
+        return lowered, agg.aggregate(inputs, key)
+    scheme = (AdditiveSharing(3, MODULUS) if name.startswith("additive")
+              else _packed())
+    masking = (ChaChaMasking(MODULUS, DIM, 128) if "chacha" in name
+               else FullMasking(MODULUS))
+    kernel = name.endswith("kernel")
+    pod = SimulatedPod(scheme, masking, mesh=make_mesh(1, 1),
+                       use_pallas=kernel, **(INTERPRETED if kernel else {}))
+    assert pod.pallas_active is kernel
+    rows, dim = pod.padded_shape(ROWS, DIM)
+    assert (rows, dim) == (ROWS, DIM)
+    step = pod.aggregate_fn(rows, dim)
+    lowered = [step.lower(jax.ShapeDtypeStruct((rows, dim), jnp.uint32),
+                          jax.ShapeDtypeStruct((2,), jnp.uint32))]
+    return lowered, np.asarray(step(jnp.asarray(inputs, jnp.uint32), key))
+
+
+def _op_paths(lowered):
+    """(op name, name-stack path) of every op of a lowered program, a
+    callee's ops under the path of each of its call sites -- as the
+    compiler's inliner composes ``op_name``, which the profiler shows."""
+    module = lowered.compiler_ir()
+    functions = {str(op.attributes["sym_name"]).strip('"'): op
+                 for op in module.body.operations
+                 if op.operation.name == "func.func"}
+
+    def path_of(op):
+        match = re.search(r'loc\("([^"]*)"', str(op.location))
+        return match.group(1).split("/") if match else []
+
+    def visit(op, prefix):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    kind = inner.operation.name
+                    path = prefix + path_of(inner)
+                    if kind == "func.call":
+                        callee = str(inner.attributes["callee"]).lstrip("@")
+                        yield from visit(functions[callee.strip('"')], path)
+                    elif inner.regions:     # while, shard_map: their bodies
+                        yield from visit(inner, prefix)
+                    elif kind not in ("func.return", "stablehlo.return",
+                                      "sdy.return", "stablehlo.constant"):
+                        yield kind, path      # a constant is no op's work
+
+    return list(visit(functions["main"], []))
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_a_round_names_its_stages_and_reveals_the_plain_sum(name):
+    lowered, aggregate = _round(name)
+    text = "\n".join(low.as_text(debug_info=True) for low in lowered)
+    named = set(re.findall(r'(?<=[/"])sda\.[\w.]+(?=[/"])', text))
+    assert named == EXPECTED[name]
+    assert named <= STAGES | CHILDREN
+    # sda.blocks is the XLA step's scan; the two children of sda.reconstruct
+    # wherever there is a Lagrange product
+    assert ("sda.blocks" in named) == name.endswith("xla")
+    assert (LAGRANGE <= named) == (not name.startswith("additive"))
+    want = _inputs().sum(axis=0) % MODULUS
+    assert aggregate.dtype == np.int64 and np.array_equal(aggregate, want)
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_every_op_of_a_round_stands_under_exactly_one_stage(name):
+    lowered, _ = _round(name)
+    ops = [op for low in lowered for op in _op_paths(low)]
+    assert len(ops) > 100
+    stray = [(kind, "/".join(path)) for kind, path in ops
+             if len(STAGES.intersection(path)) != 1
+             and not SCAN_OWN.match("/".join(path))]
+    assert stray == []
+    # a child scope stands under its parent, nowhere else
+    for kind, path in ops:
+        for child in CHILDREN.intersection(path):
+            assert child.rsplit(".", 1)[0] in path[:path.index(child)]
+    # only the XLA step's scan stands under no stage
+    own = [path for _, path in ops if not STAGES.intersection(path)]
+    assert bool(own) == name.endswith("xla")
+
+
+@pytest.fixture
+def cache_config(monkeypatch):
+    before = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_compilation_cache_include_metadata_in_key")}
+    yield monkeypatch
+    for name, value in before.items():
+        jax.config.update(name, value)
+
+
+@pytest.mark.parametrize("placed", ["by-the-environment", "by-the-program"])
+def test_a_cache_left_in_force_is_keyed_on_the_scopes(cache_config, placed, tmp_path):
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    if placed == "by-the-environment":
+        cache_config.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert backend.arm_compile_cache() == str(tmp_path)
+    else:
+        cache_config.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        cache_config.setattr(jax, "default_backend", lambda: "tpu")
+        assert backend.arm_compile_cache().endswith(".jax_compile_cache")
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+
+
+def test_no_cache_in_force_leaves_the_key_alone(cache_config):
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    cache_config.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert backend.arm_compile_cache() is None   # the CPU, nobody placed one
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is False
