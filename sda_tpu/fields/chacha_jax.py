@@ -159,8 +159,10 @@ def stream_u64_words_at(seed_words, counter0, *, nblocks: int):
     function produces them, word-major with the block index minor:
     ``out[s, j, b]`` is draw ``8 * (counter0 + b) + j`` of seed ``s``'s
     stream. No layout change: whatever is elementwise in the draws (the
-    reduction mod m) runs on this form, and ``element_order`` puts the
-    result -- one narrow plane instead of the draws' two -- in the stream's
+    reduction mod m, and the pod's fold of the residues over the seeds'
+    rows: ``simpod._chacha_mask_fold``) runs on this form, and
+    ``element_order`` puts the result -- one narrow plane instead of the
+    draws' two, and one row instead of the seeds' S -- in the stream's
     order. ``counter0`` may be traced."""
     return jax.vmap(
         lambda sw: _paired_u64(
@@ -181,7 +183,8 @@ def stream_u64_at(seed_words, counter0, *, dimension: int):
     exact regardless; only the federated wire path needs rejection parity.
 
     The element-order view of ``stream_u64_words_at``: the pod's mask stage
-    takes the word-major form and orders its residues (simpod._mask_stage).
+    takes the word-major form, folds its residues over the participants
+    and orders the fold (simpod._mask_stage, _chacha_mask_sum).
     """
     if dimension % 8:
         raise ValueError("dimension must be a multiple of 8 (one ChaCha block)")

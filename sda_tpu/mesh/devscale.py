@@ -283,11 +283,12 @@ class ModelScaleRound:
                     external_bits_fn=self._pallas_bits_fn,
                 )
             else:
-                masked, mask_sum, skey = _mask_stage(
+                masked_sum, mask_sum, skey = _mask_stage(
                     masking, f, x, dev_key, round_key,
                     pid_base=pid0, d_block0=d_block0,
                 )
-                shares = _share_sum_stage(s, f, self._M_host, masked, skey)
+                shares = _share_sum_stage(
+                    s, f, self._M_host, masked_sum, x.shape[0], skey)
             with jax.named_scope("sda.clerk_combine"):
                 rows = jax.lax.psum_scatter(
                     shares, "p", scatter_dimension=0, tiled=True)

@@ -212,31 +212,57 @@ _SCAN_CHUNK = 8
 
 def _chacha_masks(masking, f: FieldOps, round_key, pid_base, rows: int,
                   d_loc: int, d_block0):
-    """-> masks [rows, d_loc] of the participants ``pid_base .. + rows``:
-    each one's CHACHA_PRG_V1 stream from block ``d_block0`` on, reduced
-    modulo the field's modulus, in element order."""
+    """-> residues [rows, 8, d_loc/8] of the participants ``pid_base .. +
+    rows``: each one's CHACHA_PRG_V1 stream from block ``d_block0`` on,
+    reduced modulo the field's modulus, in the block function's word-major
+    layout (``out[s, j, b]`` masks element ``8 * b + j``)."""
     gids = pid_base + jnp.arange(rows)
     seeds = _chacha_seed_words(round_key, gids, masking.seed_bitsize)
     if d_loc % 8:
         raise ValueError(
             "dimension must be a multiple of 8 (one ChaCha block)")
     # The draws keep the block function's word-major layout
-    # [S, 8, d_loc/8] through pairing and reduction, both
-    # elementwise; only the residues -- one uint32 plane, not the
-    # draws' two -- are put in element order, once. A scope each,
-    # so the device trace tells cipher, reduction and layout change
-    # apart (docs/observability.md)
+    # [S, 8, d_loc/8] through pairing and reduction, both elementwise,
+    # and so does the fold over the rows that every reader of the masks
+    # starts with (``_chacha_mask_fold``): the layout change is a
+    # permutation and commutes with it. A scope each, so the device
+    # trace tells cipher, reduction, fold and layout change apart
+    # (docs/observability.md)
     with jax.named_scope("sda.mask.chacha"):
         draws = chacha_jax.stream_u64_words_at(
             seeds, d_block0, nblocks=d_loc // 8)
     with jax.named_scope("sda.mask.reduce"):
-        masks = f.from_u64(draws)
+        return f.from_u64(draws)
+
+
+def _chacha_mask_fold(masking, f: FieldOps, round_key, pid_base, rows: int,
+                      d_loc: int, d_block0):
+    """-> [8, d_loc/8]: the masks of ``rows`` participants summed over the
+    rows, word-major as ``_chacha_masks`` makes them."""
+    masks = _chacha_masks(masking, f, round_key, pid_base, rows, d_loc,
+                          d_block0)
+    with jax.named_scope("sda.mask.fold"):
+        return f.sum(masks, axis=0)
+
+
+def _element_order(folded):
+    """Word-major [8, d_loc/8] -> [d_loc], the stream's order: the one
+    layout change of the mask expansion, on a fold of the masks and never
+    on a participant's row (one one-hot matmul per byte a row,
+    ``chacha_jax.element_order``: a row costs the matrix unit 8 GFLOP at
+    a million elements)."""
     with jax.named_scope("sda.mask.relayout"):
-        return chacha_jax.element_order(masks)
+        return chacha_jax.element_order(folded)
 
 
 def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
-    """-> (masked [S, d_loc], local_mask_sum [d_loc] or None, share_key).
+    """-> (masked_sum [d_loc], local_mask_sum [d_loc] or None, share_key):
+    the block's rows folded, Σ (x + mask), and the fold of their masks.
+
+    Σ (x + m) = Σ x + Σ m mod p bit for bit, so the masks never meet the
+    [S, d_loc] input, only its fold, and no [S, d_loc] array of masks or
+    masked rows exists; under ChaCha masking the masks fold on the layout
+    the cipher makes them in and ONE row goes through ``_element_order``.
 
     ``pid_base``: global id of the first local participant row (ChaCha
     seeds are a function of (round key, global participant id) only).
@@ -244,21 +270,24 @@ def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
     (= global_dim_offset / 8). Both may be traced.
     """
     S, d_loc = x.shape
+    with jax.named_scope("sda.fold"):
+        x_sum = f.sum(x, axis=0)
     # named scope: the mask stage's ops land on a "sda.mask"-prefixed XProf
     # device lane, so merged traces attribute device time to the phase
     with jax.named_scope("sda.mask"):
         if isinstance(masking, FullMasking):
             mkey, skey = jax.random.split(key)
             masks = f.uniform(mkey, (S, d_loc))
+            with jax.named_scope("sda.mask.fold"):
+                mask_sum = f.sum(masks, axis=0)
         elif isinstance(masking, ChaChaMasking):
             skey = key
-            masks = _chacha_masks(masking, f, round_key, pid_base, S, d_loc,
-                                  d_block0)
+            mask_sum = _element_order(_chacha_mask_fold(
+                masking, f, round_key, pid_base, S, d_loc, d_block0))
         else:
-            return x, None, key
+            return x_sum, None, key
         with jax.named_scope("sda.mask.fold"):
-            masked = f.add(x, masks)
-            return masked, f.sum(masks, axis=0), skey
+            return f.add(x_sum, mask_sum), mask_sum, skey
 
 
 def _chacha_mask_sum(masking, f: FieldOps, round_key, pid_base, rows: int,
@@ -267,27 +296,32 @@ def _chacha_mask_sum(masking, f: FieldOps, round_key, pid_base, rows: int,
     ``_SCAN_CHUNK`` rows at a time under a running sum: what is live is one
     block's draws, whatever ``rows`` (the whole [rows, d_loc] block at once
     is 22 MB of temporaries a row at a million elements, and 1200 rows do
-    not compile for a v5e: PERF.md, PR 35). Rows that do not fill the last
-    block are expanded too, as ``_scan_combine`` expands its zero rows: the
-    sum is added to the fold of the inputs and subtracted from the reveal,
-    so every mask in it cancels."""
+    not compile for a v5e: PERF.md, PR 35). The running sum is word-major
+    like the blocks' folds and is put in element order ONCE, after the
+    scan. Rows that do not fill the last block are expanded too, as
+    ``_scan_combine`` expands its zero rows: the sum is added to the fold
+    of the inputs and subtracted from the reveal, so every mask in it
+    cancels."""
     chunk, padded_rows = _scan_rows(rows, _SCAN_CHUNK)
 
     def body(acc, i):
-        masks = _chacha_masks(masking, f, round_key, pid_base + i * chunk,
-                              chunk, d_loc, d_block0)
+        fold = _chacha_mask_fold(masking, f, round_key, pid_base + i * chunk,
+                                 chunk, d_loc, d_block0)
         with jax.named_scope("sda.mask.fold"):
-            return f.add(acc, f.sum(masks, axis=0)), None
+            return f.add(acc, fold), None
 
     with jax.named_scope("sda.mask"):
+        with jax.named_scope("sda.mask.fold"):
+            init = jnp.zeros((8, d_loc // 8), f.dtype)
         acc, _ = jax.lax.scan(
-            body, jnp.zeros((d_loc,), f.dtype),
-            jnp.arange(padded_rows // chunk, dtype=jnp.int32))
-    return acc
+            body, init, jnp.arange(padded_rows // chunk, dtype=jnp.int32))
+        return _element_order(acc)
 
 
-def _share_sum_stage(scheme, f: FieldOps, M_host, masked, skey):
-    """[S, d_loc] masked residues -> [n, B] participant-SUMMED share rows.
+def _share_sum_stage(scheme, f: FieldOps, M_host, masked_sum, rows: int,
+                     skey):
+    """[d_loc] fold of ``rows`` participants' masked residues (what
+    ``_mask_stage`` hands on) -> [n, B] participant-SUMMED share rows.
 
     Share generation is linear in the (secrets, randomness) vector, so the
     clerk-combined output Σ_p M @ v_p equals M @ Σ_p v_p: participants
@@ -310,14 +344,14 @@ def _share_sum_stage(scheme, f: FieldOps, M_host, masked, skey):
     why the additive branch subtracts the folded ``dsum`` rows one by one
     and asks for no total of the draws (tests/test_tpu_compile.py).
     """
-    S, d = masked.shape
+    d = masked_sum.shape[0]
     with jax.named_scope("sda.share"):
         if isinstance(scheme, SHAMIR_SCHEMES):
             k, t = scheme.secret_count, scheme.privacy_threshold
             B = -(-d // k)
-            rand = f.uniform(skey, (S, t, B))
+            rand = f.uniform(skey, (rows, t, B))
             rsum = f.sum(rand, axis=0)                             # [t, B]
-            sk = sharing.batch_columns(f.sum(masked, axis=0), k)   # [k, B]
+            sk = sharing.batch_columns(masked_sum, k)              # [k, B]
             zeros = jnp.zeros((1, B), sk.dtype)
             values = jnp.concatenate([zeros, sk, rsum], axis=0)    # [m2, B]
             if f.sp is not None:
@@ -327,17 +361,20 @@ def _share_sum_stage(scheme, f: FieldOps, M_host, masked, skey):
             return modular.modmatmul(jnp.asarray(M_host), values, f.m)
         # additive: Σ_p last_p = Σ_p masked_p - Σ over all draws
         n = scheme.share_count
-        draws = f.uniform(skey, (S, n - 1, d))
+        draws = f.uniform(skey, (rows, n - 1, d))
         dsum = f.sum(draws, axis=0)                                # [n-1, d]
-        # the folded rows come off one by one, n - 1 subtractions on [d]:
-        # the compiler turns f.sum(dsum, axis=0) into a second reduce over
-        # the draws themselves, and a draw with two consumers is written
-        # to HBM and read twice (or made twice) instead of fusing into its
-        # fold
-        last = f.sum(masked, axis=0)                               # [d]
+        # the folded rows come off one by one, n - 1 subtractions: the
+        # compiler turns f.sum(dsum, axis=0) into a second reduce over the
+        # draws themselves, and a draw with two consumers is written to HBM
+        # and read twice (or made twice) instead of fusing into its fold.
+        # They come off as rows, [1, d] less dsum[i:i+1]: a flat [d] vector
+        # and a row of [n - 1, d] tile differently on the TPU, and cutting
+        # dsum into flat vectors is a pass over it that changes nothing but
+        # the layout
+        last = masked_sum[None, :]                                 # [1, d]
         for i in range(n - 1):
-            last = f.sub(last, dsum[i])
-        return jnp.concatenate([dsum, last[None, :]], axis=0)
+            last = f.sub(last, dsum[i:i + 1])
+        return jnp.concatenate([dsum, last], axis=0)
 
 
 def _pallas_supported(scheme, masking, f: FieldOps) -> bool:
@@ -493,12 +530,12 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
             bkey = jax.random.fold_in(key, i)
         with jax.named_scope("sda.blocks"):
             pid_base = pid0 + i * chunk
-        masked, mask_sum, skey = _mask_stage(
+        masked_sum, mask_sum, skey = _mask_stage(
             masking, f, blk, bkey, round_key,
             pid_base=pid_base, d_block0=dblk0,
         )
         # an accumulator's add stands under the stage whose result it adds
-        shares = _share_sum_stage(scheme, f, M_host, masked, skey)
+        shares = _share_sum_stage(scheme, f, M_host, masked_sum, chunk, skey)
         with jax.named_scope("sda.share"):
             acc_s = f.add(acc_s, shares)
         if mask_sum is not None:
@@ -867,11 +904,12 @@ def single_chip_round(
     grain = scheme.input_size * 8 // math.gcd(scheme.input_size, 8)
 
     def one_tile(x, bkey, round_key, d_block0, d_loc):
-        masked, mask_total, skey = _mask_stage(
+        masked_sum, mask_total, skey = _mask_stage(
             masking, f, x, bkey, round_key, pid_base=0, d_block0=d_block0
         )
         # share + clerk combine fused via linearity (see _share_sum_stage)
-        combined = _share_sum_stage(scheme, f, M_host, masked, skey)  # [n, B]
+        combined = _share_sum_stage(
+            scheme, f, M_host, masked_sum, x.shape[0], skey)       # [n, B]
         masked_total = _reconstruct_stage(scheme, f, L_host, combined, d_loc)
         with jax.named_scope("sda.unmask"):
             if mask_total is None:

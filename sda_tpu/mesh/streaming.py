@@ -558,13 +558,14 @@ class StreamingAggregator:
                 # pid0/dblk0 (traced) locate this tile in the global stream
                 # so ChaCha seed masks expand the right window of each
                 # participant's stream regardless of tiling
-                masked, mask_sum, skey = _mask_stage(
+                masked_sum, mask_sum, skey = _mask_stage(
                     self.masking, f, x, key, round_key,
                     pid_base=pid0, d_block0=dblk0,
                 )
                 # share + participant-combine fused via linearity
                 # (simpod._share_sum_stage): no [S, n, B] tensor in HBM
-                shares = _share_sum_stage(s, f, M_host, masked, skey)
+                shares = _share_sum_stage(
+                    s, f, M_host, masked_sum, x.shape[0], skey)
             with jax.named_scope("sda.stream.acc"):
                 acc_shares = f.add(acc_shares, shares)
                 if mask_sum is not None:
@@ -780,11 +781,12 @@ class StreamedPod:
                     external_bits_fn=self._pallas_bits_fn,
                 )
             else:
-                masked, local_mask_sum, skey = _mask_stage(
+                masked_sum, local_mask_sum, skey = _mask_stage(
                     masking, f, x, dev_key, round_key,
                     pid_base=pid0, d_block0=dblk0,
                 )
-                shares = _share_sum_stage(s, f, self._M_host, masked, skey)
+                shares = _share_sum_stage(
+                    s, f, self._M_host, masked_sum, x.shape[0], skey)
             with jax.named_scope("sda.stream.acc"):
                 acc_shares = f.add(acc_shares, shares)
                 if local_mask_sum is not None:

@@ -20,6 +20,8 @@ from sda_tpu.protocol import (AdditiveSharing, ChaChaMasking, FullMasking,
                               PackedShamirSharing)
 from sda_tpu.utils import metrics
 
+from util import chacha_mask_rows
+
 MODULUS = 536870233  # 2^29 - 679: the uint32 fast path
 SHARES = 3
 SEED_BITS = 128
@@ -88,7 +90,7 @@ def test_mask_stage_is_the_reference_stream_of_the_rounds_seeds(d_block0):
     field = FieldOps.create(MODULUS)
     round_key = jax.random.PRNGKey(11)
     zeros = jnp.zeros((rows, dim), field.dtype)
-    masked, mask_sum, _ = simpod._mask_stage(
+    masked_sum, mask_sum, _ = simpod._mask_stage(
         ChaChaMasking(MODULUS, dim, SEED_BITS), field, zeros,
         jax.random.PRNGKey(2), round_key, pid_base=first_id, d_block0=d_block0)
     seeds = np.asarray(simpod._chacha_seed_words(
@@ -96,16 +98,21 @@ def test_mask_stage_is_the_reference_stream_of_the_rounds_seeds(d_block0):
     streams = np.stack([
         reference.mask_stream(seed[:SEED_BITS // 32], 8 * d_block0, dim, MODULUS)
         for seed in seeds])
-    assert np.array_equal(np.asarray(masked), streams)  # zero inputs: the masks
+    # row for row: the stage's three steps on every row, ordered a row
+    masks = chacha_mask_rows(field, round_key, first_id, rows, dim, d_block0, SEED_BITS)
+    assert np.array_equal(np.asarray(masks), streams)
+    # the stage: the rows' masks folded, then ordered; zero inputs, so the
+    # masked fold is the masks'
     assert np.array_equal(np.asarray(mask_sum), streams.sum(axis=0) % MODULUS)
+    assert np.array_equal(np.asarray(masked_sum), np.asarray(mask_sum))
 
 
 @pytest.mark.parametrize("participants", [3, 8, 13])
 def test_a_pod_rounds_masks_are_the_reference_stream_mod_p(participants):
-    """What the round adds to each participant's row, block by block as
-    ``_scan_combine`` asks for it, and the mask total the round subtracts,
-    against the reference's stream reduced mod p on the host: the aggregate
-    alone cannot tell (masks cancel whatever they are)."""
+    """What the round adds to the fold of each block of rows, block by
+    block as ``_scan_combine`` asks for it, and the mask total the round
+    subtracts, against the reference's stream reduced mod p on the host:
+    the aggregate alone cannot tell (masks cancel whatever they are)."""
     dim = 96
     pod = _pod(dim)
     field, chunk = pod._field, pod.scan_chunk
@@ -118,11 +125,17 @@ def test_a_pod_rounds_masks_are_the_reference_stream_mod_p(participants):
                      for seed in seeds])
     for first in range(0, participants, chunk):
         rows = x[first:first + chunk]
-        masked, _, _ = simpod._mask_stage(
+        count = rows.shape[0]
+        masked_sum, mask_sum, _ = simpod._mask_stage(
             pod.masking, field, rows, jax.random.PRNGKey(0), round_key,
             pid_base=first, d_block0=0)
-        masks = (np.asarray(masked).astype(np.int64) - np.asarray(rows)) % MODULUS
-        assert np.array_equal(masks, want[first:first + rows.shape[0]])
+        per_row = chacha_mask_rows(field, round_key, first, count, dim, 0, SEED_BITS)
+        assert np.array_equal(np.asarray(per_row), want[first:first + count])
+        assert np.array_equal(np.asarray(mask_sum),
+                              want[first:first + count].sum(axis=0) % MODULUS)
+        # what enters sharing: the fold of the rows, each with its mask
+        masked = (np.asarray(rows).astype(np.int64) + want[first:first + count])
+        assert np.array_equal(np.asarray(masked_sum), masked.sum(axis=0) % MODULUS)
     _, mask_total = simpod._scan_combine(
         field, pod.scheme, pod.masking, None, x, jax.random.PRNGKey(0), round_key,
         pid0=0, dblk0=0, chunk=chunk)
@@ -155,7 +168,8 @@ def test_additive_share_rows_sum_to_the_masked_sum_and_are_redrawn_per_key():
     scheme = AdditiveSharing(SHARES, MODULUS)
     masked = jnp.asarray(_inputs(rows, dim) % MODULUS, field.dtype)
     out = [np.asarray(simpod._share_sum_stage(
-        scheme, field, None, masked, jax.random.PRNGKey(k))).astype(np.int64)
+        scheme, field, None, field.sum(masked, axis=0), rows,
+        jax.random.PRNGKey(k))).astype(np.int64)
         for k in (0, 1)]
     want = np.asarray(masked).astype(np.int64).sum(axis=0) % MODULUS
     for shares in out:
@@ -180,7 +194,8 @@ def test_additive_share_stage_is_the_fold_of_each_participants_shares(modulus, s
     key = jax.random.PRNGKey(shares)
     masked = field.to_residues(_inputs(rows, dim) % modulus)
     stage = np.asarray(simpod._share_sum_stage(
-        AdditiveSharing(shares, modulus), field, None, masked, key)).astype(np.int64)
+        AdditiveSharing(shares, modulus), field, None, field.sum(masked, axis=0),
+        rows, key)).astype(np.int64)
     per = sharing.additive_share(key, jnp.asarray(masked, jnp.int64),
                                  share_count=shares, modulus=modulus)
     assert per.shape == (rows, shares, dim)

@@ -159,6 +159,69 @@ def test_element_order_is_the_interleave_bit_for_bit(dtype, top, shape):
         got, np.swapaxes(words, -1, -2).reshape(shape[:-2] + (-1,)))
 
 
+@pytest.mark.parametrize("blocks", [125, 128, 1001])
+@pytest.mark.parametrize("rows", [1, 8, 13])
+def test_the_fold_of_the_rows_in_element_order_is_the_fold_of_the_ordered_rows(
+        rows, blocks):
+    """The modular fold over the rows is elementwise and ``element_order``
+    a permutation: fold first on the word-major residues and order the one
+    ``[8, blocks]`` result (the pod's mask stage, PR 40), or order every
+    row and fold -- the same vector bit for bit. Residues up to p - 1, a
+    column of them in every row; block counts under, on and past whole
+    lane tiles."""
+    p = 536870233
+    field = FieldOps.create(p)
+    rng = np.random.default_rng(rows * 10_000 + blocks)
+    residues = rng.integers(0, p, size=(rows, 8, blocks), dtype=np.uint32)
+    residues[:, 3, blocks // 2] = p - 1
+    residues[0, 0, 0] = residues[-1, -1, -1] = p - 1
+    r = jnp.asarray(residues)
+    fold_first = np.asarray(chacha_jax.element_order(field.sum(r, axis=0)))
+    order_first = np.asarray(field.sum(chacha_jax.element_order(r), axis=0))
+    assert fold_first.shape == (8 * blocks,) and fold_first.dtype == np.uint32
+    np.testing.assert_array_equal(fold_first, order_first)
+    want = np.swapaxes(residues, -1, -2).reshape(rows, -1).astype(np.uint64).sum(axis=0)
+    np.testing.assert_array_equal(fold_first, want % np.uint64(p))
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs 2 virtual devices")
+@pytest.mark.parametrize("rows", [5, 8, 13, 16])
+@pytest.mark.parametrize("path", ["xla-stage", "kernel-path-sum"])
+def test_the_masks_sum_is_the_sum_of_the_host_oracles_streams_window_by_window(
+        path, rows):
+    """Both mask passes fold word-major and order the fold: the XLA step's
+    stage on the block it is given, the kernel path's scan 8 rows at a
+    time (13 rows expand 16: the ids after the last row cancel like any
+    mask). Each 'd' shard expands its own window at a traced block counter;
+    held to ``fields/chacha.py``'s block function, reduced on the host."""
+    p, dim, first_id = 536870233, 96, 7
+    d_loc = dim // 2
+    field, masking = FieldOps.create(p), ChaChaMasking(p, dim, 128)
+    round_key = jax.random.PRNGKey(11)
+
+    def local(x):
+        block0 = jax.lax.axis_index("d") * (d_loc // 8)
+        if path == "xla-stage":
+            return simpod._mask_stage(masking, field, x, jax.random.PRNGKey(2),
+                                      round_key, pid_base=first_id, d_block0=block0)[1]
+        return simpod._chacha_mask_sum(masking, field, round_key, first_id, rows,
+                                       d_loc, block0)
+
+    sharded = simpod._shard_map(
+        local, mesh=simpod.make_mesh(1, 2), in_specs=PartitionSpec(None, "d"),
+        out_specs=PartitionSpec("d"))
+    mask_sum = np.asarray(jax.jit(sharded)(jnp.zeros((rows, dim), field.dtype)))
+
+    expanded = rows if path == "xla-stage" else simpod._scan_rows(rows, simpod._SCAN_CHUNK)[1]
+    seeds = np.asarray(simpod._chacha_seed_words(
+        round_key, first_id + jnp.arange(expanded), 128))
+    for window in range(2):
+        want = sum(_host_stream([int(w) for w in seed], window * (d_loc // 8), d_loc)
+                   % np.uint64(p) for seed in seeds) % np.uint64(p)
+        np.testing.assert_array_equal(
+            mask_sum[window * d_loc:(window + 1) * d_loc].astype(np.uint64), want)
+
+
 @pytest.mark.skipif(len(jax.devices()) < 2, reason="needs 2 virtual devices")
 @pytest.mark.parametrize("step", ["xla", "pallas-interpret"])
 def test_mask_stage_masks_are_the_stream_mod_p_row_for_row_on_a_sharded_dim(step):
@@ -177,11 +240,15 @@ def test_mask_stage_masks_are_the_stream_mod_p_row_for_row_on_a_sharded_dim(step
     def local(x):
         block0 = jax.lax.axis_index("d") * (d_loc // 8)
         if step == "xla":
-            masked, mask_sum, _ = simpod._mask_stage(
+            # the stage returns folds alone: one row a call, like the kernel's
+            sums = [simpod._mask_stage(
+                masking, field, x[row:row + 1], dev_key, round_key,
+                pid_base=first_id + row, d_block0=block0)[0]
+                for row in range(rows)]
+            _, mask_sum, _ = simpod._mask_stage(
                 masking, field, x, dev_key, round_key,
                 pid_base=first_id, d_block0=block0)
-            return masked, mask_sum
-        # the kernel's step returns the mask sum alone: one row a call
+            return jnp.stack(sums), mask_sum
         sums = [simpod._pallas_stage(
             scheme, field, matrices[0], masking, x[row:row + 1], dev_key,
             round_key=round_key, pid_base=first_id + row, d_block0=block0,

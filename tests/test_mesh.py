@@ -215,7 +215,8 @@ def test_share_sum_stage_equals_per_participant_fold():
         f = FieldOps.create(mod)
         M_host, _ = _build_matrices(scheme)
         masked = f.to_residues(rng.integers(0, mod, size=(5, 36)))
-        fused = np.asarray(_share_sum_stage(scheme, f, M_host, masked, key))
+        fused = np.asarray(_share_sum_stage(
+            scheme, f, M_host, f.sum(masked, axis=0), masked.shape[0], key))
         if isinstance(scheme, PackedShamirSharing):
             if f.sp is not None:
                 per = sharing.packed_share32(

@@ -25,7 +25,7 @@ from sda_tpu.mesh.simpod import SimulatedPod, make_mesh
 from sda_tpu.protocol import ChaChaMasking, NoMasking, PackedShamirSharing
 from sda_tpu.utils import metrics
 
-from util import external_bits
+from util import chacha_mask_rows, external_bits
 
 CHIP = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 CONFIG = json.loads((CHIP / "configs" / "pod-packed8-chacha.json").read_text())
@@ -160,12 +160,14 @@ def test_the_mask_sum_is_the_sum_of_the_reference_streams_window_by_window(rows)
 # -- (c) the blocked pass against the whole-block pass it replaced -------------------
 
 def _whole_block_pass(x, dev_key, round_key, first_id, d_block0):
-    """The kernel path's ChaCha branch until PR 35: ``_mask_stage`` on the
-    whole ``[S, d]`` block, then the kernel mask-free on the masked rows."""
+    """The kernel path's ChaCha branch until PR 35: the whole ``[S, d]``
+    block masked row by row (the per-row masks are made here, since the
+    program's mask stage folds before it orders: PR 40), then the kernel
+    mask-free on the masked rows."""
     scheme, field = _scheme(), FieldOps.create(MODULUS)
-    masking = ChaChaMasking(MODULUS, DIM, SEED_BITS)
-    masked, mask_sum, _ = simpod._mask_stage(
-        masking, field, x, dev_key, round_key, pid_base=first_id, d_block0=d_block0)
+    masks = chacha_mask_rows(field, round_key, first_id, x.shape[0], DIM,
+                             d_block0, SEED_BITS)
+    masked, mask_sum = field.add(x, masks), field.sum(masks, axis=0)
     shares, none = simpod._pallas_stage(
         scheme, field, simpod._build_matrices(scheme)[0], NoMasking(), masked, dev_key,
         interpret=True, external_bits_fn=external_bits)

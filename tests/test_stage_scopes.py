@@ -19,7 +19,7 @@ from sda_tpu.protocol import (AdditiveSharing, ChaChaMasking, FullMasking,
                               PackedShamirSharing)
 from sda_tpu.utils import backend
 
-from util import external_bits
+from util import external_bits, lowered_ops
 
 MODULUS = 536870233  # 2^29 - 679: the uint32 fast path
 ROWS, DIM = 13, 96   # a ragged second scan block; whole ChaCha blocks
@@ -45,8 +45,8 @@ EXPECTED = {
         KERNEL | CHACHA | LAGRANGE | {"sda.clerk_combine", "sda.unmask"},
     # additive: the reconstruction is a plain sum of the rows, no product
     "additive-chacha-xla":
-        {"sda.residues", "sda.blocks", "sda.share", "sda.clerk_combine",
-         "sda.reconstruct", "sda.unmask"} | CHACHA,
+        {"sda.residues", "sda.fold", "sda.blocks", "sda.share",
+         "sda.clerk_combine", "sda.reconstruct", "sda.unmask"} | CHACHA,
     "streamed-step-and-finale":
         KERNEL | LAGRANGE | {"sda.stream.acc", "sda.unmask"},
 }
@@ -101,34 +101,11 @@ def _round(name: str):
 
 
 def _op_paths(lowered):
-    """(op name, name-stack path) of every op of a lowered program, a
-    callee's ops under the path of each of its call sites -- as the
-    compiler's inliner composes ``op_name``, which the profiler shows."""
-    module = lowered.compiler_ir()
-    functions = {str(op.attributes["sym_name"]).strip('"'): op
-                 for op in module.body.operations
-                 if op.operation.name == "func.func"}
-
-    def path_of(op):
-        match = re.search(r'loc\("([^"]*)"', str(op.location))
-        return match.group(1).split("/") if match else []
-
-    def visit(op, prefix):
-        for region in op.regions:
-            for block in region.blocks:
-                for inner in block.operations:
-                    kind = inner.operation.name
-                    path = prefix + path_of(inner)
-                    if kind == "func.call":
-                        callee = str(inner.attributes["callee"]).lstrip("@")
-                        yield from visit(functions[callee.strip('"')], path)
-                    elif inner.regions:     # while, shard_map: their bodies
-                        yield from visit(inner, prefix)
-                    elif kind not in ("func.return", "stablehlo.return",
-                                      "sdy.return", "stablehlo.constant"):
-                        yield kind, path      # a constant is no op's work
-
-    return list(visit(functions["main"], []))
+    """(op name, name-stack path) of every op of a lowered program
+    (``util.lowered_ops``); a constant is no op's work."""
+    return [(op.operation.name, path) for op, path in lowered_ops(lowered)
+            if op.operation.name not in ("func.return", "stablehlo.return",
+                                         "sdy.return", "stablehlo.constant")]
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))

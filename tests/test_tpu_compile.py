@@ -19,7 +19,19 @@ from sda_tpu.fields.ops import FieldOps
 from sda_tpu.mesh import simpod
 from sda_tpu.protocol import AdditiveSharing, ChaChaMasking
 
+from util import external_bits, lowered_ops
+
 MODULUS = 536870233  # 2^29 - 679: the uint32 field path
+
+
+def _packed_scheme():
+    """Packed Shamir 3/8/4 over ``MODULUS``: the packed cells' scheme."""
+    from sda_tpu.fields import numtheory
+    from sda_tpu.protocol import PackedShamirSharing
+
+    t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
+    assert p == MODULUS
+    return PackedShamirSharing(3, 8, t, p, w2, w3)
 
 
 @pytest.fixture(scope="module")
@@ -78,7 +90,7 @@ def share_stage_compiled(one_chip):
 
     def stage(masked, key):
         return simpod._share_sum_stage(AdditiveSharing(3, MODULUS), field, None,
-                                       masked, key)
+                                       field.sum(masked, axis=0), ROWS, key)
 
     return _compile_for(one_chip, stage, ((ROWS, DIM), jnp.uint32), ((2,), jnp.uint32))
 
@@ -108,6 +120,21 @@ def test_mask_stage_changes_layout_with_no_gather_no_row_loop_and_no_padded_plan
     assert " while(" not in text
     assert not _lane_padded(text)
     assert "sda.mask.relayout" in text and "dot_general" in text
+
+
+def test_mask_stage_orders_one_row_a_block(mask_stage_compiled):
+    """Since PR 40 the block's masks fold over their rows word-major and
+    the fold alone goes through the matrix unit: a matmul a byte on ONE
+    row (8 x 977 tiles x 128 words), where eight rows went. No array of
+    the layout change's tiles holds more than one row's words, and the
+    only [8, d] array is the input."""
+    text = mask_stage_compiled.as_text()
+    row = 8 * 977 * 128
+    tiled = {dims for dims in _written_shapes(text) if 977 in dims}
+    assert tiled and all(math.prod(dims) <= row for dims in tiled), tiled
+    wide = {dims for dims in _written_shapes(text)
+            if DIM in dims and math.prod(dims) >= ROWS * DIM}
+    assert wide == {(ROWS, DIM)}, wide
 
 
 def test_mask_stage_reduces_its_draws_with_no_remainder_and_no_64_bit_array(
@@ -195,14 +222,9 @@ def packed_chacha_rounds_compiled(one_chip):
     round at 64 and at 128 rows of the benchmark's padded width."""
     from jax.sharding import Mesh
 
-    from sda_tpu.fields import numtheory
-    from sda_tpu.protocol import PackedShamirSharing
-
-    t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
-    assert p == MODULUS
     mesh = Mesh([[one_chip._device]], ("p", "d"))
-    pod = simpod.SimulatedPod(PackedShamirSharing(3, 8, t, p, w2, w3),
-                              ChaChaMasking(p, 999_999, 128), mesh=mesh, use_pallas=True)
+    pod = simpod.SimulatedPod(_packed_scheme(), ChaChaMasking(MODULUS, 999_999, 128),
+                              mesh=mesh, use_pallas=True)
     return {rows: _compile_for(one_chip, pod.aggregate_fn(rows, PADDED_DIM),
                                ((rows, PADDED_DIM), jnp.uint32), ((2,), jnp.uint32))
             for rows in (64, 128)}
@@ -224,6 +246,58 @@ def test_packed_chacha_round_holds_one_block_of_masks_whatever_the_rows(
         wide = {dims for dims in _written_shapes(text)
                 if math.prod(dims) >= rows * PADDED_DIM}
         assert wide == {(rows, PADDED_DIM)}, wide
+
+
+def test_packed_chacha_round_changes_layout_once_after_the_scan(
+        packed_chacha_rounds_compiled):
+    """The running sum of the masks is word-major; ``element_order`` takes
+    it once a round. Every op the compiler left under ``sda.mask.relayout``
+    stands outside the scan's body, the matmuls among them, and no
+    computation of the loop holds a matmul."""
+    for compiled in packed_chacha_rounds_compiled.values():
+        text = compiled.as_text()
+        relayout = re.findall(r'op_name="([^"]*sda\.mask\.relayout[^"]*)"', text)
+        assert any("dot_general" in name for name in relayout)
+        assert not [name for name in relayout if "/while/" in name]
+        in_loop = [name for name in re.findall(r'op_name="([^"]*)"', text)
+                   if "/while/" in name and "dot_general" in name]
+        assert not in_loop, in_loop[:3]
+
+
+# -- the same, in the rounds as they are lowered (no compiler, any backend): the
+# relayout's matmuls take ONE row -- [8 pairs, tiles, 128 lanes] in bfloat16,
+# no leading axis of a block's rows -- inside the XLA step's scan, a block at
+# a time, and on the kernel path after the scan, once a round.
+
+def _relayout_matmuls(lowered):
+    """(plane operand's dims, inside a scan's body?) of every
+    ``dot_general`` under ``sda.mask.relayout`` in a lowered program."""
+    found = []
+    for op, path in lowered_ops(lowered):
+        if op.operation.name == "stablehlo.dot_general" and "sda.mask.relayout" in path:
+            dims = re.match(r"tensor<([\dx]+)xbf16>", str(op.operands[0].type)).group(1)
+            found.append((tuple(int(n) for n in dims.split("x")), "while" in path))
+    return found
+
+
+@pytest.mark.parametrize("path", ["xla-step", "kernel-path"])
+def test_a_chacha_round_puts_one_row_through_the_matrix_unit(path):
+    dim, rows = 1024 * 3, 24            # three lane tiles a pair; three scan blocks
+    masking = ChaChaMasking(MODULUS, dim, 128)
+    if path == "xla-step":
+        pod = simpod.SimulatedPod(AdditiveSharing(3, MODULUS), masking,
+                                  mesh=simpod.make_mesh(1, 1))
+    else:
+        pod = simpod.SimulatedPod(
+            _packed_scheme(), masking, mesh=simpod.make_mesh(1, 1), use_pallas=True,
+            pallas_interpret=True, pallas_external_bits_fn=external_bits)
+    lowered = pod.aggregate_fn(rows, dim).lower(
+        jax.ShapeDtypeStruct((rows, dim), jnp.uint32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    matmuls = _relayout_matmuls(lowered)
+    # a matmul a byte of the uint32 residues, each on one row's planes
+    assert [dims for dims, _ in matmuls] == [(8, dim // 1024, 128)] * 4, matmuls
+    assert {in_while for _, in_while in matmuls} == {path == "xla-step"}
 
 
 # -- the fused kernel's grid and draws (PR 37): with the on-core PRNG nothing
@@ -253,11 +327,9 @@ def _mosaic_modules(text: str):
 def _kernel_compiled(one_chip, participants: int, masked: bool, external: bool):
     from sda_tpu.fields import fastfield, numtheory
     from sda_tpu.fields.pallas_round import fused_mask_share_combine
-    from sda_tpu.protocol import PackedShamirSharing
 
-    t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
-    scheme = PackedShamirSharing(3, 8, t, p, w2, w3)
-    k = scheme.secret_count
+    scheme = _packed_scheme()
+    k, t, p = scheme.secret_count, scheme.privacy_threshold, scheme.prime_modulus
 
     def kernel(x_sum, seed, *bits):
         return fused_mask_share_combine(

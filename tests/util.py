@@ -130,6 +130,55 @@ def external_bits(key, P, draws, B):
     return jax.random.bits(key, (P, 2 * draws, B), dtype=jnp.uint32)
 
 
+def chacha_mask_rows(field, round_key, first_id, rows, dim, d_block0,
+                     seed_bits):
+    """[rows, dim] ChaCha masks a participant row, in element order: what
+    the pod's mask stage added to its rows one by one until it folded the
+    masks first (PR 40). The program's residues (``simpod._chacha_masks``,
+    word-major) with every row put through the layout change, for the
+    tests that hold a round's masks row for row."""
+    from sda_tpu.fields import chacha_jax
+    from sda_tpu.mesh import simpod
+    from sda_tpu.protocol import ChaChaMasking
+
+    return chacha_jax.element_order(simpod._chacha_masks(
+        ChaChaMasking(field.m, dim, seed_bits), field, round_key, first_id,
+        rows, dim, d_block0))
+
+
+def lowered_ops(lowered):
+    """(op, name-stack path) of every op of a lowered program, a callee's
+    ops under the path of each of its call sites -- as the compiler's
+    inliner composes ``op_name``, which the profiler shows (a scan's body
+    stands under ``while/body``). Ops that hold regions (``while``,
+    ``shard_map``) are entered, not yielded."""
+    import re
+
+    module = lowered.compiler_ir()
+    functions = {str(op.attributes["sym_name"]).strip('"'): op
+                 for op in module.body.operations
+                 if op.operation.name == "func.func"}
+
+    def path_of(op):
+        match = re.search(r'loc\("([^"]*)"', str(op.location))
+        return match.group(1).split("/") if match else []
+
+    def visit(op, prefix):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    path = prefix + path_of(inner)
+                    if inner.operation.name == "func.call":
+                        callee = str(inner.attributes["callee"]).lstrip("@")
+                        yield from visit(functions[callee.strip('"')], path)
+                    elif inner.regions:     # while, shard_map: their bodies
+                        yield from visit(inner, prefix)
+                    else:
+                        yield inner, path
+
+    return list(visit(functions["main"], []))
+
+
 def one_chip_pallas_pod(scheme, mask=None):
     """The Pallas stage as a 1x1 pod sees it: every row on one device, the
     kernel interpreted and fed ``external_bits`` (no TPU PRNG on the CPU)."""
