@@ -707,6 +707,7 @@ class SimulatedPod:
         )
         self._step = None
         self._step_shape = None
+        self._programs = {}  # round_program: what callers built, by their key
 
     @property
     def _sp(self):
@@ -768,7 +769,8 @@ class SimulatedPod:
             mask_total = f.canon(jax.lax.psum(local_mask_sum, "p"))
             return f.to_int64(f.sub(masked_total, mask_total))
 
-    def _build(self, P_total: int, d_total: int):
+    def _build(self, P_total: int, d_total: int, around=None,
+               name: str = "mesh.simpod.round"):
         p_shards, d_shards = self.mesh.devices.shape
         if P_total % p_shards:
             raise ValueError(f"participants {P_total} not divisible by p axis {p_shards}")
@@ -784,6 +786,8 @@ class SimulatedPod:
             in_specs=(P("p", "d"), P()),
             out_specs=P("d"),
         )
+        if around is not None:  # round_program: one program with the round
+            fn = around(fn)
         # devprof: compiled-shape registry + retrace span events + (opt-in)
         # cost analysis for the roofline block — one profile entry for the
         # whole SPMD round regardless of how many shapes get built. Every
@@ -795,8 +799,8 @@ class SimulatedPod:
                                 d_total, p_shards)
         counts = {"mesh.mask.chacha_calls": 1,
                   "mesh.mask.chacha_blocks": blocks} if blocks else None
-        return devprof.instrument("mesh.simpod.round", jax.jit(fn),
-                                  span="pod.dispatch", counts=counts)
+        return devprof.instrument(name, jax.jit(fn), span="pod.dispatch",
+                                  counts=counts)
 
     def padded_shape(self, P_total: int, d_total: int) -> Tuple[int, int]:
         p_shards, d_shards = self.mesh.devices.shape
@@ -814,7 +818,11 @@ class SimulatedPod:
         ``pod.strip`` -- siblings in one trace. Counters at the same
         boundaries: ``mesh.feed.{calls,bytes,pad_bytes}``
         (docs/observability.md)."""
-        inputs = np.asarray(inputs)
+        # an array the devices hold stays on them: the feed below then moves
+        # it between shardings at most, and a pad is made where it lives
+        resident = isinstance(inputs, jax.Array)
+        if not resident:
+            inputs = np.asarray(inputs)
         if key is None:
             from ..crypto.core import fresh_prng_key
 
@@ -827,9 +835,13 @@ class SimulatedPod:
             # zero participants/components aggregate as zero (masks on the
             # padding cancel like any other mask); strip below
             with obs.span("pod.pad", parent=trace):
-                padded = np.zeros((P_pad, d_pad), dtype=inputs.dtype)
-                padded[:P_total, :d_total] = inputs
-                inputs = padded
+                if resident:
+                    inputs = jnp.pad(inputs, ((0, P_pad - P_total),
+                                              (0, d_pad - d_total)))
+                else:
+                    padded = np.zeros((P_pad, d_pad), dtype=inputs.dtype)
+                    padded[:P_total, :d_total] = inputs
+                    inputs = padded
             pad_bytes = inputs.nbytes
         step = self._get_step(P_pad, d_pad)
         sharding = NamedSharding(self.mesh, P("p", "d"))
@@ -865,6 +877,26 @@ class SimulatedPod:
         """The raw jitted SPMD round for benchmarking/compile checks
         (shapes must already satisfy the mesh/scheme grain)."""
         return self._build(P_total, d_total)
+
+    def round_program(self, P_total: int, d_total: int, around, name: str,
+                      key=None):
+        """The round of ``aggregate_fn`` traced into a caller's program:
+        ``around(round_)`` is handed the shard-mapped round
+        ``round_(inputs [P_total, d_total], key) -> [d_total] int64`` and
+        returns the function to jit in its place, instrumented as ``name``
+        with the span and the counters every round's callable has. For a
+        caller whose inputs are made on the devices (``models.federated``):
+        producer, round and consumer compile as one program, so the
+        compiler may fuse what makes the residues into the fold that reads
+        them, and no ``[P_total, d_total]`` array of residues need stand in
+        HBM beside what they were made from. With a hashable ``key`` the
+        program is built once and kept with the pod."""
+        if key is None:
+            return self._build(P_total, d_total, around, name)
+        if (name, key) not in self._programs:
+            self._programs[name, key] = self._build(P_total, d_total, around,
+                                                    name)
+        return self._programs[name, key]
 
 
 def single_chip_round(

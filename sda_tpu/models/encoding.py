@@ -238,14 +238,8 @@ class FixedPointCodec:
         """Float array -> representatives in [0, modulus) ready to share."""
         return np.mod(self.quantize(x), self.modulus).astype(np.int64)
 
-    def decode_sum(self, values, summands: int = 1) -> np.ndarray:
-        """Aggregate in [0, m) -> exact float sum of the quantized inputs.
-
-        ``summands`` is checked against the configured capacity; the lift is
-        centered, matching RecipientOutput.positive()'s canonical band
-        (receive.rs:14-21) shifted to (-m/2, m/2].
-        """
-        v = np.asarray(values, dtype=np.int64)
+    def _check_summands(self, summands: int, size: int) -> None:
+        """The decoders' typed errors, host and device alike."""
         if summands < 1:
             # a zero/negative summand count is always a caller bug (an
             # empty frozen set, a None participation count propagated
@@ -255,16 +249,26 @@ class FixedPointCodec:
             # the error is actionable from a decoder stack trace
             raise ValueError(
                 f"decode needs at least one summand, got {summands} "
-                f"(aggregation: dim {v.size}, modulus {self.modulus}, "
+                f"(aggregation: dim {size}, modulus {self.modulus}, "
                 f"capacity {self.max_summands} summands; empty frozen "
                 "set? use the revealed participation count)"
             )
         if summands > self.max_summands:
             raise ValueError(
                 f"{summands} summands exceeds configured capacity "
-                f"{self.max_summands} (aggregation: dim {v.size}, "
+                f"{self.max_summands} (aggregation: dim {size}, "
                 f"modulus {self.modulus}); the sum may have wrapped"
             )
+
+    def decode_sum(self, values, summands: int = 1) -> np.ndarray:
+        """Aggregate in [0, m) -> exact float sum of the quantized inputs.
+
+        ``summands`` is checked against the configured capacity; the lift is
+        centered, matching RecipientOutput.positive()'s canonical band
+        (receive.rs:14-21) shifted to (-m/2, m/2].
+        """
+        v = np.asarray(values, dtype=np.int64)
+        self._check_summands(summands, v.size)
         v = np.mod(v, self.modulus)
         half = self.modulus // 2
         centered = v - np.where(v > half, self.modulus, 0)
@@ -305,6 +309,55 @@ class FixedPointCodec:
         q = jnp.round(xc * jnp.float32(self.scale)).astype(jnp.int32)
         q = jnp.clip(q, -self._q_max, self._q_max)
         return jnp.where(q < 0, q + self.modulus, q).astype(jnp.int32)
+
+    def _lift_device(self, values, summands: int):
+        """jnp canonical residues in [0, m) -> the centered lift
+        ``v - m * [v > m // 2]`` in integers; ``decode_sum``'s errors."""
+        from jax import numpy as jnp
+
+        v = jnp.asarray(values)
+        self._check_summands(summands, v.size)
+        v = v.astype(jnp.int32 if self.modulus < (1 << 31) else jnp.int64)
+        return v - jnp.where(v > self.modulus // 2, self.modulus, 0)
+
+    def decode_sum_device(self, values, summands: int = 1):
+        """jnp aggregate -> float32 sum of the quantized inputs,
+        jit-friendly; the typed errors of ``decode_sum``.
+
+        ``values`` are canonical residues in [0, m) as a round reveals
+        them, of any integer dtype; unlike the host method nothing is
+        reduced here (a 64-bit remainder is emulated on the TPU). The lift
+        is taken in integers, converted to float32 (one rounding, above
+        2^24) and divided by the scale, a power of two.
+        """
+        from jax import numpy as jnp
+
+        lifted = self._lift_device(values, summands)
+        return lifted.astype(jnp.float32) / jnp.float32(self.scale)
+
+    def decode_mean_device(self, values, summands: int):
+        """jnp aggregate -> float32 mean of the quantized inputs: against
+        ``decode_mean``, which divides in float64, that value rounded to
+        float32 to within ``2^-23 * |mean|``.
+
+        Not ``decode_sum_device(...) / summands``: compiled, a division by
+        a constant is a multiplication by its rounded reciprocal, a third
+        rounding after the lift's conversion and the product's. The lift
+        is split in integers instead, ``whole * summands + rest``; within
+        the codec's capacity ``|whole| <= q_max <= 2^24`` converts exactly,
+        ``rest / summands`` is below 1 and carries the reciprocal's
+        rounding, and the sum of the two rounds once.
+        """
+        import jax
+        from jax import numpy as jnp
+
+        lifted = self._lift_device(values, summands)
+        count = jnp.asarray(summands, lifted.dtype)
+        whole = jax.lax.div(lifted, count)  # toward zero: rest keeps the sign
+        rest = lifted - whole * count
+        mean = (whole.astype(jnp.float32)
+                + rest.astype(jnp.float32) * jnp.float32(1.0 / summands))
+        return mean / jnp.float32(self.scale)
 
     # -- misc ----------------------------------------------------------------
 

@@ -13,7 +13,9 @@ Two execution surfaces, same math:
   the HTTP seam), one aggregation per round, clerks running chores.
 - ``pod_fedavg_round`` — the TPU-native fast path: deltas for a whole
   cohort live as a [P, d] device array and one `SimulatedPod`/
-  `StreamedPod` round produces the sum via mesh collectives.
+  `StreamedPod` round produces the sum via mesh collectives. Client
+  vectors that are already a ``jax.Array`` never leave the devices:
+  delta, encode, round, decode and the new global vector are one program.
 
 The fixed-point codec guarantees the secure sum equals the plaintext sum
 of quantized deltas bit-for-bit, so FedAvg here is exactly FedAvg — the
@@ -27,6 +29,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..protocol import Aggregation, AggregationId
+from ..utils import metrics, timed_phase
 from .encoding import FixedPointCodec, ravel_pytree
 
 __all__ = ["LocalTrainer", "FederatedSession", "pod_fedavg_round"]
@@ -160,8 +163,53 @@ class FederatedSession:
         return self.codec.decode_mean(values, summands)
 
 
-def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec: np.ndarray,
-                     client_vecs, key=None) -> np.ndarray:
+def _resident_program(pod, codec: FixedPointCodec, participants: int,
+                      dimension: int, with_aggregate: bool = False):
+    """The FedAvg round on arrays the devices hold, as one program of the
+    pod: ``program(global_vec [d], client_vecs [P, d], key)`` -> the new
+    global vector [d] float32 (with ``with_aggregate``, a test's: the
+    round's int64 aggregate beside it, built anew). Built once per (pod,
+    codec, shape) by ``pod.round_program`` and kept with the pod; it
+    carries the round's ``pod.dispatch`` span and counters.
+
+    Two stage scopes around the round's own: ``sda.encode`` -- the deltas
+    ``client - global`` in float32, their fixed-point residues, and the
+    zero rows and columns up to the pod's grain, which are residue 0 -- and
+    ``sda.decode`` -- the padding stripped, the centered lift, the mean,
+    the add to the global vector. The residues are canonical, so they go in
+    as uint32 and the round's residue pass is the canon alone.
+    """
+    import jax
+    from jax import numpy as jnp
+
+    rows, width = pod.padded_shape(participants, dimension)
+    pad = ((0, rows - participants), (0, width - dimension))
+
+    def around(round_):
+        def program(global_vec, client_vecs, key):
+            with jax.named_scope("sda.encode"):
+                global_vec = global_vec.astype(jnp.float32)
+                deltas = client_vecs.astype(jnp.float32) - global_vec[None, :]
+                residues = codec.encode_device(deltas).astype(jnp.uint32)
+                if pad != ((0, 0), (0, 0)):
+                    residues = jnp.pad(residues, pad)
+            aggregate = round_(residues, key)
+            with jax.named_scope("sda.decode"):
+                aggregate = aggregate[:dimension]
+                new_global = global_vec + codec.decode_mean_device(
+                    aggregate, participants)
+            return (new_global, aggregate) if with_aggregate else new_global
+
+        return program
+
+    key = None if with_aggregate else (
+        codec.modulus, codec.fractional_bits, codec.clip, participants,
+        dimension)
+    return pod.round_program(rows, width, around, "models.fedavg.round", key)
+
+
+def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec, client_vecs,
+                     key=None):
     """TPU-native FedAvg round: cohort deltas -> mesh round -> mean delta.
 
     ``client_vecs`` is a [P, d] float array (or list of vectors) of client
@@ -169,15 +217,48 @@ def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec: np.ndarray,
     and aggregated in ONE pod round (mask + share + psum_scatter + finale
     all via mesh collectives — no per-client protocol messages). Returns the
     new global vector, exactly global + mean(quantized deltas)/scale.
+
+    Two contracts, by where the cohort lives:
+
+    - **resident** — ``client_vecs`` is a ``jax.Array`` and the pod has a
+      traceable round (``SimulatedPod``): delta, encode, the round, decode
+      and ``global + mean`` are ONE jitted program on the pod's mesh, all
+      in float32, and the result is a float32 ``jax.Array`` that is not
+      waited for. Nothing of the cohort crosses to the host (a
+      ``global_vec`` given from the host is put on the devices, and
+      counted). Against the host contract: the deltas are formed in
+      float32, not float64, and the mean is the host's rounded to float32
+      to within ``2^-23 |mean|`` (``FixedPointCodec.decode_mean_device``).
+    - **host** — anything else: the deltas are subtracted in float64 on
+      the host and sent to the devices once as float32, encoded there and
+      handed to ``pod.aggregate`` as they are; the aggregate is fetched
+      and decoded in NumPy float64, and the result is a NumPy float64
+      vector. Every surface with ``aggregate(inputs, key)`` is served
+      (``StreamedPod``, ``StreamingAggregator``: they take their inputs to
+      the host themselves).
+
+    Either way one ``fedavg.round`` phase is timed around it (attributes
+    ``participants``, ``dimension``, ``resident``), and
+    ``models.fedavg.rounds`` / ``models.fedavg.host_bytes`` count the
+    rounds and the bytes this function itself moved between host and
+    devices, both ways: 0 on the resident path.
     """
+    import jax
     from jax import numpy as jnp
 
-    global_vec = np.asarray(global_vec, dtype=np.float64)
-    stacked = np.asarray(client_vecs, dtype=np.float64)
-    if stacked.ndim != 2 or stacked.shape[1] != global_vec.shape[0]:
-        raise ValueError(f"client_vecs shape {stacked.shape} incompatible "
-                         f"with global {global_vec.shape}")
-    n = stacked.shape[0]
+    resident = (isinstance(client_vecs, jax.Array)
+                and hasattr(pod, "round_program"))
+    moved = 0  # bytes between host and devices, by this function
+    if not resident:
+        moved = sum(v.nbytes for v in (global_vec, client_vecs)
+                    if isinstance(v, jax.Array))
+        global_vec = np.asarray(global_vec, dtype=np.float64)
+        client_vecs = np.asarray(client_vecs, dtype=np.float64)
+    shape, dim = np.shape(client_vecs), np.shape(global_vec)
+    if len(shape) != 2 or len(dim) != 1 or shape[1] != dim[0]:
+        raise ValueError(f"client_vecs shape {shape} incompatible "
+                         f"with global {dim}")
+    n = shape[0]
     if n > codec.max_summands:
         raise ValueError(f"{n} clients exceed codec capacity {codec.max_summands}")
     pod_modulus = getattr(pod, "modulus", codec.modulus)
@@ -186,8 +267,23 @@ def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec: np.ndarray,
             f"codec modulus {codec.modulus} != pod modulus {pod_modulus}: "
             "the decoded mean would be garbage")
 
-    deltas = jnp.asarray(stacked - global_vec[None, :], jnp.float32)
-    encoded = codec.encode_device(deltas)
-    summed = pod.aggregate(encoded, key)
-    mean_delta = codec.decode_mean(np.asarray(summed), n)
-    return global_vec + mean_delta
+    metrics.count("models.fedavg.rounds")
+    with timed_phase("fedavg.round") as phase:
+        phase.attributes.update(participants=n, dimension=dim[0],
+                                resident=resident)
+        if resident:
+            if not isinstance(global_vec, jax.Array):
+                global_vec = jnp.asarray(global_vec, jnp.float32)
+                moved += global_vec.nbytes
+            if key is None:
+                from ..crypto.core import fresh_prng_key
+
+                key = fresh_prng_key()
+            metrics.count("models.fedavg.host_bytes", moved)
+            return _resident_program(pod, codec, n, dim[0])(
+                global_vec, client_vecs, key)
+        deltas = jnp.asarray(client_vecs - global_vec[None, :], jnp.float32)
+        summed = np.asarray(pod.aggregate(codec.encode_device(deltas), key))
+        metrics.count("models.fedavg.host_bytes",
+                      moved + deltas.nbytes + summed.nbytes)
+        return global_vec + codec.decode_mean(summed, n)

@@ -188,6 +188,68 @@ def test_codec_decode_rejects_empty_summand_set():
         codec.decode_sum(np.zeros(4, np.int64), -2)
 
 
+P29 = (1 << 29) - 679  # the pod cells' Solinas prime
+
+
+@pytest.mark.parametrize("summands", [1, 7, 1200])
+@pytest.mark.parametrize("modulus", [P29, M31, (1 << 20)])
+def test_codec_device_decode_is_the_hosts_rounded_to_float32(modulus, summands):
+    """``decode_mean_device`` against ``decode_mean``: over the lift's
+    boundaries, sums on both sides of float32's exact integers (2^24) and
+    random residues, the device's float32 mean is the host's float64 one
+    rounded to float32 to within 2^-23 |mean| -- the lift's conversion and
+    the division each round once -- and exactly it below 2^24 at one
+    summand; ``decode_sum_device`` likewise against ``decode_sum``."""
+    import jax
+    import jax.numpy as jnp
+
+    codec = FixedPointCodec(modulus, fractional_bits=4, max_summands=1200,
+                            clip=16.0)
+    half = modulus // 2
+    edges = [0, 1, half - 1, half, half + 1, half + 2, modulus - 1]
+    wide = [v for v in ((1 << 24) - 1, (1 << 24), (1 << 24) + 1,
+                        (1 << 24) + 3, modulus - (1 << 24) - 1,
+                        modulus - (1 << 24) - 3) if 0 <= v < modulus]
+    rng = np.random.default_rng(42)
+    values = np.concatenate(
+        [edges, wide, rng.integers(0, modulus, size=4096)]).astype(np.int64)
+    for dtype in (jnp.int64, jnp.uint32):
+        on_device = jnp.asarray(values, dtype)
+        mean = jax.jit(lambda v: codec.decode_mean_device(v, summands))(on_device)
+        total = codec.decode_sum_device(on_device, summands)
+        assert mean.dtype == total.dtype == jnp.float32
+        for got, host in ((mean, codec.decode_mean(values, summands)),
+                          (total, codec.decode_sum(values, summands))):
+            got = np.asarray(got).astype(np.float64)
+            rounded = host.astype(np.float32).astype(np.float64)
+            assert (np.abs(got - rounded) <= 2.0 ** -23 * np.abs(host)).all()
+            assert np.array_equal(np.sign(got), np.sign(host))
+        small = np.abs(codec.decode_sum(values, 1)) * codec.scale < (1 << 24)
+        np.testing.assert_array_equal(
+            np.asarray(total)[small],
+            codec.decode_sum(values, summands)[small].astype(np.float32))
+    # the lift's boundary itself: m // 2 stays positive, m // 2 + 1 wraps
+    lifted = np.asarray(codec.decode_sum_device(
+        jnp.asarray([half, half + 1], jnp.int64), 1)) * codec.scale
+    np.testing.assert_array_equal(
+        lifted, np.array([half, half + 1 - modulus], np.float32))
+
+
+def test_codec_device_decode_raises_the_hosts_typed_errors():
+    import jax.numpy as jnp
+
+    codec = FixedPointCodec(P29, fractional_bits=8, max_summands=4)
+    zeros = jnp.zeros(4, jnp.int64)
+    for decode in (codec.decode_mean_device, codec.decode_sum_device):
+        with pytest.raises(ValueError, match="at least one summand"):
+            decode(zeros, 0)
+        with pytest.raises(ValueError, match="exceeds configured capacity"):
+            decode(zeros, 5)
+    for decode in (codec.decode_mean, codec.decode_sum):  # as they were
+        with pytest.raises(ValueError, match="exceeds configured capacity"):
+            decode(np.zeros(4, np.int64), 5)
+
+
 def test_modulus_mismatch_is_rejected():
     """A codec/aggregation modulus mismatch must fail loudly, not decode
     garbage (both FedAvg surfaces validate it)."""

@@ -171,6 +171,50 @@ def test_lowered_step_names_the_device_stages(step):
     assert ("sda.mask_share" in text) == (step == "pallas")
 
 
+@pytest.mark.parametrize("cohort", ["resident", "host"])
+def test_fedavg_round_is_one_phase_with_its_counters(cohort):
+    """``pod_fedavg_round``: one ``fedavg.round`` phase around the work
+    (attributes ``participants``, ``dimension``, ``resident``), and the
+    counters ``models.fedavg.{rounds,host_bytes}``. Resident, its one
+    child is the program's ``pod.dispatch`` and no byte is counted; from
+    the host, ``aggregate()``'s spans stand under it and the deltas up
+    and the aggregate down are counted."""
+    from sda_tpu.models import FixedPointCodec, pod_fedavg_round
+
+    pod = _pod("xla")
+    rows, dim = pod.padded_shape(8, 48)
+    codec = FixedPointCodec(pod.modulus, 16, max_summands=rows, clip=2.0)
+    rng = np.random.default_rng(11)
+    global_vec = rng.uniform(-1, 1, size=dim).astype(np.float32)
+    clients = global_vec + rng.normal(size=(rows, dim)).astype(np.float32)
+    if cohort == "resident":
+        global_vec, clients = jnp.asarray(global_vec), jnp.asarray(clients)
+    key = jax.random.PRNGKey(5)
+    pod_fedavg_round(pod, codec, global_vec, clients, key)  # compiles
+    obs.reset_all()
+    jax.block_until_ready(
+        pod_fedavg_round(pod, codec, global_vec, clients, key))
+    spans = _by_name(obs.finished_spans())
+    round_ = spans["fedavg.round"]
+    assert round_.parent_id is None
+    assert round_.attributes == {"participants": rows, "dimension": dim,
+                                 "resident": cohort == "resident"}
+    assert phase_report()["fedavg.round"]["total_s"] == round_.duration_s
+    moved = 0 if cohort == "resident" else rows * dim * 4 + dim * 8
+    assert metrics.counter_report("models.fedavg.") == {
+        "models.fedavg.rounds": 1, "models.fedavg.host_bytes": moved}
+    if cohort == "resident":
+        assert set(spans) == {"fedavg.round", "pod.dispatch"}
+        assert spans["pod.dispatch"].parent_id == round_.span_id
+        assert metrics.counter_report("mesh.") == {}  # nothing was fed
+        assert devprof.report()["models.fedavg.round"]["calls"] == 1
+    else:
+        assert set(spans) == {"fedavg.round", "mesh.round", "pod.strip",
+                              *CHILDREN}
+        assert spans["mesh.round"].parent_id == round_.span_id
+        assert {s.trace_id for s in spans.values()} == {round_.trace_id}
+
+
 def _tensor_sizes(line: str) -> list:
     """Element counts of the ranked tensor types on one line of MLIR."""
     return [math.prod(int(n) for n in dims[:-1].split("x"))

@@ -1,9 +1,10 @@
 """The stage scopes of a round (docs/observability.md, "Stage scopes"):
 one closed list of ``jax.named_scope`` names, and every device op a round's
-own statements make stands under exactly one of them. Four rounds on the
+own statements make stands under exactly one of them. Five rounds on the
 CPU (the kernel interpreted and fed external bits where the step is the
-kernel), each held to the plain sum bit for bit; and the compile cache,
-which the program keys on those scopes wherever it leaves one in force."""
+kernel; the fifth is the resident FedAvg program, its codec's two scopes
+around the round's), each held to the plain sum bit for bit; and the
+compile cache, which the program keys on those scopes wherever it leaves one in force."""
 
 import re
 
@@ -15,6 +16,7 @@ import pytest
 from sda_tpu.fields import numtheory
 from sda_tpu.mesh import StreamingAggregator
 from sda_tpu.mesh.simpod import SimulatedPod, make_mesh
+from sda_tpu.models import FixedPointCodec, federated
 from sda_tpu.protocol import (AdditiveSharing, ChaChaMasking, FullMasking,
                               PackedShamirSharing)
 from sda_tpu.utils import backend
@@ -28,7 +30,8 @@ INTERPRETED = dict(pallas_interpret=True, pallas_external_bits_fn=external_bits)
 #: the list, as docs/observability.md holds it
 STAGES = {"sda.residues", "sda.fold", "sda.blocks", "sda.mask", "sda.share",
           "sda.relayout", "sda.mask_share", "sda.clerk_combine",
-          "sda.reconstruct", "sda.unmask", "sda.stream.acc"}
+          "sda.reconstruct", "sda.unmask", "sda.stream.acc",
+          "sda.encode", "sda.decode"}
 CHILDREN = {"sda.mask.chacha", "sda.mask.reduce", "sda.mask.relayout",
             "sda.mask.fold", "sda.reconstruct.lagrange",
             "sda.reconstruct.unbatch"}
@@ -49,6 +52,10 @@ EXPECTED = {
          "sda.clerk_combine", "sda.reconstruct", "sda.unmask"} | CHACHA,
     "streamed-step-and-finale":
         KERNEL | LAGRANGE | {"sda.stream.acc", "sda.unmask"},
+    # models.federated's resident program: the codec around the round
+    "fedavg-packed-full-kernel":
+        KERNEL | LAGRANGE | {"sda.clerk_combine", "sda.unmask",
+                             "sda.encode", "sda.decode"},
 }
 #: what ``lax.scan`` lowers to around a body that stands under no stage (the
 #: XLA step's): its counter, its test, the slice of a block: no statement's
@@ -94,6 +101,19 @@ def _round(name: str):
     assert pod.pallas_active is kernel
     rows, dim = pod.padded_shape(ROWS, DIM)
     assert (rows, dim) == (ROWS, DIM)
+    if name.startswith("fedavg"):
+        # weights whose deltas are the inputs as 20 fractional bits exactly:
+        # the program's integer stage must reveal their plain sum
+        codec = FixedPointCodec(MODULUS, 20, max_summands=ROWS, clip=1.0)
+        program = federated._resident_program(pod, codec, rows, dim,
+                                              with_aggregate=True)
+        global_vec = jnp.full((dim,), 0.5, jnp.float32)
+        clients = global_vec + jnp.asarray(inputs / 2.0 ** 20, jnp.float32)
+        lowered = [program.lower(
+            jax.ShapeDtypeStruct((dim,), jnp.float32),
+            jax.ShapeDtypeStruct((rows, dim), jnp.float32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32))]
+        return lowered, np.asarray(program(global_vec, clients, key)[1])
     step = pod.aggregate_fn(rows, dim)
     lowered = [step.lower(jax.ShapeDtypeStruct((rows, dim), jnp.uint32),
                           jax.ShapeDtypeStruct((2,), jnp.uint32))]

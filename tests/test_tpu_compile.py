@@ -167,17 +167,21 @@ def test_share_stage_block_writes_no_draw_to_memory(share_stage_compiled):
     assert share_stage_compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
-def _written_shapes(text: str):
-    """Shapes of the arrays a compiled program holds in memory: results
-    and parameters of every computation but the bodies of fusions, whose
-    values live in registers."""
-    shapes = set()
+def _written_arrays(text: str):
+    """(dtype, shape) of the arrays a compiled program holds in memory:
+    results and parameters of every computation but the bodies of fusions,
+    whose values live in registers."""
+    arrays = set()
     for header, body in re.findall(r"^(\S[^\n]*)\{\n(.*?)^\}", text, re.M | re.S):
         if header.startswith("%fused_computation"):
             continue
-        for dims in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", header + body):
-            shapes.add(tuple(int(n) for n in dims.split(",")))
-    return shapes
+        for dtype, dims in re.findall(r"\b([a-z]+\d+)\[([\d,]+)\]", header + body):
+            arrays.add((dtype, tuple(int(n) for n in dims.split(","))))
+    return arrays
+
+
+def _written_shapes(text: str):
+    return {dims for _, dims in _written_arrays(text)}
 
 
 def test_share_stage_holds_no_array_of_the_draws_width_but_its_rows(
@@ -262,6 +266,48 @@ def test_packed_chacha_round_changes_layout_once_after_the_scan(
         in_loop = [name for name in re.findall(r'op_name="([^"]*)"', text)
                    if "/while/" in name and "dot_general" in name]
         assert not in_loop, in_loop[:3]
+
+
+COHORT = (1200, 999_999)  # the cell ``fedavg-f32-1m``: float32 client weights
+
+
+@pytest.fixture(scope="module")
+def fedavg_round_compiled(one_chip):
+    """``pod_fedavg_round``'s resident program (models/federated.py) at the
+    cell's size: packed 3/8/4, full masks, the fused kernel, the codec of
+    ``pod-fedavg-packed8``."""
+    from jax.sharding import Mesh
+
+    from sda_tpu.models import FixedPointCodec, federated
+    from sda_tpu.protocol import FullMasking
+
+    mesh = Mesh([[one_chip._device]], ("p", "d"))
+    pod = simpod.SimulatedPod(_packed_scheme(), FullMasking(MODULUS),
+                              mesh=mesh, use_pallas=True)
+    codec = FixedPointCodec(MODULUS, 16, max_summands=COHORT[0], clip=2.0)
+    program = federated._resident_program(pod, codec, *COHORT)
+    return _compile_for(one_chip, program, (COHORT[1:], jnp.float32),
+                        (COHORT, jnp.float32), ((2,), jnp.uint32))
+
+
+def test_fedavg_round_writes_no_residue_of_the_cohorts_shape(
+        fedavg_round_compiled):
+    """The encode is traced into the program that folds it: the compiler
+    fuses delta, quantization and residue pass into the fold of the rows
+    (root under ``sda.fold``), so the only array of the cohort's extent is
+    the float32 input -- no int32 or uint32 ``[1200, 999999]`` stands in
+    HBM beside it (4.8 GB more, written and read back) -- and the round's
+    temporaries are the packed round's own (720,399,360 B, PR 42)."""
+    text = fedavg_round_compiled.as_text()
+    assert "tpu_custom_call" in text and "sda.decode" in text
+    wide = {(dtype, dims) for dtype, dims in _written_arrays(text)
+            if math.prod(dims) >= math.prod(COHORT)}
+    assert wide == {("f32", COHORT)}, wide
+    memory = fedavg_round_compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1e9, memory.temp_size_in_bytes
+    # the fold reads the weights themselves: one pass over the 4.8 GB
+    (fold,) = re.findall(r"fusion\(%client_vecs[.\d]*, %global_vec[.\d]*\)[^\n]*", text)
+    assert "sda.fold" in fold
 
 
 # -- the same, in the rounds as they are lowered (no compiler, any backend): the
