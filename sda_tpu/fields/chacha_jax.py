@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import chacha
+from . import chacha, layout
 from .chacha import _CONSTANTS
 
 _U32 = jnp.uint32
@@ -96,34 +96,11 @@ def element_order(x):
     """Word-major ``[..., 8, nblocks]`` -> element order ``[..., 8 * nblocks]``
     (element ``e = 8 * block + pair``), the order of the host stream.
 
-    An interleave of eight rows along the minor axis. Written as
-    ``swapaxes(-1, -2).reshape`` the TPU makes it a copy into an array whose
-    minor dimension 8 is padded to 128 lanes, a flatten and a row loop (a
-    quarter of the round it was measured in: PERF.md, PR 30). So the
-    permutation goes through the matrix unit, which a round of integer
-    arithmetic leaves idle: each tile of 8 x 128 words (pair j, block r)
-    times the one-hot ``[(j, r), 8r + j]`` is the tile's 1024 words in
-    element order. One matmul per byte of the words: a byte is exact in
-    bfloat16, every output is one product by 1 plus zeros, and the float32
-    accumulator holds it exactly -- on any backend, for any integer dtype
-    whose values are non-negative.
+    The interleave of eight rows along the minor axis, through the matrix
+    unit: ``fields.layout.interleave``, which the packed scheme's column
+    layout shares.
     """
-    lead, (pairs, nblocks) = x.shape[:-2], x.shape[-2:]
-    tiles = -(-nblocks // 128)
-    if tiles * 128 != nblocks:  # whole lane tiles; the tail is cut below
-        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, tiles * 128 - nblocks)])
-    x = x.reshape(lead + (pairs, tiles, 128))
-    target = pairs * jnp.arange(128)[None, :] + jnp.arange(pairs)[:, None]
-    onehot = jax.nn.one_hot(target, pairs * 128, dtype=jnp.bfloat16)  # [pairs, 128, 128 pairs]
-    out = None
-    for byte in range(x.dtype.itemsize):
-        shift = jnp.asarray(8 * byte, x.dtype)
-        plane = ((x >> shift) & jnp.asarray(0xFF, x.dtype)).astype(jnp.bfloat16)
-        moved = jnp.einsum("...jqr,jrl->...ql", plane, onehot,
-                           preferred_element_type=jnp.float32)
-        moved = moved.astype(x.dtype) << shift
-        out = moved if out is None else out | moved
-    return out.reshape(lead + (tiles * pairs * 128,))[..., :pairs * nblocks]
+    return layout.interleave(x)
 
 
 @functools.partial(jax.jit, static_argnames=("dimension", "modulus", "prg"))

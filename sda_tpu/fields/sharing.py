@@ -2,8 +2,9 @@
 
 The reference's batching layer (client/src/crypto/sharing/batched.rs:18-99)
 chunks a d-vector into ceil(d/k) batches of k secrets, shares each batch,
-and transposes shares per clerk. Here that whole layer is a reshape: the
-batch axis becomes the matmul's column axis, so sharing a participant's
+and transposes shares per clerk. Here that whole layer is a change of
+layout (``fields.layout``: the de-interleave of k rows and its inverse):
+the batch axis becomes the matmul's column axis, so sharing a participant's
 vector is ONE [n, m2] @ [m2, B] modular matmul and reconstruction is ONE
 [k, r+1] @ [r+1, B] matmul — MXU-shaped, vmap-able over participants.
 
@@ -19,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import fastfield
+from . import fastfield, layout
 from ..obs import devprof
 from .modular import modmatmul, modsub, modsum, uniform_mod
 
@@ -27,22 +28,15 @@ from .modular import modmatmul, modsub, modsum, uniform_mod
 def batch_columns(secrets, input_size: int):
     """[d] -> [input_size, B] column-per-batch layout (zero-padded).
 
-    Batch b holds secrets[b*k:(b+1)*k] (batched.rs:18-53 semantics).
+    Batch b holds secrets[b*k:(b+1)*k] (batched.rs:18-53 semantics): the
+    de-interleave of ``k`` rows, through the matrix unit (``fields.layout``).
     """
-    d = secrets.shape[-1]
-    B = -(-d // input_size)
-    padded = jnp.zeros(secrets.shape[:-1] + (B * input_size,), secrets.dtype)
-    padded = padded.at[..., :d].set(secrets)
-    return jnp.moveaxis(
-        padded.reshape(secrets.shape[:-1] + (B, input_size)), -1, -2
-    )
+    return layout.deinterleave(secrets, input_size)
 
 
 def unbatch_columns(batched, dimension: int):
     """[k, B] -> [d], inverse of batch_columns (truncates padding)."""
-    out = jnp.moveaxis(batched, -2, -1)
-    out = out.reshape(out.shape[:-2] + (-1,))
-    return out[..., :dimension]
+    return layout.interleave(batched)[..., :dimension]
 
 
 # ---------------------------------------------------------------------------

@@ -251,13 +251,16 @@ def test_lowered_pallas_step_folds_before_the_layout_change(dim):
     assert f"across dimensions = [0] : (tensor<5x{dim}xui32>, " \
         f"tensor<ui32>) -> tensor<{dim}xui32>" in text
     # ... and the relayout touches nothing larger than the padded [k, B]
-    # block, which is smaller than the 5 rows
+    # block, which is smaller than the 5 rows, but the one-hot of its
+    # matmuls (fields/layout.py): [k, 128, 128 k], whatever the width
+    onehot = k * 128 * 128 * k
     relayout = set(re.findall(r'(#loc\d+) = loc\("sda\.relayout/', text))
-    sizes = [size for line in text.splitlines()
+    sizes = {size for line in text.splitlines()
              if (at := re.search(r"loc\((#loc\d+)\)$", line))
              and at.group(1) in relayout
-             for size in _tensor_sizes(line)]
-    assert 0 < max(sizes) <= k * padded < 5 * dim
+             for size in _tensor_sizes(line)}
+    assert onehot in sizes
+    assert 0 < max(sizes - {onehot}) <= k * padded < 5 * dim
 
 
 def test_residue_pass_is_named_on_the_int64_path_too():

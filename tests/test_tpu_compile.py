@@ -310,6 +310,70 @@ def test_fedavg_round_writes_no_residue_of_the_cohorts_shape(
     assert "sda.fold" in fold
 
 
+# -- the packed round's layout changes (PR 43): ``batch_columns`` in front of
+# the kernel, the kernel's mask total back to ``[d]`` and ``unbatch_columns``
+# behind the Lagrange product go through the matrix unit (fields/layout.py).
+# As ``moveaxis`` + ``reshape`` the compiler moved the last one up through the
+# product's adds into its eight terms, each laid out on its own as
+# ``u32[333333,3]{1,0:T(8,128)}`` -- 4 MB held in 170 MB, a third of the round.
+
+@pytest.fixture(scope="module")
+def packed_round_compiled(one_chip):
+    """The cell ``packed-1m``'s round: 300 x 999,999 residues, packed 3/8/4
+    under full masks, the fused kernel."""
+    from jax.sharding import Mesh
+
+    from sda_tpu.protocol import FullMasking
+
+    mesh = Mesh([[one_chip._device]], ("p", "d"))
+    pod = simpod.SimulatedPod(_packed_scheme(), FullMasking(MODULUS),
+                              mesh=mesh, use_pallas=True)
+    return _compile_for(one_chip, pod.aggregate_fn(300, 999_999),
+                        ((300, 999_999), jnp.uint32), ((2,), jnp.uint32))
+
+
+@pytest.fixture(params=["packed_round_compiled", "fedavg_round_compiled"],
+                ids=["packed-1m", "fedavg-f32-1m"])
+def packed_full_round(request):
+    return request.getfixturevalue(request.param)
+
+
+def _entry_ops(text: str):
+    """(opcode, op_name) of the instructions of the program's entry
+    computation: the ops the device runs one after another."""
+    entry = text[text.index("\nENTRY "):]
+    return [(opcode, name) for opcode, name in re.findall(
+        r"^\s+(?:ROOT )?%\S+ = .*? ([a-z][\w-]*)\(.*?op_name=\"([^\"]*)\"",
+        entry, re.M)]
+
+
+def test_packed_round_holds_no_lane_padded_array(packed_full_round):
+    text = packed_full_round.as_text()
+    assert "[333333,3]" not in text
+    assert not _lane_padded(text, least=1 << 16)
+
+
+def test_packed_round_changes_layout_through_the_matrix_unit(packed_full_round):
+    """Four matmuls a layout change, one a byte of the uint32 residues:
+    eight under ``sda.relayout`` (``batch_columns`` and the mask total's way
+    back), four under ``sda.reconstruct.unbatch``, which until PR 43 no op
+    carried; and no ``copy`` or ``transpose`` under either stage."""
+    ops = _entry_ops(packed_full_round.as_text())
+    matmuls = [name for _, name in ops if name.endswith("/dot_general")]
+    assert len([n for n in matmuls if "/sda.relayout/" in n]) == 8, matmuls
+    assert len([n for n in matmuls if "/sda.reconstruct.unbatch/" in n]) == 4, matmuls
+    moved = [(opcode, name) for opcode, name in ops
+             if opcode in ("copy", "transpose")
+             and ("sda.reconstruct" in name or "sda.relayout" in name)]
+    assert not moved, moved
+
+
+def test_packed_round_keeps_its_temporaries_out_of_hbm(packed_full_round):
+    # 721,044,480 B at the parent (720,399,360 in the FedAvg round), the
+    # terms' padded arrays; 935,424 B now
+    assert packed_full_round.memory_analysis().temp_size_in_bytes < 100e6
+
+
 # -- the same, in the rounds as they are lowered (no compiler, any backend): the
 # relayout's matmuls take ONE row -- [8 pairs, tiles, 128 lanes] in bfloat16,
 # no leading axis of a block's rows -- inside the XLA step's scan, a block at
