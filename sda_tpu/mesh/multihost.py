@@ -49,14 +49,14 @@ def initialize(
     )
 
 
-def aggregate_process_local(pod, local_inputs, key=None):
+def aggregate_process_local(pod, local_inputs, key=None, reported=None):
     """One secure-aggregation round over process-local participant rows.
 
     Every process passes its own ``[P_local, d]`` block (same ``d``
     everywhere; ragged ``P_local`` is fine — blocks are zero-padded to the
     max, and zero rows aggregate as zero with their masks cancelling).
     Returns the full [d] aggregate as host numpy, identical on every
-    process.
+    process. ``reported`` is refused (``simpod.refuse_reported``).
     """
     import math
 
@@ -67,7 +67,9 @@ def aggregate_process_local(pod, local_inputs, key=None):
 
     from ..crypto.core import fresh_prng_key
     from ..utils import timed_phase
+    from .simpod import refuse_reported
 
+    refuse_reported(reported, "multihost.aggregate_process_local")
     inputs = np.asarray(local_inputs)
     if inputs.ndim != 2:
         raise ValueError("local_inputs must be [P_local, d]")

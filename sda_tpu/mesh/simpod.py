@@ -183,6 +183,19 @@ def _check_masking_supported(masking) -> None:
         )
 
 
+def _reported_rows(x, reported):
+    """[S, d_loc] residues with the rows that did not report read as zero
+    (``reported`` [S] bool, traced; None: every row counts, and ``x`` is
+    handed back as it is). Called inside ``sda.fold``, in front of the
+    fold's one read of the rows: the compiler fuses the select into that
+    read as it does the residue pass, and no [S, d_loc] array of selected
+    residues exists. Masks and share rows are drawn for every row all the
+    same, as for padding rows: they cancel whatever the rows hold."""
+    if reported is None:
+        return x
+    return jnp.where(reported[:, None], x, jnp.zeros((), x.dtype))
+
+
 def _chacha_seed_words(key, global_ids, seed_bitsize: int):
     """[S] global participant ids -> [S, 8] uint32 seed words.
 
@@ -409,7 +422,8 @@ def _resolve_pallas(scheme, masking, f: FieldOps, use_pallas: bool,
 
 def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
                   round_key=None, pid_base=0, d_block0=0,
-                  interpret: bool = False, external_bits_fn=None):
+                  interpret: bool = False, external_bits_fn=None,
+                  reported=None):
     """[S, d_loc] canonical residues -> (combined shares [n, B0],
     mask sum [d_loc] | None) on the fused Pallas kernel.
 
@@ -445,6 +459,10 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     ``external_bits_fn(key, S, draws, B)`` (tests/util.external_bits
     layout) enables interpret-mode runs on CPU, where the TPU PRNG
     primitive is unavailable.
+
+    ``reported`` ([S] bool, traced): the rows that count; the others are
+    read as zero inside the fold (``_reported_rows``). The kernel never
+    sees rows, only their fold, and draws for all S as before.
     """
     from ..fields import pallas_round
 
@@ -452,7 +470,7 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     k, t = scheme.secret_count, scheme.privacy_threshold
     masked = isinstance(masking, FullMasking)
     with jax.named_scope("sda.fold"):
-        x_sum = f.sum(x, axis=0)                            # [d_loc]
+        x_sum = f.sum(_reported_rows(x, reported), axis=0)  # [d_loc]
     chacha_mask_sum = None
     if isinstance(masking, ChaChaMasking):
         # sum_p (x_p + m_p) = sum_p x_p + sum_p m_p mod p, bit for bit: the
@@ -500,7 +518,7 @@ def _scan_rows(rows: int, chunk: int) -> Tuple[int, int]:
 
 
 def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
-                  pid0, dblk0, chunk: int):
+                  pid0, dblk0, chunk: int, reported=None):
     """[P, d] canonical residues -> (acc_shares [n, B], acc_mask [d]|None).
 
     Streams participants through ``lax.scan`` in blocks of ``chunk``: the
@@ -508,6 +526,10 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
     path stops round-tripping the full share tensor through HBM (the
     round-1 single-chip bottleneck; ~2x even on CPU from cache locality).
     Zero-padded rows aggregate as zero and their masks cancel.
+
+    ``reported`` ([P] bool, traced): cut into the scan's blocks beside the
+    rows (the padding rows did not report), and a block's rows that did
+    not report are read as zero where the block is folded.
     """
     P, d = x.shape
     chunk, padded_rows = _scan_rows(P, chunk)
@@ -519,13 +541,18 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
             x = jnp.concatenate([x, jnp.zeros((pad, d), x.dtype)], axis=0)
         nblk = x.shape[0] // chunk
         xb = x.reshape(nblk, chunk, d)
+        rb = None  # a block's entries of ``reported``, beside its rows
+        if reported is not None:
+            rb = jnp.pad(reported, (0, pad)).reshape(nblk, chunk)
     n = scheme.output_size
     B = d // scheme.input_size
     has_mask = not isinstance(masking, NoMasking)
 
     def body(carry, blk_i):
         acc_s, acc_m = carry
-        blk, i = blk_i
+        blk, blk_reported, i = blk_i
+        with jax.named_scope("sda.fold"):
+            blk = _reported_rows(blk, blk_reported)
         with jax.named_scope("sda.share"):
             bkey = jax.random.fold_in(key, i)
         with jax.named_scope("sda.blocks"):
@@ -549,7 +576,8 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
         init_m = jnp.zeros((d,), f.dtype)
     with jax.named_scope("sda.blocks"):
         counter = jnp.arange(nblk, dtype=jnp.int32)
-    (acc_s, acc_m), _ = jax.lax.scan(body, (init_s, init_m), (xb, counter))
+    (acc_s, acc_m), _ = jax.lax.scan(body, (init_s, init_m),
+                                     (xb, rb, counter))
     return acc_s, (acc_m if has_mask else None)
 
 
@@ -639,6 +667,33 @@ def _normalize_survivors(scheme, surviving_clerks) -> Optional[Tuple[int, ...]]:
     return survivors[:r]
 
 
+def refuse_reported(reported, driver: str) -> None:
+    """The drivers that stream a cohort block by block take no ``reported``
+    operand yet: handed one they raise, they never sum the rows it rules
+    out."""
+    if reported is not None:
+        raise NotImplementedError(
+            f"{driver} takes no `reported` operand: a round over the rows "
+            "that reported is SimulatedPod's (aggregate, aggregate_fn, "
+            "round_program); stream the rows that reported alone")
+
+
+def _reported_operand(reported, rows: int, padded_rows: int):
+    """The caller's ``reported`` as the round's operand: [padded_rows]
+    bool, the padding rows not reported; a ``jax.Array`` stays where it
+    lies."""
+    if not isinstance(reported, jax.Array):
+        reported = np.asarray(reported)
+    if reported.shape != (rows,):
+        raise ValueError(f"reported has shape {reported.shape}; the cohort "
+                         f"has {rows} rows")
+    reported = reported.astype(bool)
+    if padded_rows == rows:
+        return reported
+    pad = jnp.pad if isinstance(reported, jax.Array) else np.pad
+    return pad(reported, (0, padded_rows - rows))
+
+
 class SimulatedPod:
     """One secure-aggregation round as a single SPMD program.
 
@@ -715,8 +770,11 @@ class SimulatedPod:
         return self._field.sp
 
     # ------------------------------------------------------------------
-    def _local_round(self, inputs, key):
-        """Per-device body under shard_map: inputs [P_loc, d_loc]."""
+    def _local_round(self, inputs, key, reported=None):
+        """Per-device body under shard_map: inputs [P_loc, d_loc]; with
+        ``reported`` [P_loc] (bool, traced) the rows that did not report
+        count as zero, and the mesh's count of reporters is returned
+        beside the aggregate."""
         f = self._field
         P_loc, d_loc = inputs.shape
         draws = _draw_scope(self.pallas_active)
@@ -737,7 +795,7 @@ class SimulatedPod:
                 self.scheme, f, self._M_host, self.masking, x, dev_key,
                 round_key=key, pid_base=pid0, d_block0=dblk0,
                 interpret=self._pallas_interpret,
-                external_bits_fn=self._pallas_bits_fn,
+                external_bits_fn=self._pallas_bits_fn, reported=reported,
             )                                                      # [n, B_loc]
         else:
             # participant parallelism -> local scan-chunked reduction (share
@@ -745,6 +803,7 @@ class SimulatedPod:
             local_sum, local_mask_sum = _scan_combine(
                 f, self.scheme, self.masking, self._M_host, x, dev_key, key,
                 pid0=pid0, dblk0=dblk0, chunk=self.scan_chunk,
+                reported=reported,
             )                                                      # [n, B_loc]
 
         # snapshot transpose + clerk combine == one psum_scatter over ICI:
@@ -765,12 +824,19 @@ class SimulatedPod:
 
         with jax.named_scope("sda.unmask"):
             if local_mask_sum is None:
-                return f.to_int64(masked_total)
-            mask_total = f.canon(jax.lax.psum(local_mask_sum, "p"))
-            return f.to_int64(f.sub(masked_total, mask_total))
+                total = f.to_int64(masked_total)
+            else:
+                mask_total = f.canon(jax.lax.psum(local_mask_sum, "p"))
+                total = f.to_int64(f.sub(masked_total, mask_total))
+            if reported is None:
+                return total
+            # the divisor of a mean over the rows that reported: a scalar
+            # the program reads, the same on every device
+            return total, jax.lax.psum(
+                jnp.sum(reported, dtype=jnp.int32), "p")
 
     def _build(self, P_total: int, d_total: int, around=None,
-               name: str = "mesh.simpod.round"):
+               name: str = "mesh.simpod.round", reported: bool = False):
         p_shards, d_shards = self.mesh.devices.shape
         if P_total % p_shards:
             raise ValueError(f"participants {P_total} not divisible by p axis {p_shards}")
@@ -780,11 +846,12 @@ class SimulatedPod:
                 f"dimension {d_total} must be divisible by the scheme/mesh "
                 f"grain {grain}"
             )
+        metrics.count("mesh.round.builds")
         fn = _shard_map(
             self._local_round,
             mesh=self.mesh,
-            in_specs=(P("p", "d"), P()),
-            out_specs=P("d"),
+            in_specs=(P("p", "d"), P()) + ((P("p"),) if reported else ()),
+            out_specs=(P("d"), P()) if reported else P("d"),
         )
         if around is not None:  # round_program: one program with the round
             fn = around(fn)
@@ -810,8 +877,14 @@ class SimulatedPod:
             -(-d_total // grain) * grain,
         )
 
-    def aggregate(self, inputs, key=None):
+    def aggregate(self, inputs, key=None, reported=None):
         """[P, d] participant inputs -> [d] aggregate (one full round).
+
+        ``reported`` ([P] of 0/1, NumPy, a sequence or a ``jax.Array``):
+        the rows that count. A row whose entry is 0 adds exactly zero to
+        the aggregate whatever it holds; the operand is a value the
+        compiled round reads, so every set of reporters over a buffer of
+        ``P`` rows runs one program. The caller knows its count.
 
         Spans: ``pod.pad`` (only when a pad happens), then ``mesh.round``
         = ``pod.feed`` + ``pod.dispatch`` + ``pod.wait``, then
@@ -829,6 +902,8 @@ class SimulatedPod:
             key = fresh_prng_key()
         P_total, d_total = inputs.shape
         P_pad, d_pad = self.padded_shape(P_total, d_total)
+        if reported is not None:
+            reported = _reported_operand(reported, P_total, P_pad)
         trace = obs.sibling_context()
         pad_bytes = 0
         if (P_pad, d_pad) != (P_total, d_total):
@@ -843,7 +918,7 @@ class SimulatedPod:
                     padded[:P_total, :d_total] = inputs
                     inputs = padded
             pad_bytes = inputs.nbytes
-        step = self._get_step(P_pad, d_pad)
+        step = self._get_step(P_pad, d_pad, reported is not None)
         sharding = NamedSharding(self.mesh, P("p", "d"))
         metrics.count("mesh.feed.calls")
         metrics.count("mesh.feed.bytes", inputs.nbytes)
@@ -858,31 +933,44 @@ class SimulatedPod:
                     "bytes": inputs.nbytes, "dtype": str(inputs.dtype),
                     "shape": list(inputs.shape)}):
                 device_inputs = jax.device_put(inputs, sharding)
-            out = step(device_inputs, key)  # opens pod.dispatch (_build)
+            # the step opens pod.dispatch (_build)
+            if reported is None:
+                out = step(device_inputs, key)
+            else:
+                out, _ = step(device_inputs, key, jax.device_put(
+                    reported, NamedSharding(self.mesh, P("p"))))
             with obs.span("pod.wait"):
                 out.block_until_ready()
         with obs.span("pod.strip", parent=trace):
             return out[:d_total]
 
-    def _get_step(self, P_pad: int, d_pad: int):
+    def _get_step(self, P_pad: int, d_pad: int, reported: bool = False):
         """The jitted SPMD round for an already-padded shape (one-shape
-        cache, shared by aggregate() and multihost.aggregate_process_local)."""
-        shape = (P_pad, d_pad)
+        cache, shared by aggregate() and multihost.aggregate_process_local);
+        the round that takes ``reported`` is another program than the one
+        that does not."""
+        shape = (P_pad, d_pad, reported)
         if self._step is None or self._step_shape != shape:
-            self._step = self._build(*shape)
+            self._step = self._build(P_pad, d_pad, reported=reported)
             self._step_shape = shape
         return self._step
 
-    def aggregate_fn(self, P_total: int, d_total: int):
+    def aggregate_fn(self, P_total: int, d_total: int,
+                     reported: bool = False):
         """The raw jitted SPMD round for benchmarking/compile checks
-        (shapes must already satisfy the mesh/scheme grain)."""
-        return self._build(P_total, d_total)
+        (shapes must already satisfy the mesh/scheme grain):
+        ``(inputs, key) -> aggregate``, and with ``reported``
+        ``(inputs, key, reported [P_total] bool) -> (aggregate, the count
+        of rows that reported, int32)``."""
+        return self._build(P_total, d_total, reported=reported)
 
     def round_program(self, P_total: int, d_total: int, around, name: str,
-                      key=None):
+                      key=None, reported: bool = False):
         """The round of ``aggregate_fn`` traced into a caller's program:
         ``around(round_)`` is handed the shard-mapped round
-        ``round_(inputs [P_total, d_total], key) -> [d_total] int64`` and
+        ``round_(inputs [P_total, d_total], key) -> [d_total] int64``
+        (with ``reported``: ``round_(inputs, key, reported [P_total] bool)
+        -> (aggregate, count)``) and
         returns the function to jit in its place, instrumented as ``name``
         with the span and the counters every round's callable has. For a
         caller whose inputs are made on the devices (``models.federated``):
@@ -890,12 +978,13 @@ class SimulatedPod:
         compiler may fuse what makes the residues into the fold that reads
         them, and no ``[P_total, d_total]`` array of residues need stand in
         HBM beside what they were made from. With a hashable ``key`` the
-        program is built once and kept with the pod."""
+        program is built once and kept with the pod: key it on the
+        buffer's rows, never on a count of reporters."""
         if key is None:
-            return self._build(P_total, d_total, around, name)
+            return self._build(P_total, d_total, around, name, reported)
         if (name, key) not in self._programs:
             self._programs[name, key] = self._build(P_total, d_total, around,
-                                                    name)
+                                                    name, reported)
         return self._programs[name, key]
 
 

@@ -65,6 +65,7 @@ from .simpod import (
     _shard_map,
     _share_sum_stage,
     _tile_key,
+    refuse_reported,
 )
 
 #: get_block(p0, p1, d0, d1) -> integer array [p1-p0, d1-d0]
@@ -663,7 +664,8 @@ class StreamingAggregator:
             checkpoint_every_chunks=checkpoint_every_chunks,
         )
 
-    def aggregate(self, inputs, key=None) -> np.ndarray:
+    def aggregate(self, inputs, key=None, reported=None) -> np.ndarray:
+        refuse_reported(reported, "StreamingAggregator")
         inputs = np.asarray(inputs)
         return self.aggregate_blocks(
             array_block_provider(inputs), inputs.shape[0], inputs.shape[1], key
@@ -928,7 +930,8 @@ class StreamedPod:
             restore_accs=restore_accs, checkpointer=checkpointer,
         )
 
-    def aggregate(self, inputs, key=None) -> np.ndarray:
+    def aggregate(self, inputs, key=None, reported=None) -> np.ndarray:
+        refuse_reported(reported, "StreamedPod")
         inputs = np.asarray(inputs)
         return self.aggregate_blocks(
             array_block_provider(inputs), inputs.shape[0], inputs.shape[1], key

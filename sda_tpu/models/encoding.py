@@ -122,6 +122,60 @@ def ravel_pytree(tree):
     return vec, unravel
 
 
+def _settle_quotient(proposed, c):
+    """uint32 scalars ``c`` in ``[2^23, 2^24)`` and ``proposed`` within 127
+    of ``2^47 / c`` -> ``M = round(2^47 / c)`` as int32, exactly.
+
+    The quotient is never half-way between two integers (an odd number
+    would have to divide a power of two), so ``M`` is the one integer with
+    ``|2^47 - M c| < c / 2``. While ``proposed`` is within 127 of it the
+    remainder ``2^47 - proposed * c`` is below 2^31, and ``-proposed * c``
+    in wrapping uint32 arithmetic is that remainder exactly. One step by
+    the remainder's own float quotient (at most 127, so any division good
+    to a part in a thousand is within one of it) leaves it within about
+    ``c / 2``, and two steps of one by comparison leave it where it
+    belongs."""
+    import jax
+    from jax import numpy as jnp
+
+    c_signed = c.astype(jnp.int32)
+    rest = jax.lax.bitcast_convert_type(-(proposed * c), jnp.int32)
+    step = jnp.round(rest.astype(jnp.float32)
+                     / c.astype(jnp.float32)).astype(jnp.int32)
+    m, rest = proposed.astype(jnp.int32) + step, rest - step * c_signed
+    for _ in range(2):
+        step = ((2 * rest > c_signed).astype(jnp.int32)
+                - (2 * rest < -c_signed).astype(jnp.int32))
+        m, rest = m + step, rest - step * c_signed
+    return m
+
+
+def _reciprocal_device(count):
+    """A traced integer scalar ``1 <= count < 2^24`` -> ``1 / count``
+    correctly rounded to float32, so that every backend gives the bits the
+    host gives for ``np.float32(1.0 / count)``. The chip's float32
+    division does not (PERF.md, PR 42), so its quotient only proposes and
+    32-bit integers decide (``_settle_quotient``; a 64-bit division of a
+    scalar is emulated on the TPU in some two thousand scalar ops).
+
+    With ``2^n <= count < 2^(n+1)`` and ``c = count * 2^(23-n)`` in
+    ``[2^23, 2^24)``, the reciprocal is ``M * 2^-(n+24)`` for ``M =
+    round(2^47 / c)`` in ``[2^23, 2^24]``; the float quotient ``2^47 / c``
+    is an integer as it stands. The float's bits are the exponent field of
+    ``2^-(n+1)`` plus ``M - 2^23``; ``M == 2^24`` (a power of two's
+    reciprocal) carries into the exponent, as it must."""
+    import jax
+    from jax import numpy as jnp
+
+    count = count.astype(jnp.uint32)
+    n = jnp.uint32(31) - jax.lax.clz(count)
+    c = count << (jnp.uint32(23) - n)
+    proposed = (jnp.float32(2.0 ** 47) / c.astype(jnp.float32)).astype(jnp.uint32)
+    m = _settle_quotient(proposed, c)
+    bits = ((jnp.int32(126) - n.astype(jnp.int32)) << 23) + (m - (1 << 23))
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 class FixedPointCodec:
     """Deterministic fixed-point codec float -> Z_m with summand capacity.
 
@@ -335,28 +389,58 @@ class FixedPointCodec:
         lifted = self._lift_device(values, summands)
         return lifted.astype(jnp.float32) / jnp.float32(self.scale)
 
-    def decode_mean_device(self, values, summands: int):
+    def decode_mean_device(self, values, summands, capacity=None):
         """jnp aggregate -> float32 mean of the quantized inputs: against
         ``decode_mean``, which divides in float64, that value rounded to
         float32 to within ``2^-23 * |mean|``.
 
+        ``summands`` is a Python int, folded into the program, or a traced
+        integer scalar -- a round's count of the rows that reported, which
+        the compiled program reads -- with ``capacity``, the static number
+        it cannot pass (the buffer's rows), checked in its place. A traced
+        count of 0 (nobody reported: the aggregate is 0) gives a mean of
+        exactly 0.
+
         Not ``decode_sum_device(...) / summands``: compiled, a division by
         a constant is a multiplication by its rounded reciprocal, a third
-        rounding after the lift's conversion and the product's. The lift
-        is split in integers instead, ``whole * summands + rest``; within
-        the codec's capacity ``|whole| <= q_max <= 2^24`` converts exactly,
-        ``rest / summands`` is below 1 and carries the reciprocal's
-        rounding, and the sum of the two rounds once.
+        rounding after the lift's conversion and the product's, and by a
+        traced divisor it is the chip's float32 division, which is not
+        correctly rounded (PERF.md, PR 42). The lift is split in integers
+        instead, ``lift = whole * summands + rest`` (``lax.div``: toward
+        zero, exact on every backend, by a traced divisor too), and
+
+            mean = float32(whole) + float32(rest) * r,  r = float32(1 / summands)
+
+        The bound, for every count within the capacity: ``|whole| <= q_max
+        <= 2^24`` and ``|rest| < summands <= 2^24`` convert exactly; ``r``
+        is correctly rounded (the constant by the host, the traced one by
+        ``_reciprocal_device`` in integers: the same number bit for bit),
+        so ``rest * r`` is within ``2^-23 |rest / summands|`` of ``rest /
+        summands``, which is below 1 and has ``whole``'s sign; the sum
+        rounds once, ``2^-24 |mean|``. ``whole == 0``: the sum is exact and
+        the error ``2^-23 |mean|``. ``|mean|`` in ``[2^j, 2^(j+1))``, ``j
+        >= 0``: at most ``2^(j-24) + 2^-23 (|mean| - 2^j) <= 2^-23 |mean|``.
+        The division by the scale, a power of two, is exact.
         """
         import jax
         from jax import numpy as jnp
 
-        lifted = self._lift_device(values, summands)
-        count = jnp.asarray(summands, lifted.dtype)
+        if isinstance(summands, (int, np.integer)):
+            lifted = self._lift_device(values, int(summands))
+            count = jnp.asarray(summands, lifted.dtype)
+            reciprocal = jnp.float32(1.0 / summands)
+        else:
+            if capacity is None:
+                raise ValueError(
+                    "a traced count of summands needs its static capacity "
+                    "(the rows of the buffer the round summed over)")
+            lifted = self._lift_device(values, int(capacity))
+            count = jnp.maximum(jnp.asarray(summands, lifted.dtype), 1)
+            reciprocal = _reciprocal_device(count)
         whole = jax.lax.div(lifted, count)  # toward zero: rest keeps the sign
         rest = lifted - whole * count
         mean = (whole.astype(jnp.float32)
-                + rest.astype(jnp.float32) * jnp.float32(1.0 / summands))
+                + rest.astype(jnp.float32) * reciprocal)
         return mean / jnp.float32(self.scale)
 
     # -- misc ----------------------------------------------------------------

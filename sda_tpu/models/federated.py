@@ -164,13 +164,24 @@ class FederatedSession:
 
 
 def _resident_program(pod, codec: FixedPointCodec, participants: int,
-                      dimension: int, with_aggregate: bool = False):
+                      dimension: int, with_aggregate: bool = False,
+                      reported: bool = False):
     """The FedAvg round on arrays the devices hold, as one program of the
     pod: ``program(global_vec [d], client_vecs [P, d], key)`` -> the new
     global vector [d] float32 (with ``with_aggregate``, a test's: the
     round's int64 aggregate beside it, built anew). Built once per (pod,
     codec, shape) by ``pod.round_program`` and kept with the pod; it
     carries the round's ``pod.dispatch`` span and counters.
+
+    With ``reported`` the program takes a fourth operand, ``[P]`` bool:
+    the rows of the buffer that count this round. It is a value the
+    program reads, so one program serves every set of reporters over a
+    buffer of ``participants`` rows (the key holds the buffer's rows, no
+    count). It selects among the *residues*, inside the round's fold: the
+    encode has already scrubbed NaN and clipped, so whatever a row that
+    did not report holds reaches nothing; the mean divides by the round's
+    count of reporters, and with none the global vector comes back as it
+    went in.
 
     Two stage scopes around the round's own: ``sda.encode`` -- the deltas
     ``client - global`` in float32, their fixed-point residues, and the
@@ -186,30 +197,36 @@ def _resident_program(pod, codec: FixedPointCodec, participants: int,
     pad = ((0, rows - participants), (0, width - dimension))
 
     def around(round_):
-        def program(global_vec, client_vecs, key):
+        def program(global_vec, client_vecs, key, *who):
             with jax.named_scope("sda.encode"):
                 global_vec = global_vec.astype(jnp.float32)
                 deltas = client_vecs.astype(jnp.float32) - global_vec[None, :]
                 residues = codec.encode_device(deltas).astype(jnp.uint32)
                 if pad != ((0, 0), (0, 0)):
                     residues = jnp.pad(residues, pad)
-            aggregate = round_(residues, key)
+                if reported:  # the padding rows did not report
+                    who = (jnp.pad(who[0], pad[0]),)
+            if reported:
+                aggregate, count = round_(residues, key, *who)
+            else:
+                aggregate, count = round_(residues, key), participants
             with jax.named_scope("sda.decode"):
                 aggregate = aggregate[:dimension]
                 new_global = global_vec + codec.decode_mean_device(
-                    aggregate, participants)
+                    aggregate, count, capacity=participants)
             return (new_global, aggregate) if with_aggregate else new_global
 
         return program
 
     key = None if with_aggregate else (
         codec.modulus, codec.fractional_bits, codec.clip, participants,
-        dimension)
-    return pod.round_program(rows, width, around, "models.fedavg.round", key)
+        dimension, reported)
+    return pod.round_program(rows, width, around, "models.fedavg.round", key,
+                             reported=reported)
 
 
 def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec, client_vecs,
-                     key=None):
+                     key=None, reported=None):
     """TPU-native FedAvg round: cohort deltas -> mesh round -> mean delta.
 
     ``client_vecs`` is a [P, d] float array (or list of vectors) of client
@@ -218,6 +235,16 @@ def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec, client_vecs,
     all via mesh collectives — no per-client protocol messages). Returns the
     new global vector, exactly global + mean(quantized deltas)/scale.
 
+    ``reported`` ([P] of 0/1: NumPy or a sequence, as the coordinator knows
+    who reported, or a ``jax.Array``) says which rows of the buffer count
+    this round: hand the round the selected cohort's whole buffer and who
+    reported, never a slice of it. A row whose entry is 0 changes no bit of
+    the result whatever it holds (stale weights, NaN, +-inf), and the mean
+    is over the rows that reported; with none, the global vector is
+    returned as it is. The buffer's rows, not the count, must fit the
+    codec's ``max_summands``. ``None`` is a round in which every row
+    reported.
+
     Two contracts, by where the cohort lives:
 
     - **resident** — ``client_vecs`` is a ``jax.Array`` and the pod has a
@@ -225,23 +252,29 @@ def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec, client_vecs,
       and ``global + mean`` are ONE jitted program on the pod's mesh, all
       in float32, and the result is a float32 ``jax.Array`` that is not
       waited for. Nothing of the cohort crosses to the host (a
-      ``global_vec`` given from the host is put on the devices, and
-      counted). Against the host contract: the deltas are formed in
+      ``global_vec`` or a ``reported`` given from the host is put on the
+      devices, and counted). ``reported`` is an operand of that program,
+      not a shape: every set of reporters runs the one program compiled
+      for the buffer. Against the host contract: the deltas are formed in
       float32, not float64, and the mean is the host's rounded to float32
       to within ``2^-23 |mean|`` (``FixedPointCodec.decode_mean_device``).
     - **host** — anything else: the deltas are subtracted in float64 on
       the host and sent to the devices once as float32, encoded there and
-      handed to ``pod.aggregate`` as they are; the aggregate is fetched
-      and decoded in NumPy float64, and the result is a NumPy float64
-      vector. Every surface with ``aggregate(inputs, key)`` is served
-      (``StreamedPod``, ``StreamingAggregator``: they take their inputs to
-      the host themselves).
+      handed to ``pod.aggregate`` as they are (with ``reported``, which
+      the streamed drivers refuse); the aggregate is fetched
+      and decoded in NumPy float64 over the host's count, and the result
+      is a NumPy float64 vector. Every surface with ``aggregate(inputs,
+      key)`` is served (``StreamedPod``, ``StreamingAggregator``: they
+      take their inputs to the host themselves).
 
     Either way one ``fedavg.round`` phase is timed around it (attributes
-    ``participants``, ``dimension``, ``resident``), and
+    ``participants``, ``dimension``, ``resident``, and with the operand
+    given from the host ``reported``, its count), and
     ``models.fedavg.rounds`` / ``models.fedavg.host_bytes`` count the
     rounds and the bytes this function itself moved between host and
-    devices, both ways: 0 on the resident path.
+    devices, both ways: 0 on the resident path but for such operands;
+    ``models.fedavg.reported_rows`` counts the rows a host ``reported``
+    said had reported.
     """
     import jax
     from jax import numpy as jnp
@@ -250,10 +283,12 @@ def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec, client_vecs,
                 and hasattr(pod, "round_program"))
     moved = 0  # bytes between host and devices, by this function
     if not resident:
-        moved = sum(v.nbytes for v in (global_vec, client_vecs)
+        moved = sum(v.nbytes for v in (global_vec, client_vecs, reported)
                     if isinstance(v, jax.Array))
         global_vec = np.asarray(global_vec, dtype=np.float64)
         client_vecs = np.asarray(client_vecs, dtype=np.float64)
+        if reported is not None:
+            reported = np.asarray(reported)
     shape, dim = np.shape(client_vecs), np.shape(global_vec)
     if len(shape) != 2 or len(dim) != 1 or shape[1] != dim[0]:
         raise ValueError(f"client_vecs shape {shape} incompatible "
@@ -266,24 +301,53 @@ def pod_fedavg_round(pod, codec: FixedPointCodec, global_vec, client_vecs,
         raise ValueError(
             f"codec modulus {codec.modulus} != pod modulus {pod_modulus}: "
             "the decoded mean would be garbage")
+    attributes = dict(participants=n, dimension=dim[0], resident=resident)
+    if reported is not None:
+        if np.shape(reported) != (n,):
+            raise ValueError(f"reported has shape {np.shape(reported)}; the "
+                             f"cohort has {n} rows")
+        if not isinstance(reported, jax.Array):
+            # the coordinator's list: counted here, where it is on the host
+            reported = np.asarray(reported).astype(bool)
+            attributes["reported"] = int(reported.sum())
+            metrics.count("models.fedavg.reported_rows", attributes["reported"])
 
     metrics.count("models.fedavg.rounds")
     with timed_phase("fedavg.round") as phase:
-        phase.attributes.update(participants=n, dimension=dim[0],
-                                resident=resident)
+        phase.attributes.update(attributes)
         if resident:
             if not isinstance(global_vec, jax.Array):
                 global_vec = jnp.asarray(global_vec, jnp.float32)
                 moved += global_vec.nbytes
+            who = ()
+            if reported is not None:
+                if isinstance(reported, jax.Array):
+                    reported = reported.astype(bool)
+                else:
+                    # the program's own call puts the list on the devices,
+                    # with its other operands: no transfer of its own
+                    # stands in front of the dispatch (the round waits for
+                    # it: the fold reads it first)
+                    moved += reported.nbytes
+                who = (reported,)
             if key is None:
                 from ..crypto.core import fresh_prng_key
 
                 key = fresh_prng_key()
             metrics.count("models.fedavg.host_bytes", moved)
-            return _resident_program(pod, codec, n, dim[0])(
-                global_vec, client_vecs, key)
+            return _resident_program(
+                pod, codec, n, dim[0], reported=bool(who))(
+                    global_vec, client_vecs, key, *who)
         deltas = jnp.asarray(client_vecs - global_vec[None, :], jnp.float32)
-        summed = np.asarray(pod.aggregate(codec.encode_device(deltas), key))
+        encoded = codec.encode_device(deltas)
+        if reported is None:
+            summed, count = np.asarray(pod.aggregate(encoded, key)), n
+        else:
+            summed = np.asarray(pod.aggregate(encoded, key, reported=reported))
+            count = attributes["reported"]
+            moved += reported.nbytes
         metrics.count("models.fedavg.host_bytes",
                       moved + deltas.nbytes + summed.nbytes)
-        return global_vec + codec.decode_mean(summed, n)
+        if count == 0:  # nobody reported: the global vector holds
+            return global_vec
+        return global_vec + codec.decode_mean(summed, count)

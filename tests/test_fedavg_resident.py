@@ -125,15 +125,24 @@ def test_resident_round_is_the_references_on_every_scheme_masking_and_step(
         [0, q_max, -q_max, q_max, -q_max, q_max, -q_max])
 
 
+@pytest.mark.parametrize("reporters", ["all-rows", "nine-of-13"])
 @pytest.mark.parametrize("shape", [(1, 2), (2, 1)], ids=["1x2", "2x1"])
 @pytest.mark.parametrize("step", ["xla", "kernel"])
-def test_resident_round_on_a_mesh(shape, step):
+def test_resident_round_on_a_mesh(shape, step, reporters):
+    """With ``reported`` the round is the reference's over the rows that
+    reported (the NaN of row 0 among those that did not), its count summed
+    over the mesh's ``p`` axis."""
     pod = _pod("packed", "full", step, mesh=make_mesh(*shape))
     global_vec, clients = _weights(7)
-    _, want, limit = _expected(global_vec, clients)
+    reported = None
+    if reporters == "nine-of-13":
+        reported = np.arange(ROWS) % 3 != 0
+        _, want, limit = _expected(global_vec, clients[reported])
+    else:
+        _, want, limit = _expected(global_vec, clients)
     result = pod_fedavg_round(
         pod, _codec(), *_on(pod.mesh, global_vec, clients,
-                            jax.random.PRNGKey(2)))
+                            jax.random.PRNGKey(2)), reported=reported)
     assert isinstance(result, jax.Array) and result.dtype == jnp.float32
     _assert_within(result, want, limit)
 

@@ -191,10 +191,13 @@ def test_codec_decode_rejects_empty_summand_set():
 P29 = (1 << 29) - 679  # the pod cells' Solinas prime
 
 
+@pytest.mark.parametrize("count", ["constant", "traced"])
 @pytest.mark.parametrize("summands", [1, 7, 1200])
 @pytest.mark.parametrize("modulus", [P29, M31, (1 << 20)])
-def test_codec_device_decode_is_the_hosts_rounded_to_float32(modulus, summands):
-    """``decode_mean_device`` against ``decode_mean``: over the lift's
+def test_codec_device_decode_is_the_hosts_rounded_to_float32(modulus, summands,
+                                                             count):
+    """``decode_mean_device`` against ``decode_mean``, the count folded
+    into the program or an argument it reads: over the lift's
     boundaries, sums on both sides of float32's exact integers (2^24) and
     random residues, the device's float32 mean is the host's float64 one
     rounded to float32 to within 2^-23 |mean| -- the lift's conversion and
@@ -215,7 +218,12 @@ def test_codec_device_decode_is_the_hosts_rounded_to_float32(modulus, summands):
         [edges, wide, rng.integers(0, modulus, size=4096)]).astype(np.int64)
     for dtype in (jnp.int64, jnp.uint32):
         on_device = jnp.asarray(values, dtype)
-        mean = jax.jit(lambda v: codec.decode_mean_device(v, summands))(on_device)
+        if count == "constant":
+            mean = jax.jit(
+                lambda v: codec.decode_mean_device(v, summands))(on_device)
+        else:
+            mean = jax.jit(lambda v, c: codec.decode_mean_device(
+                v, c, capacity=1200))(on_device, jnp.int32(summands))
         total = codec.decode_sum_device(on_device, summands)
         assert mean.dtype == total.dtype == jnp.float32
         for got, host in ((mean, codec.decode_mean(values, summands)),

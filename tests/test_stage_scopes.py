@@ -1,9 +1,10 @@
 """The stage scopes of a round (docs/observability.md, "Stage scopes"):
 one closed list of ``jax.named_scope`` names, and every device op a round's
-own statements make stands under exactly one of them. Five rounds on the
+own statements make stands under exactly one of them. Seven rounds on the
 CPU (the kernel interpreted and fed external bits where the step is the
 kernel; the fifth is the resident FedAvg program, its codec's two scopes
-around the round's), each held to the plain sum bit for bit; and the
+around the round's; the last two take the ``reported`` operand, which adds
+ops and no scope), each held to the plain sum bit for bit; and the
 compile cache, which the program keys on those scopes wherever it leaves one in force."""
 
 import re
@@ -57,6 +58,12 @@ EXPECTED = {
         KERNEL | LAGRANGE | {"sda.clerk_combine", "sda.unmask",
                              "sda.encode", "sda.decode"},
 }
+# the rounds that are told who reported name what the others name: the
+# select stands under sda.fold, the count under sda.unmask and sda.decode
+EXPECTED.update({
+    "fedavg-packed-full-kernel-reported": EXPECTED["fedavg-packed-full-kernel"],
+    "additive-chacha-xla-reported": EXPECTED["additive-chacha-xla"]})
+REPORTED = np.arange(13) % 4 != 1   # 9 of the 13 rows
 #: what ``lax.scan`` lowers to around a body that stands under no stage (the
 #: XLA step's): its counter, its test, the slice of a block: no statement's
 SCAN_OWN = re.compile(
@@ -75,8 +82,11 @@ def _inputs() -> np.ndarray:
 
 
 def _round(name: str):
-    """-> (lowered programs, the round's aggregate of ``_inputs()``)."""
+    """-> (lowered programs, the round's aggregate of ``_inputs()``, of
+    the rows ``REPORTED`` where the round is told who reported)."""
     inputs, key = _inputs(), jax.random.PRNGKey(38)
+    who = (jnp.asarray(REPORTED),) if name.endswith("reported") else ()
+    told = (jax.ShapeDtypeStruct((ROWS,), jnp.bool_),) if who else ()
     if name == "streamed-step-and-finale":
         agg = StreamingAggregator(_packed(), FullMasking(MODULUS),
                                   participants_chunk=8, use_pallas=True,
@@ -95,7 +105,7 @@ def _round(name: str):
               else _packed())
     masking = (ChaChaMasking(MODULUS, DIM, 128) if "chacha" in name
                else FullMasking(MODULUS))
-    kernel = name.endswith("kernel")
+    kernel = "kernel" in name
     pod = SimulatedPod(scheme, masking, mesh=make_mesh(1, 1),
                        use_pallas=kernel, **(INTERPRETED if kernel else {}))
     assert pod.pallas_active is kernel
@@ -105,19 +115,20 @@ def _round(name: str):
         # weights whose deltas are the inputs as 20 fractional bits exactly:
         # the program's integer stage must reveal their plain sum
         codec = FixedPointCodec(MODULUS, 20, max_summands=ROWS, clip=1.0)
-        program = federated._resident_program(pod, codec, rows, dim,
-                                              with_aggregate=True)
+        program = federated._resident_program(
+            pod, codec, rows, dim, with_aggregate=True, reported=bool(who))
         global_vec = jnp.full((dim,), 0.5, jnp.float32)
         clients = global_vec + jnp.asarray(inputs / 2.0 ** 20, jnp.float32)
         lowered = [program.lower(
             jax.ShapeDtypeStruct((dim,), jnp.float32),
             jax.ShapeDtypeStruct((rows, dim), jnp.float32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32))]
-        return lowered, np.asarray(program(global_vec, clients, key)[1])
-    step = pod.aggregate_fn(rows, dim)
+            jax.ShapeDtypeStruct((2,), jnp.uint32), *told)]
+        return lowered, np.asarray(program(global_vec, clients, key, *who)[1])
+    step = pod.aggregate_fn(rows, dim, reported=bool(who))
     lowered = [step.lower(jax.ShapeDtypeStruct((rows, dim), jnp.uint32),
-                          jax.ShapeDtypeStruct((2,), jnp.uint32))]
-    return lowered, np.asarray(step(jnp.asarray(inputs, jnp.uint32), key))
+                          jax.ShapeDtypeStruct((2,), jnp.uint32), *told)]
+    out = step(jnp.asarray(inputs, jnp.uint32), key, *who)
+    return lowered, np.asarray(out[0] if who else out)
 
 
 def _op_paths(lowered):
@@ -137,9 +148,10 @@ def test_a_round_names_its_stages_and_reveals_the_plain_sum(name):
     assert named <= STAGES | CHILDREN
     # sda.blocks is the XLA step's scan; the two children of sda.reconstruct
     # wherever there is a Lagrange product
-    assert ("sda.blocks" in named) == name.endswith("xla")
+    assert ("sda.blocks" in named) == ("xla" in name)
     assert (LAGRANGE <= named) == (not name.startswith("additive"))
-    want = _inputs().sum(axis=0) % MODULUS
+    rows = REPORTED if name.endswith("reported") else slice(None)
+    want = _inputs()[rows].sum(axis=0) % MODULUS
     assert aggregate.dtype == np.int64 and np.array_equal(aggregate, want)
 
 
@@ -158,7 +170,7 @@ def test_every_op_of_a_round_stands_under_exactly_one_stage(name):
             assert child.rsplit(".", 1)[0] in path[:path.index(child)]
     # only the XLA step's scan stands under no stage
     own = [path for _, path in ops if not STAGES.intersection(path)]
-    assert bool(own) == name.endswith("xla")
+    assert bool(own) == ("xla" in name)
 
 
 @pytest.fixture

@@ -35,16 +35,23 @@ def _packed_scheme():
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_host():
+    """The four described chips of a v5e:2x2 host."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here, or another process holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_host):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_host[0])
 
 
 ROWS, DIM = 8, 1_000_000  # one scan block of the cell ``additive-chacha-1m``
@@ -271,11 +278,7 @@ def test_packed_chacha_round_changes_layout_once_after_the_scan(
 COHORT = (1200, 999_999)  # the cell ``fedavg-f32-1m``: float32 client weights
 
 
-@pytest.fixture(scope="module")
-def fedavg_round_compiled(one_chip):
-    """``pod_fedavg_round``'s resident program (models/federated.py) at the
-    cell's size: packed 3/8/4, full masks, the fused kernel, the codec of
-    ``pod-fedavg-packed8``."""
+def _fedavg_round_compiled(one_chip, reported: bool):
     from jax.sharding import Mesh
 
     from sda_tpu.models import FixedPointCodec, federated
@@ -285,9 +288,26 @@ def fedavg_round_compiled(one_chip):
     pod = simpod.SimulatedPod(_packed_scheme(), FullMasking(MODULUS),
                               mesh=mesh, use_pallas=True)
     codec = FixedPointCodec(MODULUS, 16, max_summands=COHORT[0], clip=2.0)
-    program = federated._resident_program(pod, codec, *COHORT)
+    program = federated._resident_program(pod, codec, *COHORT,
+                                          reported=reported)
+    who = [(COHORT[:1], jnp.bool_)] if reported else []
     return _compile_for(one_chip, program, (COHORT[1:], jnp.float32),
-                        (COHORT, jnp.float32), ((2,), jnp.uint32))
+                        (COHORT, jnp.float32), ((2,), jnp.uint32), *who)
+
+
+@pytest.fixture(scope="module")
+def fedavg_round_compiled(one_chip):
+    """``pod_fedavg_round``'s resident program (models/federated.py) at the
+    cell's size: packed 3/8/4, full masks, the fused kernel, the codec of
+    ``pod-fedavg-packed8``."""
+    return _fedavg_round_compiled(one_chip, reported=False)
+
+
+@pytest.fixture(scope="module")
+def sporadic_round_compiled(one_chip):
+    """The same with the ``reported`` operand, ``bool[1200]``: the cell
+    ``fedavg-sporadic-1m``'s one program (PR 44)."""
+    return _fedavg_round_compiled(one_chip, reported=True)
 
 
 def test_fedavg_round_writes_no_residue_of_the_cohorts_shape(
@@ -308,6 +328,35 @@ def test_fedavg_round_writes_no_residue_of_the_cohorts_shape(
     # the fold reads the weights themselves: one pass over the 4.8 GB
     (fold,) = re.findall(r"fusion\(%client_vecs[.\d]*, %global_vec[.\d]*\)[^\n]*", text)
     assert "sda.fold" in fold
+
+
+def test_sporadic_round_selects_inside_the_folds_one_read_of_the_cohort(
+        sporadic_round_compiled, fedavg_round_compiled):
+    """Told who reported, the round still reads the float32 cohort in ONE
+    fusion, root under ``sda.fold``, and that fusion takes the ``reported``
+    operand: the select fuses into the read as the encode does. No array
+    of the cohort's extent but the input -- no ``[1200, 999999]`` or
+    ``[1200, 1000008]`` of selected residues -- and the packed round's
+    temporaries. The count's sum is the one op the operand adds to the
+    device's trace; the reciprocal of the count is some forty scalar ops
+    (two thousand as an emulated 64-bit division, PR 44)."""
+    text = sporadic_round_compiled.as_text()
+    assert "tpu_custom_call" in text and "sda.decode" in text
+    wide = {(dtype, dims) for dtype, dims in _written_arrays(text)
+            if math.prod(dims) >= math.prod(COHORT)}
+    assert wide == {("f32", COHORT)}, wide
+    assert sporadic_round_compiled.memory_analysis().temp_size_in_bytes < 100e6
+    readers = re.findall(r"[^\n]*\(%client_vecs[.\d]*[,)][^\n]*", text)
+    (fold,) = [line for line in readers if " fusion(" in line]
+    assert "sda.fold" in fold and "%who" in fold, fold
+    assert not re.search(r"\b[us]64\[\]", text)   # no emulated 64-bit scalar
+    ops, plain = (_entry_ops(c.as_text()) for c in (
+        sporadic_round_compiled, fedavg_round_compiled))
+    assert len(ops) - len(plain) < 100, (len(ops), len(plain))
+    traced = lambda found: sorted(        # noqa: E731  (what a trace shows)
+        name for opcode, name in found if opcode in ("fusion", "custom-call"))
+    added = set(traced(ops)) - set(traced(plain))
+    assert added == {"jit(program)/sda.unmask/reduce_sum"}, added
 
 
 # -- the packed round's layout changes (PR 43): ``batch_columns`` in front of
@@ -479,3 +528,133 @@ def test_external_bits_kernel_keeps_its_participant_axis(one_chip):
     grid, draws = _kernel_compiled(one_chip, 1200, True, external=True)
     assert grid == [KERNEL_COLUMNS // KERNEL_TILE, 3]
     assert not draws
+
+
+# -- the accepted cells' rounds as they are lowered for the chip (PR 44): a
+# change that means to leave a cell's program alone shows it here. The
+# method of PRs 31-43, kept as a test: the lowered text, every Mosaic module
+# deserialized and printed without its debug locations (they hold the
+# caller's line numbers), sha256. A pin is the value at the parent commit
+# of the PR that last changed that cell's program ON PURPOSE: such a PR
+# replaces the pin of the cells it changes (the failure prints the value)
+# and says so; an untouched pin is the proof that the others lower to the
+# parent's text.
+
+LOWERED_SHA256 = {
+    "packed-1m": "ed573eb07a503a1ff82193b857b876960605f656dabf6ad9dd0dd6c09740254d",
+    "packed-1m-hostfed": "5fa51410850772063bf34f48ee6a9bf57248a5bd835ea4bfab3cc0ab82478de1",
+    "packed-1m-mesh4": "e322816bc5795a67b75c3e476e17b1e39af921343bc2534ec383abdba15e91e4",
+    "additive-chacha-1m": "320be16eb5dfbcb55127e0a4a2bf9d06d99aa66aee219544ce934164e94b97df",
+    "packed-1m-streamed": "3cc029c33dabc5f695b23528a9a2b1e515ff9ac7d238f2015d8110faab595bda",
+    "packed-chacha-1m": "9bf45fc82c2963449c18d43f9b4ce558e055bdf19ef4fa653a29b8e29632d6d3",
+    "fedavg-f32-1m": "dbf5d9b29710a4446e3133f7902de96be8e36b67067b830c7b70b1792c83487d",
+}
+
+
+def _without_mosaic_locations(text: str) -> str:
+    """``text`` with each serialized Mosaic module replaced by the hash of
+    its assembly printed without locations."""
+    import base64
+    import hashlib
+    import json
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    from jaxlib.mlir.passmanager import PassManager
+
+    def unescape(literal):  # an MLIR string literal's escapes
+        simple = {"n": "\n", "t": "\t", "\\": "\\", '"': '"'}
+        return re.sub(
+            r'\\([0-9A-Fa-f]{2}|[nt"\\])',
+            lambda m: simple.get(m.group(1)) or chr(int(m.group(1), 16)),
+            literal)
+
+    def replace(match):
+        config = json.loads(unescape(match.group(1)))
+        if "custom_call_config" not in config:
+            return match.group(0)
+        with mlir.make_ir_context() as context:
+            tpu.register_dialect(context)
+            context.allow_unregistered_dialects = True
+            module = ir.Module.parse(
+                base64.b64decode(config["custom_call_config"]["body"]))
+            PassManager.parse(
+                "builtin.module(mosaic-serde{serialize=false})").run(
+                    module.operation)
+            assembly = module.operation.get_asm(enable_debug_info=False)
+        config["custom_call_config"]["body"] = hashlib.sha256(
+            assembly.encode()).hexdigest()
+        return "backend_config = " + json.dumps(config, sort_keys=True)
+
+    return re.sub(r'backend_config = "((?:[^"\\]|\\.)*)"', replace, text)
+
+
+def _cell_lowered(cell, devices):
+    """The lowered round(s) of one cell of BENCHMARK.json on described
+    ``devices``, built by the cell's own driver from its own files."""
+    from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+    import harness
+
+    config, traffic = cell.config, cell.traffic
+    participants, dim = traffic["participants"], traffic["dim"]
+    driver = harness.load_module(cell.home, "drivers", config["driver"])
+    if config["driver"] == "stream":
+        agg = driver.build_aggregator(config)
+        one = SingleDeviceSharding(devices[0])
+
+        def of(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+        rows, scheme, dtype = config["participants_chunk"], agg.scheme, agg._field.dtype
+        accs = (of((scheme.output_size, dim // scheme.input_size), dtype),
+                of((dim,), dtype))
+        return [agg._step_fn((rows, dim)).lower(
+                    of((rows, dim), jnp.int64), of((2,), jnp.uint32),
+                    of((2,), jnp.uint32), of((), jnp.int32), of((), jnp.int32),
+                    *accs),
+                agg._final_fn(dim).lower(*accs)]
+    codec = None
+    if config["driver"] == "pod_fedavg":
+        pod, codec = driver.build_pod(config, devices)
+    elif config["driver"] == "pod":
+        pod = driver.build_pod(config, devices)
+    else:
+        pod = driver.build_pod(config, dim, devices)
+
+    def on(spec, shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(pod.mesh, PartitionSpec(*spec)))
+
+    key = on((), (2,), jnp.uint32)
+    if codec is not None:
+        from sda_tpu.models import federated
+
+        program = federated._resident_program(pod, codec, participants, dim)
+        return [program.lower(on(("d",), (dim,), jnp.float32),
+                              on(("p", "d"), (participants, dim), jnp.float32), key)]
+    padded = pod.padded_shape(participants, dim)
+    dtype = jnp.int64 if traffic["input"] == "host" else jnp.uint32
+    return [pod.aggregate_fn(*padded).lower(on(("p", "d"), padded, dtype), key)]
+
+
+@pytest.mark.parametrize("name", sorted(LOWERED_SHA256))
+def test_an_accepted_cells_round_lowers_to_the_text_it_had(v5e_host, name):
+    import hashlib
+    import sys
+    from pathlib import Path
+
+    home = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    sys.path.insert(0, str(home))
+    try:
+        import harness
+
+        cell = harness.load_cell(harness.ROOT, name)
+        texts = [_without_mosaic_locations(lowered.as_text())
+                 for lowered in _cell_lowered(cell, v5e_host[:cell.chips])]
+    finally:
+        sys.path.remove(str(home))
+    assert "body\\22" not in "".join(texts)   # every kernel's module was read
+    digest = hashlib.sha256("\n".join(texts).encode()).hexdigest()
+    assert digest == LOWERED_SHA256[name], (name, digest)
