@@ -25,6 +25,7 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from . import chacha, layout
 from .chacha import _CONSTANTS
@@ -32,19 +33,42 @@ from .chacha import _CONSTANTS
 _U32 = jnp.uint32
 
 
+# The rounds are written in lax ops: the same equations as the operators
+# (``x << n | x >> 32 - n``, ``+``, ``^``), without the array API's own
+# dispatch on every one of the twenty rounds' 1,600 -- a round's trace,
+# which every warm start pays again, is mostly theirs
+
+
 def _rotl(x, n: int):
-    return (x << _U32(n)) | (x >> _U32(32 - n))
+    return lax.bitwise_or(lax.shift_left(x, _U32(n)),
+                          lax.shift_right_logical(x, _U32(32 - n)))
 
 
 def _quarter(s, a, b, c, d):
-    s[a] = s[a] + s[b]
-    s[d] = _rotl(s[d] ^ s[a], 16)
-    s[c] = s[c] + s[d]
-    s[b] = _rotl(s[b] ^ s[c], 12)
-    s[a] = s[a] + s[b]
-    s[d] = _rotl(s[d] ^ s[a], 8)
-    s[c] = s[c] + s[d]
-    s[b] = _rotl(s[b] ^ s[c], 7)
+    s[a] = lax.add(s[a], s[b])
+    s[d] = _rotl(lax.bitwise_xor(s[d], s[a]), 16)
+    s[c] = lax.add(s[c], s[d])
+    s[b] = _rotl(lax.bitwise_xor(s[b], s[c]), 12)
+    s[a] = lax.add(s[a], s[b])
+    s[d] = _rotl(lax.bitwise_xor(s[d], s[a]), 8)
+    s[c] = lax.add(s[c], s[d])
+    s[b] = _rotl(lax.bitwise_xor(s[b], s[c]), 7)
+
+
+def double_round(state):
+    """Two of the block function's twenty rounds, a column round and a
+    diagonal round, on the sixteen state words (a list, updated in place
+    and returned). The XLA block function runs it ten times in Python;
+    the on-core cipher (``chacha_kernel``) runs it in a ten-step loop."""
+    _quarter(state, 0, 4, 8, 12)
+    _quarter(state, 1, 5, 9, 13)
+    _quarter(state, 2, 6, 10, 14)
+    _quarter(state, 3, 7, 11, 15)
+    _quarter(state, 0, 5, 10, 15)
+    _quarter(state, 1, 6, 11, 12)
+    _quarter(state, 2, 7, 8, 13)
+    _quarter(state, 3, 4, 9, 14)
+    return state
 
 
 def _block_word_arrays(seed_words, counter0, nblocks: int):
@@ -59,14 +83,7 @@ def _block_word_arrays(seed_words, counter0, nblocks: int):
     )
     state = list(init)
     for _ in range(10):
-        _quarter(state, 0, 4, 8, 12)
-        _quarter(state, 1, 5, 9, 13)
-        _quarter(state, 2, 6, 10, 14)
-        _quarter(state, 3, 7, 11, 15)
-        _quarter(state, 0, 5, 10, 15)
-        _quarter(state, 1, 6, 11, 12)
-        _quarter(state, 2, 7, 8, 13)
-        _quarter(state, 3, 4, 9, 14)
+        double_round(state)
     return [s + i for s, i in zip(state, init)]
 
 

@@ -57,6 +57,7 @@ from ..obs import devprof
 from ..utils import metrics, timed_phase
 from .simpod import (
     _build_matrices,
+    _chacha_cipher,
     _check_collective_headroom,
     _check_mask_modulus,
     _check_masking_supported,
@@ -230,6 +231,7 @@ class ModelScaleRound:
         _check_collective_headroom(self._field, p_shards)
         self.pallas_active = _resolve_pallas(
             s, self.masking, self._field, use_pallas, "model-scale")
+        self._cipher = _chacha_cipher(self._field, mesh.devices)
         self._pallas_interpret = bool(pallas_interpret)
         self._pallas_bits_fn = pallas_external_bits_fn
         # tile grain: whole packing columns AND whole ChaCha blocks (the
@@ -281,11 +283,12 @@ class ModelScaleRound:
                     d_block0=d_block0,
                     interpret=self._pallas_interpret,
                     external_bits_fn=self._pallas_bits_fn,
+                    cipher=self._cipher,
                 )
             else:
                 masked_sum, mask_sum, skey = _mask_stage(
                     masking, f, x, dev_key, round_key,
-                    pid_base=pid0, d_block0=d_block0,
+                    pid_base=pid0, d_block0=d_block0, cipher=self._cipher,
                 )
                 shares = _share_sum_stage(
                     s, f, self._M_host, masked_sum, x.shape[0], skey)

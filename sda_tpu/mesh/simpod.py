@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..fields import chacha_jax, fastfield, numtheory, sharing
+from ..fields import chacha_jax, chacha_kernel, fastfield, numtheory, sharing
 from ..fields.ops import FieldOps
 from .. import obs
 from ..obs import devprof
@@ -219,8 +219,37 @@ def _chacha_seed_words(key, global_ids, seed_bitsize: int):
 
 
 #: participant rows a block of the XLA step's scan holds by default, and
-#: the rows the kernel path expands ChaCha masks for at a time
+#: the rows the kernel path's XLA cipher expands ChaCha masks for at a time
 _SCAN_CHUNK = 8
+
+#: the platforms whose steps expand ChaCha masks with the on-core cipher
+#: (``fields/chacha_kernel.py``) over a uint32 field, each with the cipher
+#: they take: the chip compiles the kernel with Mosaic. Every other step
+#: runs the XLA block function (``_chacha_cipher``)
+_ON_CORE_CIPHER = {"tpu": "kernel"}
+
+
+def _chacha_cipher(f: FieldOps, devices) -> str:
+    """The cipher a step over ``f`` built for ``devices`` expands its
+    ChaCha masks with: ``"kernel"`` (the on-core cipher, compiled) where
+    they are TPUs and ``f`` is a uint32 Solinas field, ``"xla"`` (the XLA
+    block function) everywhere else; ``"interpret"`` is the kernel
+    interpreted, which only a test asks for. Decided from what the step is
+    built for, never by an option, and settled before it is traced: the
+    other cipher is neither traced nor lowered."""
+    platforms = {d.platform for d in np.ravel(devices)}
+    if f.sp is None or len(platforms) != 1:
+        return "xla"
+    return _ON_CORE_CIPHER.get(platforms.pop(), "xla")
+
+
+def _chacha_seeds(masking, round_key, pid_base, rows: int, d_loc: int):
+    """[rows, 8] seed words of the participants ``pid_base .. + rows``."""
+    if d_loc % 8:
+        raise ValueError(
+            "dimension must be a multiple of 8 (one ChaCha block)")
+    return _chacha_seed_words(round_key, pid_base + jnp.arange(rows),
+                              masking.seed_bitsize)
 
 
 def _chacha_masks(masking, f: FieldOps, round_key, pid_base, rows: int,
@@ -228,16 +257,13 @@ def _chacha_masks(masking, f: FieldOps, round_key, pid_base, rows: int,
     """-> residues [rows, 8, d_loc/8] of the participants ``pid_base .. +
     rows``: each one's CHACHA_PRG_V1 stream from block ``d_block0`` on,
     reduced modulo the field's modulus, in the block function's word-major
-    layout (``out[s, j, b]`` masks element ``8 * b + j``)."""
-    gids = pid_base + jnp.arange(rows)
-    seeds = _chacha_seed_words(round_key, gids, masking.seed_bitsize)
-    if d_loc % 8:
-        raise ValueError(
-            "dimension must be a multiple of 8 (one ChaCha block)")
+    layout (``out[s, j, b]`` masks element ``8 * b + j``). The XLA block
+    function's."""
+    seeds = _chacha_seeds(masking, round_key, pid_base, rows, d_loc)
     # The draws keep the block function's word-major layout
     # [S, 8, d_loc/8] through pairing and reduction, both elementwise,
     # and so does the fold over the rows that every reader of the masks
-    # starts with (``_chacha_mask_fold``): the layout change is a
+    # starts with (``_chacha_block_fold``): the layout change is a
     # permutation and commutes with it. A scope each, so the device
     # trace tells cipher, reduction, fold and layout change apart
     # (docs/observability.md)
@@ -248,14 +274,38 @@ def _chacha_masks(masking, f: FieldOps, round_key, pid_base, rows: int,
         return f.from_u64(draws)
 
 
-def _chacha_mask_fold(masking, f: FieldOps, round_key, pid_base, rows: int,
-                      d_loc: int, d_block0):
-    """-> [8, d_loc/8]: the masks of ``rows`` participants summed over the
-    rows, word-major as ``_chacha_masks`` makes them."""
+def _chacha_block_fold(masking, f: FieldOps, round_key, pid_base, rows: int,
+                       d_loc: int, d_block0):
+    """``_chacha_mask_fold`` with the XLA block function: the masks of
+    ``_chacha_masks`` summed over the rows."""
     masks = _chacha_masks(masking, f, round_key, pid_base, rows, d_loc,
                           d_block0)
     with jax.named_scope("sda.mask.fold"):
         return f.sum(masks, axis=0)
+
+
+def _chacha_kernel_fold(masking, f: FieldOps, round_key, pid_base,
+                        rows: int, d_loc: int, d_block0, interpret: bool):
+    """``_chacha_mask_fold`` with the on-core cipher: ONE Pallas kernel,
+    ``sda_chacha_mask_fold``, makes the sum of the rows' reduced draws
+    (``fields/chacha_kernel.py``), bit for bit ``_chacha_block_fold``'s."""
+    seeds = _chacha_seeds(masking, round_key, pid_base, rows, d_loc)
+    with jax.named_scope("sda.mask.chacha"):
+        return chacha_kernel.mask_fold(seeds, d_block0, nblocks=d_loc // 8,
+                                       sp=f.sp, interpret=interpret)
+
+
+def _chacha_mask_fold(masking, f: FieldOps, round_key, pid_base, rows: int,
+                      d_loc: int, d_block0, cipher: str = "xla"):
+    """-> [8, d_loc/8]: the masks of ``rows`` participants summed over the
+    rows, word-major as ``_chacha_masks`` makes them, by the ``cipher`` of
+    ``_chacha_cipher``: the on-core cipher's one kernel call, or the XLA
+    block function, the reduction and the fold."""
+    if cipher == "xla":
+        return _chacha_block_fold(masking, f, round_key, pid_base, rows,
+                                  d_loc, d_block0)
+    return _chacha_kernel_fold(masking, f, round_key, pid_base, rows, d_loc,
+                               d_block0, interpret=cipher == "interpret")
 
 
 def _element_order(folded):
@@ -268,19 +318,22 @@ def _element_order(folded):
         return chacha_jax.element_order(folded)
 
 
-def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
+def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0,
+                cipher: str = "xla"):
     """-> (masked_sum [d_loc], local_mask_sum [d_loc] or None, share_key):
     the block's rows folded, Σ (x + mask), and the fold of their masks.
 
     Σ (x + m) = Σ x + Σ m mod p bit for bit, so the masks never meet the
     [S, d_loc] input, only its fold, and no [S, d_loc] array of masks or
     masked rows exists; under ChaCha masking the masks fold on the layout
-    the cipher makes them in and ONE row goes through ``_element_order``.
+    the cipher makes them in (``_chacha_mask_fold``: on a TPU one kernel
+    call a block) and ONE row goes through ``_element_order``.
 
     ``pid_base``: global id of the first local participant row (ChaCha
     seeds are a function of (round key, global participant id) only).
     ``d_block0``: ChaCha block counter at this shard's dim offset
-    (= global_dim_offset / 8). Both may be traced.
+    (= global_dim_offset / 8). Both may be traced. ``cipher``: the one the
+    step was built for (``_chacha_cipher``).
     """
     S, d_loc = x.shape
     with jax.named_scope("sda.fold"):
@@ -296,7 +349,7 @@ def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
         elif isinstance(masking, ChaChaMasking):
             skey = key
             mask_sum = _element_order(_chacha_mask_fold(
-                masking, f, round_key, pid_base, S, d_loc, d_block0))
+                masking, f, round_key, pid_base, S, d_loc, d_block0, cipher))
         else:
             return x_sum, None, key
         with jax.named_scope("sda.mask.fold"):
@@ -304,26 +357,32 @@ def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0):
 
 
 def _chacha_mask_sum(masking, f: FieldOps, round_key, pid_base, rows: int,
-                     d_loc: int, d_block0):
-    """-> [d_loc] sum of the ChaCha masks of ``rows`` participants, expanded
-    ``_SCAN_CHUNK`` rows at a time under a running sum: what is live is one
-    block's draws, whatever ``rows`` (the whole [rows, d_loc] block at once
-    is 22 MB of temporaries a row at a million elements, and 1200 rows do
-    not compile for a v5e: PERF.md, PR 35). The running sum is word-major
-    like the blocks' folds and is put in element order ONCE, after the
-    scan. Rows that do not fill the last block are expanded too, as
+                     d_loc: int, d_block0, cipher: str = "xla"):
+    """-> [d_loc] sum of the ChaCha masks of ``rows`` participants, put in
+    element order ONCE. The on-core cipher makes the sum of all ``rows``
+    in ONE kernel call, whatever their number. The XLA block function
+    expands them ``_SCAN_CHUNK`` rows at a time under a word-major running
+    sum: what is live is one block's draws, whatever ``rows`` (the whole
+    [rows, d_loc] block at once is 22 MB of temporaries a row at a million
+    elements, and 1200 rows do not compile for a v5e: PERF.md).
+    There, rows that do not fill the last block are expanded too, as
     ``_scan_combine`` expands its zero rows: the sum is added to the fold
     of the inputs and subtracted from the reveal, so every mask in it
     cancels."""
-    chunk, padded_rows = _scan_rows(rows, _SCAN_CHUNK)
-
-    def body(acc, i):
-        fold = _chacha_mask_fold(masking, f, round_key, pid_base + i * chunk,
-                                 chunk, d_loc, d_block0)
-        with jax.named_scope("sda.mask.fold"):
-            return f.add(acc, fold), None
-
     with jax.named_scope("sda.mask"):
+        if cipher != "xla":
+            return _element_order(_chacha_mask_fold(
+                masking, f, round_key, pid_base, rows, d_loc, d_block0,
+                cipher))
+        chunk, padded_rows = _scan_rows(rows, _SCAN_CHUNK)
+
+        def body(acc, i):
+            fold = _chacha_block_fold(masking, f, round_key,
+                                      pid_base + i * chunk, chunk, d_loc,
+                                      d_block0)
+            with jax.named_scope("sda.mask.fold"):
+                return f.add(acc, fold), None
+
         with jax.named_scope("sda.mask.fold"):
             init = jnp.zeros((8, d_loc // 8), f.dtype)
         acc, _ = jax.lax.scan(
@@ -393,9 +452,8 @@ def _share_sum_stage(scheme, f: FieldOps, M_host, masked_sum, rows: int,
 def _pallas_supported(scheme, masking, f: FieldOps) -> bool:
     """The fused kernel serves packed-Shamir over a Solinas prime with any
     masking in the lattice. None/Full draw inside the kernel; ChaCha masks
-    are expanded from the CHACHA_PRG_V1 stream in an XLA pass FIRST, a
-    block of rows at a time, and the kernel runs mask-free on the masked
-    fold — see _pallas_stage. Pod-internal masks are generated AND
+    are expanded from the CHACHA_PRG_V1 stream FIRST (``_chacha_mask_sum``)
+    and the kernel runs mask-free on the masked fold — see _pallas_stage. Pod-internal masks are generated AND
     cancelled inside the round (never wire-visible), so this choice is
     independent of the scheme's ``prg`` tag — any prg-tagged ChaChaMasking
     is accepted and the aggregate is exact either way."""
@@ -423,7 +481,7 @@ def _resolve_pallas(scheme, masking, f: FieldOps, use_pallas: bool,
 def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
                   round_key=None, pid_base=0, d_block0=0,
                   interpret: bool = False, external_bits_fn=None,
-                  reported=None):
+                  reported=None, cipher: str = "xla"):
     """[S, d_loc] canonical residues -> (combined shares [n, B0],
     mask sum [d_loc] | None) on the fused Pallas kernel.
 
@@ -446,8 +504,10 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
 
     ChaCha masking: the mask is the CHACHA_PRG_V1 stream, a function of
     (round key, global participant id, dim offset). The masks' sum is made
-    ``_SCAN_CHUNK`` rows at a time (``_chacha_mask_sum``: the XLA step's
-    expansion, under a running sum) and added to the fold, and the kernel
+    by ``_chacha_mask_sum`` with the ``cipher`` the step was built for (on
+    a TPU one call of the on-core cipher for all S rows; elsewhere the XLA
+    step's expansion, ``_SCAN_CHUNK`` rows at a time under a running sum)
+    and added to the fold, and the kernel
     then runs mask-free on that masked fold: no [S, d_loc] array of draws,
     masks or masked inputs exists. ``round_key``/``pid_base``/
     ``d_block0`` locate this tile in the global stream exactly like the
@@ -476,7 +536,7 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
         # sum_p (x_p + m_p) = sum_p x_p + sum_p m_p mod p, bit for bit: the
         # masks never meet the [S, d_loc] input, only its fold
         chacha_mask_sum = _chacha_mask_sum(
-            masking, f, round_key, pid_base, S, d_loc, d_block0)
+            masking, f, round_key, pid_base, S, d_loc, d_block0, cipher)
         with jax.named_scope("sda.mask"), jax.named_scope("sda.mask.fold"):
             x_sum = f.add(x_sum, chacha_mask_sum)
     # sda.relayout: the XLA passes that put the folded secrets into the
@@ -518,7 +578,7 @@ def _scan_rows(rows: int, chunk: int) -> Tuple[int, int]:
 
 
 def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
-                  pid0, dblk0, chunk: int, reported=None):
+                  pid0, dblk0, chunk: int, reported=None, cipher: str = "xla"):
     """[P, d] canonical residues -> (acc_shares [n, B], acc_mask [d]|None).
 
     Streams participants through ``lax.scan`` in blocks of ``chunk``: the
@@ -559,7 +619,7 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
             pid_base = pid0 + i * chunk
         masked_sum, mask_sum, skey = _mask_stage(
             masking, f, blk, bkey, round_key,
-            pid_base=pid_base, d_block0=dblk0,
+            pid_base=pid_base, d_block0=dblk0, cipher=cipher,
         )
         # an accumulator's add stands under the stage whose result it adds
         shares = _share_sum_stage(scheme, f, M_host, masked_sum, chunk, skey)
@@ -604,9 +664,11 @@ def _reconstruct_stage(scheme, f: FieldOps, L_host, gathered, d_loc: int,
 def _chacha_blocks(masking, pallas_active: bool, rows: int, chunk: int,
                    d_total: int, p_shards: int) -> int:
     """ChaCha20 blocks one round asks of the mesh (8 u64 draws a block), 0
-    under any other masking. ``rows`` per p shard; both steps expand whole
-    blocks of rows: the XLA step its scan's (``_scan_combine`` pads the rows
-    to ``chunk``), the Pallas step ``_chacha_mask_sum``'s."""
+    under any other masking. ``rows`` per p shard, rounded up to whole
+    blocks of rows: the XLA step's scan's (``_scan_combine`` pads the rows
+    to ``chunk``), on the Pallas step ``_SCAN_CHUNK`` rows, the blocks of
+    ``_chacha_mask_sum``'s XLA expansion (its one kernel call expands the
+    rows as they are)."""
     if not isinstance(masking, ChaChaMasking):
         return 0
     rows = _scan_rows(rows, _SCAN_CHUNK if pallas_active else chunk)[1]
@@ -712,9 +774,11 @@ class SimulatedPod:
     ``pallas_active`` says which step this pod took.
 
     Under ChaCha masking every dispatch of the round counts
-    ``mesh.mask.chacha_calls`` and ``mesh.mask.chacha_blocks`` (the
-    ChaCha20 blocks asked of the mesh, from static shapes); a pod with
-    any other masking counts nothing.
+    ``mesh.mask.chacha_calls``, ``mesh.mask.chacha_blocks`` (the ChaCha20
+    blocks asked of the mesh, from static shapes) and
+    ``mesh.mask.chacha_kernel_blocks`` (those of them the on-core cipher
+    is asked for: all of them on a TPU mesh over a uint32 field, else 0);
+    a pod with any other masking counts nothing.
     """
 
     def __init__(
@@ -760,6 +824,7 @@ class SimulatedPod:
         self.pallas_active = _resolve_pallas(
             sharing_scheme, self.masking, self._field, use_pallas, "local"
         )
+        self._cipher = _chacha_cipher(self._field, mesh.devices)
         self._step = None
         self._step_shape = None
         self._programs = {}  # round_program: what callers built, by their key
@@ -796,6 +861,7 @@ class SimulatedPod:
                 round_key=key, pid_base=pid0, d_block0=dblk0,
                 interpret=self._pallas_interpret,
                 external_bits_fn=self._pallas_bits_fn, reported=reported,
+                cipher=self._cipher,
             )                                                      # [n, B_loc]
         else:
             # participant parallelism -> local scan-chunked reduction (share
@@ -803,7 +869,7 @@ class SimulatedPod:
             local_sum, local_mask_sum = _scan_combine(
                 f, self.scheme, self.masking, self._M_host, x, dev_key, key,
                 pid0=pid0, dblk0=dblk0, chunk=self.scan_chunk,
-                reported=reported,
+                reported=reported, cipher=self._cipher,
             )                                                      # [n, B_loc]
 
         # snapshot transpose + clerk combine == one psum_scatter over ICI:
@@ -864,8 +930,11 @@ class SimulatedPod:
         blocks = _chacha_blocks(self.masking, self.pallas_active,
                                 P_total // p_shards, self.scan_chunk,
                                 d_total, p_shards)
+        on_core = self._cipher != "xla"
         counts = {"mesh.mask.chacha_calls": 1,
-                  "mesh.mask.chacha_blocks": blocks} if blocks else None
+                  "mesh.mask.chacha_blocks": blocks,
+                  "mesh.mask.chacha_kernel_blocks": blocks if on_core else 0,
+                  } if blocks else None
         return devprof.instrument(name, jax.jit(fn), span="pod.dispatch",
                                   counts=counts)
 
@@ -1020,13 +1089,16 @@ def single_chip_round(
     _check_mask_modulus(masking, scheme)
     M_host, L_host = _build_matrices(scheme)
     f = FieldOps.create(_scheme_modulus(scheme))
+    # the round is jitted by the caller and runs on the default device
+    cipher = _chacha_cipher(f, jax.devices()[:1])
     # tile grain: whole packing columns (input_size) and whole ChaCha
     # blocks (8 u64 draws) — same grain as the streaming driver
     grain = scheme.input_size * 8 // math.gcd(scheme.input_size, 8)
 
     def one_tile(x, bkey, round_key, d_block0, d_loc):
         masked_sum, mask_total, skey = _mask_stage(
-            masking, f, x, bkey, round_key, pid_base=0, d_block0=d_block0
+            masking, f, x, bkey, round_key, pid_base=0, d_block0=d_block0,
+            cipher=cipher,
         )
         # share + clerk combine fused via linearity (see _share_sum_stage)
         combined = _share_sum_stage(

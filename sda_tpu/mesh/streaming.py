@@ -50,6 +50,7 @@ from ..protocol import (
 )
 from ..utils import metrics, timed_phase
 from .simpod import (
+    _chacha_cipher,
     _check_collective_headroom,
     _check_mask_modulus,
     _check_masking_supported,
@@ -535,6 +536,8 @@ class StreamingAggregator:
         self.pallas_active = _resolve_pallas(
             s, self.masking, self._field, use_pallas, "streamed"
         )
+        # the steps are jitted for the default device
+        self._cipher = _chacha_cipher(self._field, jax.devices()[:1])
         self._pallas_interpret = bool(pallas_interpret)
         self._pallas_bits_fn = pallas_external_bits_fn
         self._steps = {}      # block shape -> jitted accumulate step
@@ -554,6 +557,7 @@ class StreamingAggregator:
                     round_key=round_key, pid_base=pid0, d_block0=dblk0,
                     interpret=self._pallas_interpret,
                     external_bits_fn=self._pallas_bits_fn,
+                    cipher=self._cipher,
                 )
             else:
                 # pid0/dblk0 (traced) locate this tile in the global stream
@@ -561,7 +565,7 @@ class StreamingAggregator:
                 # participant's stream regardless of tiling
                 masked_sum, mask_sum, skey = _mask_stage(
                     self.masking, f, x, key, round_key,
-                    pid_base=pid0, d_block0=dblk0,
+                    pid_base=pid0, d_block0=dblk0, cipher=self._cipher,
                 )
                 # share + participant-combine fused via linearity
                 # (simpod._share_sum_stage): no [S, n, B] tensor in HBM
@@ -736,6 +740,7 @@ class StreamedPod:
         self.pallas_active = _resolve_pallas(
             s, self.masking, self._field, use_pallas, "streamed"
         )
+        self._cipher = _chacha_cipher(self._field, mesh.devices)
         self._pallas_interpret = bool(pallas_interpret)
         self._pallas_bits_fn = pallas_external_bits_fn
         self._steps = {}      # local block shape -> jitted accumulate step
@@ -781,11 +786,12 @@ class StreamedPod:
                     round_key=round_key, pid_base=pid0, d_block0=dblk0,
                     interpret=self._pallas_interpret,
                     external_bits_fn=self._pallas_bits_fn,
+                    cipher=self._cipher,
                 )
             else:
                 masked_sum, local_mask_sum, skey = _mask_stage(
                     masking, f, x, dev_key, round_key,
-                    pid_base=pid0, d_block0=dblk0,
+                    pid_base=pid0, d_block0=dblk0, cipher=self._cipher,
                 )
                 shares = _share_sum_stage(
                     s, f, self._M_host, masked_sum, x.shape[0], skey)

@@ -57,8 +57,8 @@ def one_chip(v5e_host):
 ROWS, DIM = 8, 1_000_000  # one scan block of the cell ``additive-chacha-1m``
 
 
-def _compile_for(one_chip, stage, *args):
-    """``stage`` compiled for the described chip on ``(shape, dtype)`` args."""
+def _compiled(lowered):
+    """A program lowered for the described chip, compiled."""
     from jax.experimental.compilation_cache import compilation_cache
 
     # a compile for a described chip is written to the persistent cache but
@@ -67,18 +67,24 @@ def _compile_for(one_chip, stage, *args):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        return jax.jit(stage).lower(*(
-            jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-            for shape, dtype in args)).compile()
+        return lowered.compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
 
 
+def _compile_for(one_chip, stage, *args):
+    """``stage`` compiled for the described chip on ``(shape, dtype)`` args."""
+    return _compiled(jax.jit(stage).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in args)))
+
+
 @pytest.fixture(scope="module")
 def mask_stage_compiled(one_chip):
     """``_mask_stage``'s ChaCha branch on one scan block: 8 rows x
-    1,000,000, a traced block counter."""
+    1,000,000, a traced block counter, with the XLA block function (the
+    stage's default; a step built for a TPU takes the on-core cipher)."""
     field = FieldOps.create(MODULUS)
 
     def stage(x, key, round_key, pid_base, block0):
@@ -544,9 +550,9 @@ LOWERED_SHA256 = {
     "packed-1m": "ed573eb07a503a1ff82193b857b876960605f656dabf6ad9dd0dd6c09740254d",
     "packed-1m-hostfed": "5fa51410850772063bf34f48ee6a9bf57248a5bd835ea4bfab3cc0ab82478de1",
     "packed-1m-mesh4": "e322816bc5795a67b75c3e476e17b1e39af921343bc2534ec383abdba15e91e4",
-    "additive-chacha-1m": "320be16eb5dfbcb55127e0a4a2bf9d06d99aa66aee219544ce934164e94b97df",
+    "additive-chacha-1m": "24ed050c6907127191dc8832892c9c3cfad256e95a6420ae80d01612771c8909",
     "packed-1m-streamed": "3cc029c33dabc5f695b23528a9a2b1e515ff9ac7d238f2015d8110faab595bda",
-    "packed-chacha-1m": "9bf45fc82c2963449c18d43f9b4ce558e055bdf19ef4fa653a29b8e29632d6d3",
+    "packed-chacha-1m": "e046b5d8b816cb7ccc775ee9b95c94e9a01f8879631263d45661c7dbc5333b1d",
     "fedavg-f32-1m": "dbf5d9b29710a4446e3133f7902de96be8e36b67067b830c7b70b1792c83487d",
 }
 
@@ -588,6 +594,100 @@ def _without_mosaic_locations(text: str) -> str:
         return "backend_config = " + json.dumps(config, sort_keys=True)
 
     return re.sub(r'backend_config = "((?:[^"\\]|\\.)*)"', replace, text)
+
+
+# -- the ChaCha cells' masks from ONE kernel: where the round is built for a TPU
+# over a uint32 field, ``fields/chacha_kernel.py`` holds the cipher's sixteen
+# words on-core and folds a block's reduced draws in VMEM. The XLA block
+# function runs as some thirty fusions of ``u32[8,1,N]`` word planes a scan
+# block there, and the compiler stacks and copies the words besides.
+
+CHACHA_CELLS = {"additive-chacha-1m": 125_000, "packed-chacha-1m": 125_001}
+
+
+@pytest.fixture(scope="module")
+def chacha_cells_compiled(v5e_host):
+    return {name: _compiled(lowered) for name in CHACHA_CELLS
+            for [lowered] in [_accepted_cell_lowered(name, v5e_host)]}
+
+
+def _ops_of(text: str):
+    """(name, dims, op_name) of every instruction of a compiled program."""
+    ops = []
+    for line in text.splitlines():
+        head = re.match(r"\s+(?:ROOT )?%([\w.-]+) = \w+\[([\d,]*)\]", line)
+        if head:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            ops.append((head.group(1), tuple(int(n) for n in head.group(2).split(",") if n),
+                        op_name.group(1) if op_name else ""))
+    return ops
+
+
+@pytest.mark.parametrize("name", sorted(CHACHA_CELLS))
+def test_a_chacha_cell_expands_its_masks_in_one_kernel_call(chacha_cells_compiled, name):
+    """ONE ``sda_chacha_mask_fold`` Mosaic call under ``sda.mask.chacha``
+    (once a scan block on the XLA step, once a round on the kernel path):
+    no word plane of the cipher, ``u32[8,1,N]``, and nothing under the
+    scope of a block's width but the kernel's output and its re-tile."""
+    from sda_tpu.fields import chacha_kernel
+
+    blocks = CHACHA_CELLS[name]
+    ops = _ops_of(chacha_cells_compiled[name].as_text())
+    kernels = [(n, op_name) for n, _, op_name in ops if n.startswith("sda_chacha_mask_fold")]
+    assert len(kernels) == 1 and "/sda.mask.chacha/" in kernels[0][1], kernels
+    assert not [n for n, dims, _ in ops if dims == (8, 1, blocks)]
+    wide = {dims for _, dims, op_name in ops
+            if "/sda.mask.chacha/" in op_name and math.prod(dims) >= blocks}
+    rows = -(-blocks // chacha_kernel._VECTOR) * chacha_kernel._SUB  # 41 vectors
+    assert wide == {(8, rows, 128), (8, rows * 128)}, wide
+
+
+def test_the_cipher_kernel_lowers_to_a_loop_whatever_its_rows(one_chip):
+    """The body traces to a few hundred equations and lowers to one Mosaic
+    module with two loops (the rows, the ten double rounds; the eight
+    draws' reduction is one traced body, lowered eight times), the same at
+    8 rows and at 1200: a body that unrolls the
+    twenty rounds or the draws in Python is many times that, traced and
+    lowered on every warm start, and fails here and not in ``setup_s``."""
+    from jax._src import core
+
+    from sda_tpu.fields import chacha_kernel
+
+    field = FieldOps.create(MODULUS)
+
+    def equations(jaxpr):
+        return sum(1 + sum(equations(sub) for sub in core.jaxprs_in_params(eqn.params))
+                   for eqn in jaxpr.eqns)
+
+    sizes = set()
+    for rows, blocks in ((ROWS, 125_000), (1200, 125_001)):
+        def kernel(seeds, block0):
+            return chacha_kernel.mask_fold(seeds, block0, nblocks=blocks, sp=field.sp)
+
+        args = (((rows, 8), jnp.uint32), ((), jnp.int32))
+        traced = equations(jax.make_jaxpr(kernel)(
+            *(jnp.zeros(shape, dtype) for shape, dtype in args)).jaxpr)
+        [module] = _mosaic_modules(_compile_for(one_chip, kernel, *args).as_text())
+        sizes.add((traced, len(module.splitlines()), module.count("scf.for")))
+    [(traced, lines, loops)] = sizes
+    assert traced < 500 and lines < 2500 and loops == 2, sizes
+
+
+def _accepted_cell_lowered(name, devices):
+    """The lowered round(s) of the accepted cell ``name`` of BENCHMARK.json
+    on the described ``devices`` it asks for."""
+    import sys
+    from pathlib import Path
+
+    home = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    sys.path.insert(0, str(home))
+    try:
+        import harness
+
+        cell = harness.load_cell(harness.ROOT, name)
+        return _cell_lowered(cell, devices[:cell.chips])
+    finally:
+        sys.path.remove(str(home))
 
 
 def _cell_lowered(cell, devices):
@@ -642,19 +742,9 @@ def _cell_lowered(cell, devices):
 @pytest.mark.parametrize("name", sorted(LOWERED_SHA256))
 def test_an_accepted_cells_round_lowers_to_the_text_it_had(v5e_host, name):
     import hashlib
-    import sys
-    from pathlib import Path
 
-    home = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
-    sys.path.insert(0, str(home))
-    try:
-        import harness
-
-        cell = harness.load_cell(harness.ROOT, name)
-        texts = [_without_mosaic_locations(lowered.as_text())
-                 for lowered in _cell_lowered(cell, v5e_host[:cell.chips])]
-    finally:
-        sys.path.remove(str(home))
+    texts = [_without_mosaic_locations(lowered.as_text())
+             for lowered in _accepted_cell_lowered(name, v5e_host)]
     assert "body\\22" not in "".join(texts)   # every kernel's module was read
     digest = hashlib.sha256("\n".join(texts).encode()).hexdigest()
     assert digest == LOWERED_SHA256[name], (name, digest)
