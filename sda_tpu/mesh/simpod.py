@@ -365,10 +365,10 @@ def _chacha_mask_sum(masking, f: FieldOps, round_key, pid_base, rows: int,
     sum: what is live is one block's draws, whatever ``rows`` (the whole
     [rows, d_loc] block at once is 22 MB of temporaries a row at a million
     elements, and 1200 rows do not compile for a v5e: PERF.md).
-    There, rows that do not fill the last block are expanded too, as
-    ``_scan_combine`` expands its zero rows: the sum is added to the fold
-    of the inputs and subtracted from the reveal, so every mask in it
-    cancels."""
+    There, rows that do not fill the last block are expanded too: the
+    sum is added to the fold of the inputs and subtracted from the reveal,
+    so every mask in it cancels. Both steps take the masks' sum from here,
+    once a round."""
     with jax.named_scope("sda.mask"):
         if cipher != "xla":
             return _element_order(_chacha_mask_fold(
@@ -390,6 +390,59 @@ def _chacha_mask_sum(masking, f: FieldOps, round_key, pid_base, rows: int,
         return _element_order(acc)
 
 
+def _share_draws(scheme, f: FieldOps, rows: int, d: int, skey):
+    """The share randomness of ``rows`` participants over ``d`` elements,
+    folded over the participants: ``[t, B]`` for a Shamir scheme, the
+    ``[n - 1, d]`` free rows for the additive one (the n-th row of a
+    participant is its secret less the others).
+
+    ``f.uniform`` rows, every element reduced from the 64 bits of one
+    threefry block of its own. The ``[rows, ...]`` draws never reach HBM:
+    they have ONE consumer, the fold over participants, and the draw, its
+    reduction and the fold compile to one fusion."""
+    with jax.named_scope("sda.share"):
+        return f.sum(f.uniform(skey, (rows,) + _drawn_shape(scheme, d)), axis=0)
+
+
+def _drawn_shape(scheme, d: int) -> Tuple[int, int]:
+    """The shape of one participant's share randomness over ``d``
+    elements (``_share_draws``)."""
+    if isinstance(scheme, SHAMIR_SCHEMES):
+        return scheme.privacy_threshold, -(-d // scheme.secret_count)
+    return scheme.share_count - 1, d
+
+
+def _share_combine(scheme, f: FieldOps, M_host, masked_sum, drawn):
+    """[d_loc] fold of the participants' masked residues and the fold of
+    their share randomness (``_share_draws``) -> [n, B] participant-SUMMED
+    share rows."""
+    d = masked_sum.shape[0]
+    with jax.named_scope("sda.share"):
+        if isinstance(scheme, SHAMIR_SCHEMES):
+            k = scheme.secret_count
+            sk = sharing.batch_columns(masked_sum, k)              # [k, B]
+            zeros = jnp.zeros((1, -(-d // k)), sk.dtype)
+            values = jnp.concatenate([zeros, sk, drawn], axis=0)   # [m2, B]
+            if f.sp is not None:
+                return fastfield.modmatmul32(M_host, values, f.sp)
+            from ..fields import modular
+
+            return modular.modmatmul(jnp.asarray(M_host), values, f.m)
+        # additive: Σ_p last_p = Σ_p masked_p - Σ over all draws. The
+        # folded rows come off one by one, n - 1 subtractions: the
+        # compiler turns f.sum(drawn, axis=0) into a second reduce over the
+        # draws themselves, and a draw with two consumers is written to HBM
+        # and read twice (or made twice) instead of fusing into its fold.
+        # They come off as rows, [1, d] less drawn[i:i+1]: a flat [d] vector
+        # and a row of [n - 1, d] tile differently on the TPU, and cutting
+        # the rows into flat vectors is a pass over them that changes
+        # nothing but the layout
+        last = masked_sum[None, :]                                 # [1, d]
+        for i in range(scheme.share_count - 1):
+            last = f.sub(last, drawn[i:i + 1])
+        return jnp.concatenate([drawn, last], axis=0)
+
+
 def _share_sum_stage(scheme, f: FieldOps, M_host, masked_sum, rows: int,
                      skey):
     """[d_loc] fold of ``rows`` participants' masked residues (what
@@ -406,47 +459,11 @@ def _share_sum_stage(scheme, f: FieldOps, M_host, masked_sum, rows: int,
     federated client path): the same randomness shapes are drawn from the
     same key and mod-m arithmetic is exact, so fold order is free —
     tests/test_mesh.py and test_fast_rounds.py pin this equivalence.
-
-    What is drawn: ``f.uniform`` rows, every element reduced from the 64
-    bits of one threefry block of its own — ``[S, t, B]`` for a Shamir
-    scheme, ``[S, n - 1, d]`` free rows for the additive one (the n-th row
-    of a participant is its secret less the others). Neither tensor
-    reaches HBM: each has ONE consumer, the fold over participants, and
-    the draw, its reduction and the fold compile to one fusion. That is
-    why the additive branch subtracts the folded ``dsum`` rows one by one
-    and asks for no total of the draws (tests/test_tpu_compile.py).
+    The draws' fold has one consumer, so the additive branch asks for no
+    total of the draws (``_share_combine``; tests/test_tpu_compile.py).
     """
-    d = masked_sum.shape[0]
-    with jax.named_scope("sda.share"):
-        if isinstance(scheme, SHAMIR_SCHEMES):
-            k, t = scheme.secret_count, scheme.privacy_threshold
-            B = -(-d // k)
-            rand = f.uniform(skey, (rows, t, B))
-            rsum = f.sum(rand, axis=0)                             # [t, B]
-            sk = sharing.batch_columns(masked_sum, k)              # [k, B]
-            zeros = jnp.zeros((1, B), sk.dtype)
-            values = jnp.concatenate([zeros, sk, rsum], axis=0)    # [m2, B]
-            if f.sp is not None:
-                return fastfield.modmatmul32(M_host, values, f.sp)
-            from ..fields import modular
-
-            return modular.modmatmul(jnp.asarray(M_host), values, f.m)
-        # additive: Σ_p last_p = Σ_p masked_p - Σ over all draws
-        n = scheme.share_count
-        draws = f.uniform(skey, (rows, n - 1, d))
-        dsum = f.sum(draws, axis=0)                                # [n-1, d]
-        # the folded rows come off one by one, n - 1 subtractions: the
-        # compiler turns f.sum(dsum, axis=0) into a second reduce over the
-        # draws themselves, and a draw with two consumers is written to HBM
-        # and read twice (or made twice) instead of fusing into its fold.
-        # They come off as rows, [1, d] less dsum[i:i+1]: a flat [d] vector
-        # and a row of [n - 1, d] tile differently on the TPU, and cutting
-        # dsum into flat vectors is a pass over it that changes nothing but
-        # the layout
-        last = masked_sum[None, :]                                 # [1, d]
-        for i in range(n - 1):
-            last = f.sub(last, dsum[i:i + 1])
-        return jnp.concatenate([dsum, last], axis=0)
+    drawn = _share_draws(scheme, f, rows, masked_sum.shape[0], skey)
+    return _share_combine(scheme, f, M_host, masked_sum, drawn)
 
 
 def _pallas_supported(scheme, masking, f: FieldOps) -> bool:
@@ -581,64 +598,63 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
                   pid0, dblk0, chunk: int, reported=None, cipher: str = "xla"):
     """[P, d] canonical residues -> (acc_shares [n, B], acc_mask [d]|None).
 
-    Streams participants through ``lax.scan`` in blocks of ``chunk``: the
-    live share tensor is [chunk, n, B] instead of [P, n, B], so the XLA
-    path stops round-tripping the full share tensor through HBM (the
-    round-1 single-chip bottleneck; ~2x even on CPU from cache locality).
-    Zero-padded rows aggregate as zero and their masks cancel.
+    All that is linear in the rows happens once a round, outside the
+    participant scan: the rows fold in ONE read of the input
+    (``sda.fold``; ``reported`` [P] bool, traced, reads the rows that did
+    not report as zero inside it), the ChaCha masks' sum is made by
+    ``_chacha_mask_sum`` (keyed by round key and participant id alone; on
+    a TPU one call of the on-core cipher and ONE ``_element_order``), and
+    the shares are made once from the masked fold and the folded
+    randomness (``_share_combine``: share generation is linear).
 
-    ``reported`` ([P] bool, traced): cut into the scan's blocks beside the
-    rows (the padding rows did not report), and a block's rows that did
-    not report are read as zero where the block is folded.
+    The scan carries only what is drawn by block, from the block's key
+    ``fold_in(key, i)``: the share randomness of ``chunk`` rows
+    (``_share_draws``) and, under full masking, their masks (``split`` of
+    that key), each folded into its running sum. What is live is one
+    block's draws, [chunk, ...] instead of [P, ...]. The draws of the
+    rows that pad the last block, or did not report, only cancel.
     """
     P, d = x.shape
     chunk, padded_rows = _scan_rows(P, chunk)
-    pad = padded_rows - P
-    # sda.blocks: the cohort cut into the scan's blocks -- the zero rows,
-    # the reshape, the block counter and a block's place in the cohort
-    with jax.named_scope("sda.blocks"):
-        if pad:
-            x = jnp.concatenate([x, jnp.zeros((pad, d), x.dtype)], axis=0)
-        nblk = x.shape[0] // chunk
-        xb = x.reshape(nblk, chunk, d)
-        rb = None  # a block's entries of ``reported``, beside its rows
-        if reported is not None:
-            rb = jnp.pad(reported, (0, pad)).reshape(nblk, chunk)
-    n = scheme.output_size
-    B = d // scheme.input_size
-    has_mask = not isinstance(masking, NoMasking)
+    full = isinstance(masking, FullMasking)
+    with jax.named_scope("sda.fold"):
+        x_sum = f.sum(_reported_rows(x, reported), axis=0)
+    mask_sum = None
+    if isinstance(masking, ChaChaMasking):
+        mask_sum = _chacha_mask_sum(masking, f, round_key, pid0, P, d, dblk0,
+                                    cipher)
 
-    def body(carry, blk_i):
-        acc_s, acc_m = carry
-        blk, blk_reported, i = blk_i
-        with jax.named_scope("sda.fold"):
-            blk = _reported_rows(blk, blk_reported)
+    def body(carry, i):
+        drawn, acc_m = carry
         with jax.named_scope("sda.share"):
-            bkey = jax.random.fold_in(key, i)
-        with jax.named_scope("sda.blocks"):
-            pid_base = pid0 + i * chunk
-        masked_sum, mask_sum, skey = _mask_stage(
-            masking, f, blk, bkey, round_key,
-            pid_base=pid_base, d_block0=dblk0, cipher=cipher,
-        )
+            skey = jax.random.fold_in(key, i)
+        if full:
+            with jax.named_scope("sda.mask"):
+                mkey, skey = jax.random.split(skey)
+                masks = f.uniform(mkey, (chunk, d))
+                with jax.named_scope("sda.mask.fold"):
+                    acc_m = f.add(acc_m, f.sum(masks, axis=0))
         # an accumulator's add stands under the stage whose result it adds
-        shares = _share_sum_stage(scheme, f, M_host, masked_sum, chunk, skey)
+        block = _share_draws(scheme, f, chunk, d, skey)
         with jax.named_scope("sda.share"):
-            acc_s = f.add(acc_s, shares)
-        if mask_sum is not None:
-            with jax.named_scope("sda.mask"), jax.named_scope("sda.mask.fold"):
-                acc_m = f.add(acc_m, mask_sum)
-        return (acc_s, acc_m), None
+            return (f.add(drawn, block), acc_m), None
 
     with jax.named_scope("sda.share"):
-        init_s = jnp.zeros((n, B), f.dtype)
-    with jax.named_scope("sda.mask"), jax.named_scope("sda.mask.fold"):
-        init_m = jnp.zeros((d,), f.dtype)
+        init_d = jnp.zeros(_drawn_shape(scheme, d), f.dtype)
+    init_m = None
+    if full:
+        with jax.named_scope("sda.mask"), jax.named_scope("sda.mask.fold"):
+            init_m = jnp.zeros((d,), f.dtype)
+    # sda.blocks: the scan's block counter
     with jax.named_scope("sda.blocks"):
-        counter = jnp.arange(nblk, dtype=jnp.int32)
-    (acc_s, acc_m), _ = jax.lax.scan(body, (init_s, init_m),
-                                     (xb, rb, counter))
-    return acc_s, (acc_m if has_mask else None)
+        counter = jnp.arange(padded_rows // chunk, dtype=jnp.int32)
+    (drawn, acc_m), _ = jax.lax.scan(body, (init_d, init_m), counter)
+    if full:
+        mask_sum = acc_m
+    if mask_sum is not None:
+        with jax.named_scope("sda.mask"), jax.named_scope("sda.mask.fold"):
+            x_sum = f.add(x_sum, mask_sum)
+    return _share_combine(scheme, f, M_host, x_sum, drawn), mask_sum
 
 
 def _reconstruct_stage(scheme, f: FieldOps, L_host, gathered, d_loc: int,
@@ -661,18 +677,15 @@ def _reconstruct_stage(scheme, f: FieldOps, L_host, gathered, d_loc: int,
         return f.sum(gathered, axis=0)  # additive: plain share sum
 
 
-def _chacha_blocks(masking, pallas_active: bool, rows: int, chunk: int,
-                   d_total: int, p_shards: int) -> int:
+def _chacha_blocks(masking, rows: int, d_total: int, p_shards: int) -> int:
     """ChaCha20 blocks one round asks of the mesh (8 u64 draws a block), 0
-    under any other masking. ``rows`` per p shard, rounded up to whole
-    blocks of rows: the XLA step's scan's (``_scan_combine`` pads the rows
-    to ``chunk``), on the Pallas step ``_SCAN_CHUNK`` rows, the blocks of
-    ``_chacha_mask_sum``'s XLA expansion (its one kernel call expands the
-    rows as they are)."""
+    under any other masking. Both steps make the masks' sum with
+    ``_chacha_mask_sum``: ``rows`` per p shard, rounded up to the whole
+    blocks of ``_SCAN_CHUNK`` rows of its XLA expansion (its one kernel
+    call expands the rows as they are)."""
     if not isinstance(masking, ChaChaMasking):
         return 0
-    rows = _scan_rows(rows, _SCAN_CHUNK if pallas_active else chunk)[1]
-    return p_shards * rows * (d_total // 8)
+    return p_shards * _scan_rows(rows, _SCAN_CHUNK)[1] * (d_total // 8)
 
 
 def _dim_grain(scheme, masking) -> int:
@@ -765,12 +778,13 @@ class SimulatedPod:
 
     Two local steps compute the same round. The **XLA step** is the
     default (``use_pallas=False``) and serves every scheme and masking:
-    ``_scan_combine`` streams the rows in blocks of ``scan_chunk`` through
-    ``_mask_stage`` and ``_share_sum_stage``. The **fused Pallas kernel**
-    (``use_pallas=True``) serves packed and basic Shamir over a Solinas
-    prime with none/full/ChaCha masking (``_pallas_supported``); asked for
-    on additive sharing or a non-Solinas modulus it raises, so an
-    additive-sharing aggregation always runs the XLA step.
+    ``_scan_combine`` folds the rows once and draws the share randomness
+    (and full masks) in a scan over blocks of ``scan_chunk`` rows. The
+    **fused Pallas kernel** (``use_pallas=True``) serves packed and basic
+    Shamir over a Solinas prime with none/full/ChaCha masking
+    (``_pallas_supported``); asked for on additive sharing or a
+    non-Solinas modulus it raises, so an additive-sharing aggregation
+    always runs the XLA step.
     ``pallas_active`` says which step this pod took.
 
     Under ChaCha masking every dispatch of the round counts
@@ -927,9 +941,8 @@ class SimulatedPod:
         # holder of the callable (aggregate(), aggregate_fn() callers,
         # multihost) gets the pod.dispatch span around its calls, and under
         # ChaCha masking the mask counters: static amounts, settled here
-        blocks = _chacha_blocks(self.masking, self.pallas_active,
-                                P_total // p_shards, self.scan_chunk,
-                                d_total, p_shards)
+        blocks = _chacha_blocks(self.masking, P_total // p_shards, d_total,
+                                p_shards)
         on_core = self._cipher != "xla"
         counts = {"mesh.mask.chacha_calls": 1,
                   "mesh.mask.chacha_blocks": blocks,

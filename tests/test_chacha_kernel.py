@@ -196,7 +196,7 @@ def test_a_pod_aggregates_the_plain_sum_with_either_cipher_and_counts_the_kernel
 def test_the_lowered_round_holds_the_kernel_only_where_the_platform_takes_it(request):
     """A pod built for the CPU lowers the XLA cipher and no kernel; where
     steps built for the CPU take the kernel, one ``sda_chacha_mask_fold`` a
-    scan block under ``sda.mask.chacha`` and no cipher of the XLA block
+    round under ``sda.mask.chacha`` and no cipher of the XLA block
     function."""
     def lowered_text():
         return _pod("additive", 96).aggregate_fn(16, 96).lower(

@@ -234,8 +234,8 @@ def test_the_kernel_path_counts_whole_blocks_of_rows():
     report = metrics.counter_report("mesh.mask.")
     assert report["mesh.mask.chacha_calls"] - before[0] == 1
     assert report["mesh.mask.chacha_blocks"] - before[1] == 16 * 96 // 8
-    assert simpod._chacha_blocks(pod.masking, True, 1200, 8, 1_000_008, 1) == 1200 * 125_001
-    assert simpod._chacha_blocks(pod.masking, True, 5, 64, 96, 2) == 2 * 5 * 12
+    assert simpod._chacha_blocks(pod.masking, 1200, 1_000_008, 1) == 1200 * 125_001
+    assert simpod._chacha_blocks(pod.masking, 5, 96, 2) == 2 * 5 * 12
 
 
 # -- (e) the streamed driver -----------------------------------------------------------

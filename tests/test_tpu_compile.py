@@ -431,8 +431,8 @@ def test_packed_round_keeps_its_temporaries_out_of_hbm(packed_full_round):
 
 # -- the same, in the rounds as they are lowered (no compiler, any backend): the
 # relayout's matmuls take ONE row -- [8 pairs, tiles, 128 lanes] in bfloat16,
-# no leading axis of a block's rows -- inside the XLA step's scan, a block at
-# a time, and on the kernel path after the scan, once a round.
+# no leading axis of a block's rows -- once a round, after the masks' scan, on
+# both steps (the XLA step's once took one row a scan block).
 
 def _relayout_matmuls(lowered):
     """(plane operand's dims, inside a scan's body?) of every
@@ -460,9 +460,10 @@ def test_a_chacha_round_puts_one_row_through_the_matrix_unit(path):
         jax.ShapeDtypeStruct((rows, dim), jnp.uint32),
         jax.ShapeDtypeStruct((2,), jnp.uint32))
     matmuls = _relayout_matmuls(lowered)
-    # a matmul a byte of the uint32 residues, each on one row's planes
+    # a matmul a byte of the uint32 residues, each on one row's planes,
+    # once a round after the masks' scan on both steps
     assert [dims for dims, _ in matmuls] == [(8, dim // 1024, 128)] * 4, matmuls
-    assert {in_while for _, in_while in matmuls} == {path == "xla-step"}
+    assert {in_while for _, in_while in matmuls} == {False}
 
 
 # -- the fused kernel's grid and draws (PR 37): with the on-core PRNG nothing
@@ -550,7 +551,7 @@ LOWERED_SHA256 = {
     "packed-1m": "ed573eb07a503a1ff82193b857b876960605f656dabf6ad9dd0dd6c09740254d",
     "packed-1m-hostfed": "5fa51410850772063bf34f48ee6a9bf57248a5bd835ea4bfab3cc0ab82478de1",
     "packed-1m-mesh4": "e322816bc5795a67b75c3e476e17b1e39af921343bc2534ec383abdba15e91e4",
-    "additive-chacha-1m": "24ed050c6907127191dc8832892c9c3cfad256e95a6420ae80d01612771c8909",
+    "additive-chacha-1m": "efb68d56a0095fd60f49d3bd7677d91e9bdd1ab2224b6441fae72e91f40ed098",
     "packed-1m-streamed": "3cc029c33dabc5f695b23528a9a2b1e515ff9ac7d238f2015d8110faab595bda",
     "packed-chacha-1m": "e046b5d8b816cb7ccc775ee9b95c94e9a01f8879631263d45661c7dbc5333b1d",
     "fedavg-f32-1m": "dbf5d9b29710a4446e3133f7902de96be8e36b67067b830c7b70b1792c83487d",
@@ -623,10 +624,20 @@ def _ops_of(text: str):
     return ops
 
 
+def _equations(jaxpr) -> int:
+    """Equations of a traced program, those of its nested jaxprs (a
+    scan's body, a kernel's) included: what every warm start traces."""
+    from jax._src import core
+
+    return sum(1 + sum(_equations(sub) for sub in core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
 @pytest.mark.parametrize("name", sorted(CHACHA_CELLS))
 def test_a_chacha_cell_expands_its_masks_in_one_kernel_call(chacha_cells_compiled, name):
     """ONE ``sda_chacha_mask_fold`` Mosaic call under ``sda.mask.chacha``
-    (once a scan block on the XLA step, once a round on the kernel path):
+    (once a round on both steps, where the XLA step's was once a scan
+    block):
     no word plane of the cipher, ``u32[8,1,N]``, and nothing under the
     scope of a block's width but the kernel's output and its re-tile."""
     from sda_tpu.fields import chacha_kernel
@@ -642,6 +653,53 @@ def test_a_chacha_cell_expands_its_masks_in_one_kernel_call(chacha_cells_compile
     assert wide == {(8, rows, 128), (8, rows * 128)}, wide
 
 
+def test_the_xla_steps_scan_carries_the_draws_alone(chacha_cells_compiled):
+    """``additive-chacha-1m``'s round: the cohort folds once in front of
+    the participant scan and the masks' sum is made once a round, as on
+    the kernel path. No copy of the 2.4 GB cohort into scan blocks
+    (``u32[75,8,1000000]`` in the block-by-block scan, 7.06 ms a round on
+    a v5e, and 2.4 GB of temporaries); the one kernel call and every op of the layout change
+    stand outside the ``while``, the matmuls on ONE row's planes; what the
+    loop runs is the share stage's draws and its counter."""
+    compiled = chacha_cells_compiled["additive-chacha-1m"]
+    ops = _ops_of(compiled.as_text())
+    assert not [n for n, dims, _ in ops if dims == (75, 8, 1_000_000)]
+    [kernel] = [op_name for n, _, op_name in ops if n.startswith("sda_chacha_mask_fold")]
+    assert "/while/" not in kernel, kernel
+    relayout = [(n, dims, op_name) for n, dims, op_name in ops
+                if "/sda.mask.relayout/" in op_name]
+    assert relayout and not [op for op in relayout if "/while/" in op[2]], relayout[:3]
+    planes = {dims for n, dims, op_name in relayout if "dot_general" in op_name}
+    assert planes and all(math.prod(dims) <= 8 * 977 * 1024 for dims in planes), planes
+    in_loop = {op_name.split("/while/body/")[1].split("/")[1]
+               for _, _, op_name in ops if "/while/body/closed_call/" in op_name}
+    assert in_loop == {"sda.share"}, in_loop
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+#: traced equations of ``additive-chacha-1m``'s round at its shape, built
+#: for a v5e (the on-core cipher), nested jaxprs included. The block-by-
+#: block scan traced 540: every warm start traces and lowers the round
+#: again, so a change of the XLA step may not make it larger.
+ADDITIVE_CHACHA_EQUATIONS = 531
+
+
+def test_the_xla_steps_trace_is_no_larger_than_before(one_chip):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh([[one_chip._device]], ("p", "d"))
+    pod = simpod.SimulatedPod(AdditiveSharing(3, MODULUS),
+                              ChaChaMasking(MODULUS, 999_999, 128), mesh=mesh)
+    assert pod._cipher == "kernel" and not pod.pallas_active
+    rows, dim = pod.padded_shape(600, 999_999)
+    traced = jax.make_jaxpr(pod.aggregate_fn(rows, dim))(
+        jax.ShapeDtypeStruct((rows, dim), jnp.uint32,
+                             sharding=NamedSharding(mesh, PartitionSpec("p", "d"))),
+        jax.ShapeDtypeStruct((2,), jnp.uint32,
+                             sharding=NamedSharding(mesh, PartitionSpec())))
+    assert _equations(traced.jaxpr) == ADDITIVE_CHACHA_EQUATIONS <= 540
+
+
 def test_the_cipher_kernel_lowers_to_a_loop_whatever_its_rows(one_chip):
     """The body traces to a few hundred equations and lowers to one Mosaic
     module with two loops (the rows, the ten double rounds; the eight
@@ -649,23 +707,16 @@ def test_the_cipher_kernel_lowers_to_a_loop_whatever_its_rows(one_chip):
     8 rows and at 1200: a body that unrolls the
     twenty rounds or the draws in Python is many times that, traced and
     lowered on every warm start, and fails here and not in ``setup_s``."""
-    from jax._src import core
-
     from sda_tpu.fields import chacha_kernel
 
     field = FieldOps.create(MODULUS)
-
-    def equations(jaxpr):
-        return sum(1 + sum(equations(sub) for sub in core.jaxprs_in_params(eqn.params))
-                   for eqn in jaxpr.eqns)
-
     sizes = set()
     for rows, blocks in ((ROWS, 125_000), (1200, 125_001)):
         def kernel(seeds, block0):
             return chacha_kernel.mask_fold(seeds, block0, nblocks=blocks, sp=field.sp)
 
         args = (((rows, 8), jnp.uint32), ((), jnp.int32))
-        traced = equations(jax.make_jaxpr(kernel)(
+        traced = _equations(jax.make_jaxpr(kernel)(
             *(jnp.zeros(shape, dtype) for shape, dtype in args)).jaxpr)
         [module] = _mosaic_modules(_compile_for(one_chip, kernel, *args).as_text())
         sizes.add((traced, len(module.splitlines()), module.count("scf.for")))
