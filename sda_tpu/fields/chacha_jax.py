@@ -176,9 +176,9 @@ def stream_u64_at(seed_words, counter0, *, dimension: int):
     rejection step — masks cancel within the round, so the aggregate is
     exact regardless; only the federated wire path needs rejection parity.
 
-    The element-order view of ``stream_u64_words_at``: the pod's mask stage
-    takes the word-major form, folds its residues over the participants
-    and orders the fold (simpod._mask_stage, _chacha_mask_sum).
+    The element-order view of ``stream_u64_words_at``: the round takes the
+    word-major form, folds its residues over the participants and orders
+    the fold (simpod._chacha_mask_sum).
     """
     if dimension % 8:
         raise ValueError("dimension must be a multiple of 8 (one ChaCha block)")

@@ -32,11 +32,12 @@ the chip folds) and is also what a protocol-grade deployment would use to
 inject threefry/ChaCha streams (reference mask PRGs:
 client/src/crypto/masking/*.rs).
 
-Callers: ``mesh.simpod._pallas_stage``, the one stage around the kernel
-(the pod, streamed and model-scale steps, with ``use_pallas=True``). This
-module exports the kernel and its column-tile rule and drives no round.
-The XLA steps stay the default of the library; the chip benchmark's packed
-cells all run this kernel.
+Callers: ``mesh.simpod._pallas_stage``, the one stage around the kernel,
+which ``simpod._RoundPlan`` makes the local step of every driver built
+with ``use_pallas=True``. This module exports the kernel and its
+column-tile rule and drives no round. The XLA step (``_scan_combine``)
+stays the default of the library; the chip benchmark's packed cells all
+run this kernel.
 """
 
 from __future__ import annotations

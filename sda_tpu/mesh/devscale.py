@@ -55,26 +55,7 @@ from ..fields.dimtile import scan_dim_tiles, tile_plan
 from ..fields.ops import FieldOps
 from ..obs import devprof
 from ..utils import metrics, timed_phase
-from .simpod import (
-    _build_matrices,
-    _chacha_cipher,
-    _check_collective_headroom,
-    _check_mask_modulus,
-    _check_masking_supported,
-    _dim_grain,
-    _draw_scope,
-    _mask_stage,
-    _normalize_survivors,
-    _pallas_stage,
-    _reconstruct_stage,
-    _resolve_pallas,
-    _scheme_modulus,
-    _shard_map,
-    _share_sum_stage,
-    _tile_key,
-    default_mesh_shape,
-    make_mesh,
-)
+from .simpod import _dim_grain, _Planned, _RoundPlan, _shard_map, _tile_key
 
 __all__ = [
     "DeviceTileCombiner",
@@ -176,7 +157,7 @@ def watermark_dim_tile(
 # The sharded scan round: one program, tiles streamed inside it
 
 
-class ModelScaleRound:
+class ModelScaleRound(_Planned):
     """One jitted shard_map round whose per-device body scans dim tiles.
 
     The pjit x scan x Pallas composition: the ``[P, dim]`` combine is
@@ -206,34 +187,11 @@ class ModelScaleRound:
         surviving_clerks=None,
         participants_chunk: int = 8,
     ):
-        import jax
-
-        from ..protocol import NoMasking
-
-        self.scheme = s = sharing_scheme
-        self.modulus = _scheme_modulus(s)
-        self.masking = masking_scheme or NoMasking()
-        _check_masking_supported(self.masking)
-        _check_mask_modulus(self.masking, s)
-        if mesh is None:
-            p_shards, d_shards = default_mesh_shape(
-                len(jax.devices()), s.output_size)
-            mesh = make_mesh(p_shards, d_shards)
-        self.mesh = mesh
-        p_shards, d_shards = mesh.devices.shape
-        if s.output_size % p_shards:
-            raise ValueError(
-                f"committee size {s.output_size} must be divisible by the "
-                f"p axis ({p_shards})")
-        self.surviving_clerks = _normalize_survivors(s, surviving_clerks)
-        self._M_host, self._L_host = _build_matrices(s, self.surviving_clerks)
-        self._field = FieldOps.create(self.modulus, cross_terms=p_shards)
-        _check_collective_headroom(self._field, p_shards)
-        self.pallas_active = _resolve_pallas(
-            s, self.masking, self._field, use_pallas, "model-scale")
-        self._cipher = _chacha_cipher(self._field, mesh.devices)
-        self._pallas_interpret = bool(pallas_interpret)
-        self._pallas_bits_fn = pallas_external_bits_fn
+        self._plan = _RoundPlan(sharing_scheme, masking_scheme, mesh,
+                                use_pallas, surviving_clerks, pallas_interpret,
+                                pallas_external_bits_fn)
+        s = self.scheme
+        p_shards, d_shards = self.mesh.devices.shape
         # tile grain: whole packing columns AND whole ChaCha blocks (the
         # per-tile d_block0 window arithmetic needs 8-aligned widths),
         # same rule as mesh.single_chip_round's tiled schedule
@@ -251,17 +209,13 @@ class ModelScaleRound:
         self._step = None
         self._step_shape = None
 
-    @property
-    def _sp(self):
-        return self._field.sp
-
     def _local_round(self, inputs, key):
         """Per-device body: scan the local [P_loc, d_loc] shard in tiles."""
         import jax
 
-        f, s, masking = self._field, self.scheme, self.masking
+        plan = self._plan
+        f, draws = plan.field, plan.draws
         P_loc, d_loc = inputs.shape
-        draws = _draw_scope(self.pallas_active)
         with jax.named_scope(draws):
             pi = jax.lax.axis_index("p")
             di = jax.lax.axis_index("d")
@@ -276,34 +230,10 @@ class ModelScaleRound:
             x = f.to_residues(blk)
             with jax.named_scope(draws):
                 pid0 = pi * P_loc
-            if self.pallas_active:
-                shares, mask_sum = _pallas_stage(
-                    s, f, self._M_host, masking, x, dev_key,
-                    round_key=round_key, pid_base=pid0,
-                    d_block0=d_block0,
-                    interpret=self._pallas_interpret,
-                    external_bits_fn=self._pallas_bits_fn,
-                    cipher=self._cipher,
-                )
-            else:
-                masked_sum, mask_sum, skey = _mask_stage(
-                    masking, f, x, dev_key, round_key,
-                    pid_base=pid0, d_block0=d_block0, cipher=self._cipher,
-                )
-                shares = _share_sum_stage(
-                    s, f, self._M_host, masked_sum, x.shape[0], skey)
-            with jax.named_scope("sda.clerk_combine"):
-                rows = jax.lax.psum_scatter(
-                    shares, "p", scatter_dimension=0, tiled=True)
-                rows = f.canon(rows)
-                gathered = jax.lax.all_gather(rows, "p", axis=0, tiled=True)
-            total = _reconstruct_stage(s, f, self._L_host, gathered, width,
-                                       self.surviving_clerks)
-            with jax.named_scope("sda.unmask"):
-                if mask_sum is None:
-                    return f.to_int64(total)
-                mask_total = f.canon(jax.lax.psum(mask_sum, "p"))
-                return f.to_int64(f.sub(total, mask_total))
+            shares, mask_sum = plan.local_step(
+                x, dev_key, round_key=round_key, pid_base=pid0,
+                d_block0=d_block0)
+            return plan.finale(shares, mask_sum, width)
 
         return scan_dim_tiles(one_tile, self._grain_loc, self._tile_loc)(
             inputs, key)

@@ -42,6 +42,7 @@ federated HTTP mode keeps sealed boxes.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -83,30 +84,6 @@ def _scheme_modulus(scheme: LinearSecretSharingScheme) -> int:
     if isinstance(scheme, AdditiveSharing):
         return scheme.modulus
     raise ValueError(f"unsupported sharing scheme {type(scheme).__name__}")
-
-
-def _check_mask_modulus(masking, scheme) -> None:
-    # the mask/unmask algebra only cancels when masking and sharing operate
-    # in the same group
-    mask_mod = getattr(masking, "modulus", None)
-    if mask_mod is not None and mask_mod != _scheme_modulus(scheme):
-        raise ValueError(
-            f"masking modulus {mask_mod} != sharing modulus "
-            f"{_scheme_modulus(scheme)}: masks would not cancel"
-        )
-
-
-def _check_collective_headroom(field: FieldOps, p_shards: int) -> None:
-    """psum/psum_scatter add ``p_shards`` canonical residues before the next
-    canonicalize; the int64 path cannot chunk inside a collective, so the
-    bound must hold up front (the uint32 path's bound is enforced by
-    FieldOps.create falling back to int64)."""
-    if field.sp is None and p_shards * (field.m - 1) >= (1 << 63):
-        raise ValueError(
-            f"modulus {field.m} too large for {p_shards}-way participant "
-            f"shards: cross-shard sums would overflow int64 — use fewer "
-            f"p shards or a smaller modulus"
-        )
 
 
 def make_mesh(p_shards: int, d_shards: int, devices=None) -> Mesh:
@@ -167,20 +144,6 @@ def _tile_key(round_key, *indices):
     for ix in indices:
         k = jax.random.fold_in(k, ix)
     return k
-
-
-def _draw_scope(pallas_active: bool) -> str:
-    """The stage scope a local step derives its place on the mesh and its
-    keys under: the stage that draws from them -- the kernel, or the XLA
-    step's share stage (docs/observability.md, "Stage scopes")."""
-    return "sda.mask_share" if pallas_active else "sda.share"
-
-
-def _check_masking_supported(masking) -> None:
-    if not isinstance(masking, (NoMasking, FullMasking, ChaChaMasking)):
-        raise ValueError(
-            f"unsupported masking scheme {type(masking).__name__}"
-        )
 
 
 def _reported_rows(x, reported):
@@ -318,44 +281,6 @@ def _element_order(folded):
         return chacha_jax.element_order(folded)
 
 
-def _mask_stage(masking, f: FieldOps, x, key, round_key, pid_base, d_block0,
-                cipher: str = "xla"):
-    """-> (masked_sum [d_loc], local_mask_sum [d_loc] or None, share_key):
-    the block's rows folded, Σ (x + mask), and the fold of their masks.
-
-    Σ (x + m) = Σ x + Σ m mod p bit for bit, so the masks never meet the
-    [S, d_loc] input, only its fold, and no [S, d_loc] array of masks or
-    masked rows exists; under ChaCha masking the masks fold on the layout
-    the cipher makes them in (``_chacha_mask_fold``: on a TPU one kernel
-    call a block) and ONE row goes through ``_element_order``.
-
-    ``pid_base``: global id of the first local participant row (ChaCha
-    seeds are a function of (round key, global participant id) only).
-    ``d_block0``: ChaCha block counter at this shard's dim offset
-    (= global_dim_offset / 8). Both may be traced. ``cipher``: the one the
-    step was built for (``_chacha_cipher``).
-    """
-    S, d_loc = x.shape
-    with jax.named_scope("sda.fold"):
-        x_sum = f.sum(x, axis=0)
-    # named scope: the mask stage's ops land on a "sda.mask"-prefixed XProf
-    # device lane, so merged traces attribute device time to the phase
-    with jax.named_scope("sda.mask"):
-        if isinstance(masking, FullMasking):
-            mkey, skey = jax.random.split(key)
-            masks = f.uniform(mkey, (S, d_loc))
-            with jax.named_scope("sda.mask.fold"):
-                mask_sum = f.sum(masks, axis=0)
-        elif isinstance(masking, ChaChaMasking):
-            skey = key
-            mask_sum = _element_order(_chacha_mask_fold(
-                masking, f, round_key, pid_base, S, d_loc, d_block0, cipher))
-        else:
-            return x_sum, None, key
-        with jax.named_scope("sda.mask.fold"):
-            return f.add(x_sum, mask_sum), mask_sum, skey
-
-
 def _chacha_mask_sum(masking, f: FieldOps, round_key, pid_base, rows: int,
                      d_loc: int, d_block0, cipher: str = "xla"):
     """-> [d_loc] sum of the ChaCha masks of ``rows`` participants, put in
@@ -415,7 +340,18 @@ def _drawn_shape(scheme, d: int) -> Tuple[int, int]:
 def _share_combine(scheme, f: FieldOps, M_host, masked_sum, drawn):
     """[d_loc] fold of the participants' masked residues and the fold of
     their share randomness (``_share_draws``) -> [n, B] participant-SUMMED
-    share rows."""
+    share rows.
+
+    Share generation is linear in the (secrets, randomness) vector, so the
+    clerk-combined output Σ_p M @ v_p equals M @ Σ_p v_p: participants
+    fold with cheap modular adds FIRST and the share matmul runs once --
+    the [S, n, B] per-participant share tensor is never materialized
+    (those rows live on the participants' own devices in the federated
+    protocol; a pod computing the aggregate needs only their sum).
+    Bit-exact vs summing per-participant shares from
+    ``sharing.packed_share32``/``packed_share``/``additive_share`` (the
+    federated client path) drawn from the same key: mod-m arithmetic is
+    exact, so fold order is free (tests/test_mesh.py)."""
     d = masked_sum.shape[0]
     with jax.named_scope("sda.share"):
         if isinstance(scheme, SHAMIR_SCHEMES):
@@ -443,58 +379,6 @@ def _share_combine(scheme, f: FieldOps, M_host, masked_sum, drawn):
         return jnp.concatenate([drawn, last], axis=0)
 
 
-def _share_sum_stage(scheme, f: FieldOps, M_host, masked_sum, rows: int,
-                     skey):
-    """[d_loc] fold of ``rows`` participants' masked residues (what
-    ``_mask_stage`` hands on) -> [n, B] participant-SUMMED share rows.
-
-    Share generation is linear in the (secrets, randomness) vector, so the
-    clerk-combined output Σ_p M @ v_p equals M @ Σ_p v_p: participants
-    fold with cheap modular adds FIRST and the share matmul runs once —
-    the [S, n, B] per-participant share tensor is never materialized
-    (those rows live on the participants' own devices in the federated
-    protocol; a pod computing the aggregate needs only their sum).
-    Bit-exact vs summing per-participant shares from
-    ``sharing.packed_share32``/``packed_share``/``additive_share`` (the
-    federated client path): the same randomness shapes are drawn from the
-    same key and mod-m arithmetic is exact, so fold order is free —
-    tests/test_mesh.py and test_fast_rounds.py pin this equivalence.
-    The draws' fold has one consumer, so the additive branch asks for no
-    total of the draws (``_share_combine``; tests/test_tpu_compile.py).
-    """
-    drawn = _share_draws(scheme, f, rows, masked_sum.shape[0], skey)
-    return _share_combine(scheme, f, M_host, masked_sum, drawn)
-
-
-def _pallas_supported(scheme, masking, f: FieldOps) -> bool:
-    """The fused kernel serves packed-Shamir over a Solinas prime with any
-    masking in the lattice. None/Full draw inside the kernel; ChaCha masks
-    are expanded from the CHACHA_PRG_V1 stream FIRST (``_chacha_mask_sum``)
-    and the kernel runs mask-free on the masked fold — see _pallas_stage. Pod-internal masks are generated AND
-    cancelled inside the round (never wire-visible), so this choice is
-    independent of the scheme's ``prg`` tag — any prg-tagged ChaChaMasking
-    is accepted and the aggregate is exact either way."""
-    return (
-        isinstance(scheme, SHAMIR_SCHEMES)
-        and f.sp is not None
-        and isinstance(masking, (NoMasking, FullMasking, ChaChaMasking))
-    )
-
-
-def _resolve_pallas(scheme, masking, f: FieldOps, use_pallas: bool,
-                    what: str) -> bool:
-    """Shared constructor gating for the aggregators: the kernel asked for
-    on a config it does not serve raises; nothing falls back to the XLA
-    step behind the caller."""
-    want = bool(use_pallas)
-    if want and not _pallas_supported(scheme, masking, f):
-        raise ValueError(
-            f"pallas {what} step requires packed-Shamir over a Solinas "
-            f"prime (none/full/chacha masking)"
-        )
-    return want
-
-
 def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
                   round_key=None, pid_base=0, d_block0=0,
                   interpret: bool = False, external_bits_fn=None,
@@ -502,9 +386,9 @@ def _pallas_stage(scheme, f: FieldOps, M_host, masking, x, dev_key, *,
     """[S, d_loc] canonical residues -> (combined shares [n, B0],
     mask sum [d_loc] | None) on the fused Pallas kernel.
 
-    Drop-in replacement for the _mask_stage + _share_sum_stage pair in the
-    pod/streamed local steps. Fold first, lay out second, as
-    _share_sum_stage does: the residues fold over the participants on
+    The kernel path's local step (``_RoundPlan``), ``_scan_combine``'s
+    twin. Fold first, lay out second, as ``_share_combine`` does: the
+    residues fold over the participants on
     their native [S, d_loc] layout (``sda.fold``: one read of the input,
     exact), the column-per-batch relayout and the pad to the kernel's
     column tile run on the folded [d_loc] vector (``sda.relayout``), and
@@ -594,9 +478,12 @@ def _scan_rows(rows: int, chunk: int) -> Tuple[int, int]:
     return chunk, -(-rows // chunk) * chunk
 
 
-def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
-                  pid0, dblk0, chunk: int, reported=None, cipher: str = "xla"):
-    """[P, d] canonical residues -> (acc_shares [n, B], acc_mask [d]|None).
+def _scan_combine(scheme, f: FieldOps, M_host, masking, x, key, *,
+                  round_key=None, pid_base=0, d_block0=0, reported=None,
+                  cipher: str = "xla"):
+    """[P, d] canonical residues -> (acc_shares [n, B], acc_mask [d]|None):
+    the XLA step, every driver's local step where the fused kernel is not
+    asked for (``_RoundPlan``); ``_pallas_stage``'s call form.
 
     All that is linear in the rows happens once a round, outside the
     participant scan: the rows fold in ONE read of the input
@@ -608,21 +495,21 @@ def _scan_combine(f: FieldOps, scheme, masking, M_host, x, key, round_key,
     randomness (``_share_combine``: share generation is linear).
 
     The scan carries only what is drawn by block, from the block's key
-    ``fold_in(key, i)``: the share randomness of ``chunk`` rows
+    ``fold_in(key, i)``: the share randomness of ``_SCAN_CHUNK`` rows
     (``_share_draws``) and, under full masking, their masks (``split`` of
     that key), each folded into its running sum. What is live is one
     block's draws, [chunk, ...] instead of [P, ...]. The draws of the
     rows that pad the last block, or did not report, only cancel.
     """
     P, d = x.shape
-    chunk, padded_rows = _scan_rows(P, chunk)
+    chunk, padded_rows = _scan_rows(P, _SCAN_CHUNK)
     full = isinstance(masking, FullMasking)
     with jax.named_scope("sda.fold"):
         x_sum = f.sum(_reported_rows(x, reported), axis=0)
     mask_sum = None
     if isinstance(masking, ChaChaMasking):
-        mask_sum = _chacha_mask_sum(masking, f, round_key, pid0, P, d, dblk0,
-                                    cipher)
+        mask_sum = _chacha_mask_sum(masking, f, round_key, pid_base, P, d,
+                                    d_block0, cipher)
 
     def body(carry, i):
         drawn, acc_m = carry
@@ -769,22 +656,147 @@ def _reported_operand(reported, rows: int, padded_rows: int):
     return pad(reported, (0, padded_rows - rows))
 
 
-class SimulatedPod:
+class _RoundPlan:
+    """What every round driver settles before it traces a step, in one
+    place: one per driver, built by its constructor from the caller's
+    arguments. It holds the scheme's modulus; the masking, defaulted and
+    checked against the lattice and the modulus; the mesh (``None``: the
+    default mesh over every device) and its committee layout; the clerk
+    quorum and the share / reconstruct matrices; the field, with the
+    headroom its collectives need over ``p``; and the local step.
+
+    ``local_step(x [S, d_loc], dev_key, *, round_key, pid_base, d_block0,
+    reported=None) -> (participant-summed shares [n, B], masks' sum
+    [d_loc] | None)`` is the fused kernel (``_pallas_stage``) where it is
+    asked for and the XLA step (``_scan_combine``) everywhere else, with
+    the ChaCha ``cipher`` the steps are built for (``_chacha_cipher``).
+    This is the one place the two are chosen, before anything is traced;
+    a ``functools.partial``, so no Python frame stands between a driver's
+    traced function and its stage. ``draws`` is the stage scope a step
+    derives its place on the mesh and its keys under: the stage that draws
+    from them (docs/observability.md, "Stage scopes").
+
+    The fused kernel serves packed and basic Shamir over a Solinas prime
+    with none/full/ChaCha masking; asked for on another scheme or modulus
+    it raises, and nothing falls back to the XLA step behind the caller.
+    Pod-internal masks are generated AND cancelled inside the round, so
+    the choice is independent of the masking's wire ``prg`` tag.
+    """
+
+    def __init__(self, scheme: LinearSecretSharingScheme, masking, mesh,
+                 use_pallas: bool = False, surviving_clerks=None,
+                 pallas_interpret: bool = False, pallas_external_bits_fn=None):
+        self.scheme = scheme
+        self.modulus = _scheme_modulus(scheme)
+        self.masking = masking or NoMasking()
+        if not isinstance(self.masking, (NoMasking, FullMasking, ChaChaMasking)):
+            raise ValueError(
+                f"unsupported masking scheme {type(self.masking).__name__}")
+        # the mask/unmask algebra only cancels when masking and sharing
+        # operate in the same group
+        mask_mod = getattr(self.masking, "modulus", None)
+        if mask_mod is not None and mask_mod != self.modulus:
+            raise ValueError(f"masking modulus {mask_mod} != sharing modulus "
+                             f"{self.modulus}: masks would not cancel")
+        if mesh is None:
+            mesh = make_mesh(*default_mesh_shape(len(jax.devices()),
+                                                 scheme.output_size))
+        self.mesh = mesh
+        p_shards = mesh.devices.shape[0]
+        if scheme.output_size % p_shards:
+            raise ValueError(
+                f"committee size {scheme.output_size} must be divisible by "
+                f"the p axis ({p_shards})")
+        self.survivors = _normalize_survivors(scheme, surviving_clerks)
+        self.M_host, self.L_host = _build_matrices(scheme, self.survivors)
+        # cross-shard share/mask sums ride collectives between canonicalizes
+        # (psum/psum_scatter add p_shards canonical residues): past the
+        # uint32 path's bound FieldOps.create falls back to int64, and the
+        # int64 path, which cannot chunk inside a collective, must hold it
+        self.field = f = FieldOps.create(self.modulus, cross_terms=p_shards)
+        if f.sp is None and p_shards * (f.m - 1) >= (1 << 63):
+            raise ValueError(
+                f"modulus {f.m} too large for {p_shards}-way participant "
+                f"shards: cross-shard sums would overflow int64 — use fewer "
+                f"p shards or a smaller modulus")
+        self.pallas_active = bool(use_pallas)
+        if self.pallas_active and not (isinstance(scheme, SHAMIR_SCHEMES)
+                                       and f.sp is not None):
+            raise ValueError("the pallas step requires packed-Shamir over a "
+                             "Solinas prime (none/full/chacha masking)")
+        self.cipher = _chacha_cipher(f, mesh.devices)
+        head = (scheme, f, self.M_host, self.masking)
+        if self.pallas_active:
+            self.draws = "sda.mask_share"
+            self.local_step = functools.partial(
+                _pallas_stage, *head, cipher=self.cipher,
+                interpret=bool(pallas_interpret),
+                external_bits_fn=pallas_external_bits_fn)
+        else:
+            self.draws = "sda.share"
+            self.local_step = functools.partial(_scan_combine, *head,
+                                                cipher=self.cipher)
+
+    def finale(self, shares, mask_sum, d_loc: int, collective: bool = True):
+        """[n, B] participant-summed shares and the masks' sum (None: no
+        masks) -> the [d_loc] int64 aggregate: reconstruct, then unmask.
+
+        ``collective``: a step under ``shard_map`` over the mesh, whose
+        rows and sums are its device's part. The snapshot transpose and
+        every clerk's combine are then ONE ``psum_scatter`` over ``p`` (the
+        clerk axis split across ``p`` while the participant shards' partial
+        sums combine), the recipient gathers every clerk row with one
+        ``all_gather``, and the masks' sums are ``psum``'d over ``p``
+        before they are subtracted. Without, the rows and the sum are the
+        whole committee's already."""
+        f = self.field
+        if collective:
+            with jax.named_scope("sda.clerk_combine"):
+                rows = jax.lax.psum_scatter(shares, "p", scatter_dimension=0,
+                                            tiled=True)            # [n/p, B]
+                rows = f.canon(rows)
+                shares = jax.lax.all_gather(rows, "p", axis=0, tiled=True)
+        total = _reconstruct_stage(self.scheme, f, self.L_host, shares, d_loc,
+                                   self.survivors)                 # [d_loc]
+        with jax.named_scope("sda.unmask"):
+            if mask_sum is None:
+                return f.to_int64(total)
+            if collective:
+                mask_sum = f.canon(jax.lax.psum(mask_sum.reshape(d_loc), "p"))
+            return f.to_int64(f.sub(total, mask_sum))
+
+
+class _Planned:
+    """A round driver's plan (``self._plan``) under the names its callers
+    and the benchmark read."""
+
+    scheme = property(lambda self: self._plan.scheme)
+    mesh = property(lambda self: self._plan.mesh)
+    masking = property(lambda self: self._plan.masking)
+    modulus = property(lambda self: self._plan.modulus)
+    surviving_clerks = property(lambda self: self._plan.survivors)
+    pallas_active = property(lambda self: self._plan.pallas_active)
+    _field = property(lambda self: self._plan.field)
+    #: Solinas parameters when the uint32 fast path is active, else None
+    _sp = property(lambda self: self._plan.field.sp)
+    _cipher = property(lambda self: self._plan.cipher)
+
+
+class SimulatedPod(_Planned):
     """One secure-aggregation round as a single SPMD program.
 
     Committee size must be divisible by the ``p`` axis; participant and
     dimension counts are auto-padded to the mesh/scheme grain (zero rows
     and components aggregate as zero; padding is stripped from the output).
 
-    Two local steps compute the same round. The **XLA step** is the
-    default (``use_pallas=False``) and serves every scheme and masking:
-    ``_scan_combine`` folds the rows once and draws the share randomness
-    (and full masks) in a scan over blocks of ``scan_chunk`` rows. The
-    **fused Pallas kernel** (``use_pallas=True``) serves packed and basic
-    Shamir over a Solinas prime with none/full/ChaCha masking
-    (``_pallas_supported``); asked for on additive sharing or a
-    non-Solinas modulus it raises, so an additive-sharing aggregation
-    always runs the XLA step.
+    Two local steps compute the same round (``_RoundPlan``). The **XLA
+    step** is the default (``use_pallas=False``) and serves every scheme
+    and masking: ``_scan_combine`` folds the rows once and draws the share
+    randomness (and full masks) in a scan over blocks of ``scan_chunk``
+    rows. The **fused Pallas kernel** (``use_pallas=True``) serves packed
+    and basic Shamir over a Solinas prime with none/full/ChaCha masking;
+    asked for on additive sharing or a non-Solinas modulus it raises, so
+    an additive-sharing aggregation always runs the XLA step.
     ``pallas_active`` says which step this pod took.
 
     Under ChaCha masking every dispatch of the round counts
@@ -795,58 +807,25 @@ class SimulatedPod:
     a pod with any other masking counts nothing.
     """
 
+    #: participant rows a block of the XLA step's scan holds
+    scan_chunk = _SCAN_CHUNK
+
     def __init__(
         self,
         sharing_scheme: LinearSecretSharingScheme,
         masking_scheme: Optional[LinearMaskingScheme] = None,
         mesh: Optional[Mesh] = None,
-        scan_chunk: int = _SCAN_CHUNK,
         use_pallas: bool = False,
         pallas_interpret: bool = False,
         pallas_external_bits_fn=None,
         surviving_clerks=None,
     ):
-        self.scan_chunk = int(scan_chunk)
-        self.scheme = sharing_scheme
-        self.modulus = _scheme_modulus(sharing_scheme)
-        self.masking = masking_scheme or NoMasking()
-        _check_masking_supported(self.masking)
-        _check_mask_modulus(self.masking, sharing_scheme)
-        self._pallas_interpret = bool(pallas_interpret)
-        self._pallas_bits_fn = pallas_external_bits_fn
-        if mesh is None:
-            p_shards, d_shards = default_mesh_shape(
-                len(jax.devices()), sharing_scheme.output_size
-            )
-            mesh = make_mesh(p_shards, d_shards)
-        self.mesh = mesh
-        p_shards = mesh.devices.shape[0]
-        if sharing_scheme.output_size % p_shards:
-            raise ValueError(
-                f"committee size {sharing_scheme.output_size} must be divisible "
-                f"by the p axis ({p_shards})"
-            )
-        self.surviving_clerks = _normalize_survivors(
-            sharing_scheme, surviving_clerks
-        )
-        self._M_host, self._L_host = _build_matrices(
-            sharing_scheme, self.surviving_clerks
-        )
-        # cross-shard share/mask sums ride collectives between canonicalizes
-        self._field = FieldOps.create(self.modulus, cross_terms=p_shards)
-        _check_collective_headroom(self._field, p_shards)
-        self.pallas_active = _resolve_pallas(
-            sharing_scheme, self.masking, self._field, use_pallas, "local"
-        )
-        self._cipher = _chacha_cipher(self._field, mesh.devices)
+        self._plan = _RoundPlan(sharing_scheme, masking_scheme, mesh,
+                                use_pallas, surviving_clerks, pallas_interpret,
+                                pallas_external_bits_fn)
         self._step = None
         self._step_shape = None
         self._programs = {}  # round_program: what callers built, by their key
-
-    @property
-    def _sp(self):
-        """Solinas parameters when the uint32 fast path is active, else None."""
-        return self._field.sp
 
     # ------------------------------------------------------------------
     def _local_round(self, inputs, key, reported=None):
@@ -854,10 +833,9 @@ class SimulatedPod:
         ``reported`` [P_loc] (bool, traced) the rows that did not report
         count as zero, and the mesh's count of reporters is returned
         beside the aggregate."""
-        f = self._field
+        plan = self._plan
         P_loc, d_loc = inputs.shape
-        draws = _draw_scope(self.pallas_active)
-        with jax.named_scope(draws):
+        with jax.named_scope(plan.draws):
             pi = jax.lax.axis_index("p")
             di = jax.lax.axis_index("d")
             # distinct randomness per device block, domain-separated from
@@ -865,51 +843,18 @@ class SimulatedPod:
             # dim shard derives the same per-participant seed
             dev_key = _tile_key(key, pi, di)
 
-        x = f.to_residues(inputs)
-        with jax.named_scope(draws):  # behind the residue pass, as it lowers
+        x = plan.field.to_residues(inputs)
+        with jax.named_scope(plan.draws):  # behind the residue pass, as it lowers
             pid0, dblk0 = pi * P_loc, di * (d_loc // 8)
-        if self.pallas_active:
-            # fused mask+share+combine in one HBM pass (pallas_round.py)
-            local_sum, local_mask_sum = _pallas_stage(
-                self.scheme, f, self._M_host, self.masking, x, dev_key,
-                round_key=key, pid_base=pid0, d_block0=dblk0,
-                interpret=self._pallas_interpret,
-                external_bits_fn=self._pallas_bits_fn, reported=reported,
-                cipher=self._cipher,
-            )                                                      # [n, B_loc]
-        else:
-            # participant parallelism -> local scan-chunked reduction (share
-            # tensor stays [chunk, n, B_loc], never [P_loc, n, B_loc])
-            local_sum, local_mask_sum = _scan_combine(
-                f, self.scheme, self.masking, self._M_host, x, dev_key, key,
-                pid0=pid0, dblk0=dblk0, chunk=self.scan_chunk,
-                reported=reported, cipher=self._cipher,
-            )                                                      # [n, B_loc]
-
-        # snapshot transpose + clerk combine == one psum_scatter over ICI:
-        # clerk axis is split across 'p' while partial sums are combined
-        with jax.named_scope("sda.clerk_combine"):
-            clerk_rows = jax.lax.psum_scatter(
-                local_sum, "p", scatter_dimension=0, tiled=True
-            )                                                      # [n/p, B_loc]
-            clerk_rows = f.canon(clerk_rows)
-
-            # recipient gathers all clerk rows (clerk -> recipient leg)
-            gathered = jax.lax.all_gather(clerk_rows, "p", axis=0, tiled=True)
-
-        masked_total = _reconstruct_stage(
-            self.scheme, f, self._L_host, gathered, d_loc,
-            self.surviving_clerks,
-        )                                                          # [d_loc]
-
+        # participant parallelism is a local fold: no [P_loc, n, B_loc]
+        # share tensor on either step
+        local_sum, local_mask_sum = plan.local_step(
+            x, dev_key, round_key=key, pid_base=pid0, d_block0=dblk0,
+            reported=reported)                                     # [n, B_loc]
+        total = plan.finale(local_sum, local_mask_sum, d_loc)
+        if reported is None:
+            return total
         with jax.named_scope("sda.unmask"):
-            if local_mask_sum is None:
-                total = f.to_int64(masked_total)
-            else:
-                mask_total = f.canon(jax.lax.psum(local_mask_sum, "p"))
-                total = f.to_int64(f.sub(masked_total, mask_total))
-            if reported is None:
-                return total
             # the divisor of a mean over the rows that reported: a scalar
             # the program reads, the same on every device
             return total, jax.lax.psum(
@@ -1093,34 +1038,17 @@ def single_chip_round(
     round over its own columns (masks cancel per tile; ChaCha tiles read
     their window of the global stream via d_block0).
     """
-    scheme = sharing_scheme
-    masking = masking_scheme or NoMasking()
-    if not isinstance(masking, (NoMasking, FullMasking, ChaChaMasking)):
-        raise ValueError(
-            f"unsupported masking scheme {type(masking).__name__}"
-        )
-    _check_mask_modulus(masking, scheme)
-    M_host, L_host = _build_matrices(scheme)
-    f = FieldOps.create(_scheme_modulus(scheme))
     # the round is jitted by the caller and runs on the default device
-    cipher = _chacha_cipher(f, jax.devices()[:1])
+    plan = _RoundPlan(sharing_scheme, masking_scheme, make_mesh(1, 1))
+    f = plan.field
     # tile grain: whole packing columns (input_size) and whole ChaCha
     # blocks (8 u64 draws) — same grain as the streaming driver
-    grain = scheme.input_size * 8 // math.gcd(scheme.input_size, 8)
+    grain = math.lcm(sharing_scheme.input_size, 8)
 
     def one_tile(x, bkey, round_key, d_block0, d_loc):
-        masked_sum, mask_total, skey = _mask_stage(
-            masking, f, x, bkey, round_key, pid_base=0, d_block0=d_block0,
-            cipher=cipher,
-        )
-        # share + clerk combine fused via linearity (see _share_sum_stage)
-        combined = _share_sum_stage(
-            scheme, f, M_host, masked_sum, x.shape[0], skey)       # [n, B]
-        masked_total = _reconstruct_stage(scheme, f, L_host, combined, d_loc)
-        with jax.named_scope("sda.unmask"):
-            if mask_total is None:
-                return f.to_int64(masked_total)
-            return f.to_int64(f.sub(masked_total, mask_total))
+        combined, mask_total = plan.local_step(
+            x, bkey, round_key=round_key, pid_base=0, d_block0=d_block0)
+        return plan.finale(combined, mask_total, d_loc, collective=False)
 
     if dim_tile is None:
         def round_fn(inputs, key):

@@ -40,32 +40,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..fields.ops import FieldOps
 from ..obs import devprof
-from ..protocol import (
-    ChaChaMasking,
-    FullMasking,
-    LinearMaskingScheme,
-    NoMasking,
-)
+from ..protocol import LinearMaskingScheme, NoMasking
 from ..utils import metrics, timed_phase
 from .simpod import (
-    _chacha_cipher,
-    _check_collective_headroom,
-    _check_mask_modulus,
-    _check_masking_supported,
     _dim_grain,
-    _draw_scope,
-    _build_matrices,
-    _mask_stage,
-    _normalize_survivors,
-    _pallas_stage,
-    _reconstruct_stage,
-    _resolve_pallas,
-    _scheme_modulus,
+    _Planned,
+    _RoundPlan,
     _shard_map,
-    _share_sum_stage,
     _tile_key,
+    make_mesh,
     refuse_reported,
 )
 
@@ -481,7 +465,7 @@ def _round_fingerprint(scheme, masking, participants, dimension, pc, dc,
     return hashlib.sha256(canonical_json(payload)).hexdigest()
 
 
-class StreamingAggregator:
+class StreamingAggregator(_Planned):
     """Chunked single-chip rounds: fixed device memory for any P and d.
 
     Full scheme-lattice coverage like the pod modes: Packed-Shamir OR
@@ -510,14 +494,13 @@ class StreamingAggregator:
         surviving_clerks=None,
         uniform_tail: bool = False,
     ):
-        self.scheme = s = sharing_scheme
-        self.modulus = _scheme_modulus(s)  # also validates the scheme type
-        self.masking = masking_scheme or NoMasking()
-        _check_masking_supported(self.masking)
-        _check_mask_modulus(self.masking, s)
+        # the steps are jitted for the default device
+        self._plan = _RoundPlan(sharing_scheme, masking_scheme, make_mesh(1, 1),
+                                use_pallas, surviving_clerks, pallas_interpret,
+                                pallas_external_bits_fn)
         # ChaCha seed masks expand a window of one per-participant stream at
         # each tile's dim offset, so tiles align to the 8-word block grain
-        self._grain = _dim_grain(s, self.masking)
+        self._grain = _dim_grain(self.scheme, self.masking)
         self.participants_chunk = int(participants_chunk)
         self.dim_chunk = -(-int(dim_chunk) // self._grain) * self._grain
         # uniform_tail pads the LAST dim tile to the full dim_chunk width
@@ -527,50 +510,21 @@ class StreamingAggregator:
         # dim_chunk ~ dim/ntiles. Exactness
         # pinned in tests/test_streaming.py (uniform-tail block).
         self.uniform_tail = bool(uniform_tail)
-        self.surviving_clerks = _normalize_survivors(s, surviving_clerks)
-        self._M_host, self._L_host = _build_matrices(
-            s, self.surviving_clerks
-        )  # None for additive
-        self._field = FieldOps.create(self.modulus)
-        self._sp = self._field.sp
-        self.pallas_active = _resolve_pallas(
-            s, self.masking, self._field, use_pallas, "streamed"
-        )
-        # the steps are jitted for the default device
-        self._cipher = _chacha_cipher(self._field, jax.devices()[:1])
-        self._pallas_interpret = bool(pallas_interpret)
-        self._pallas_bits_fn = pallas_external_bits_fn
         self._steps = {}      # block shape -> jitted accumulate step
         self._finals = {}     # dim size -> jitted reconstruct+unmask
 
     # -- jitted pieces ---------------------------------------------------
     def _step_fn(self, block_shape):
-        s, f = self.scheme, self._field
-        M_host = self._M_host
+        plan = self._plan
+        f = plan.field
 
         def step(block, key, round_key, pid0, dblk0, acc_shares, acc_mask):
             x = f.to_residues(block)
-            if self.pallas_active:
-                # fused mask+share+combine in one HBM pass (pallas_round.py)
-                shares, mask_sum = _pallas_stage(
-                    s, f, M_host, self.masking, x, key,
-                    round_key=round_key, pid_base=pid0, d_block0=dblk0,
-                    interpret=self._pallas_interpret,
-                    external_bits_fn=self._pallas_bits_fn,
-                    cipher=self._cipher,
-                )
-            else:
-                # pid0/dblk0 (traced) locate this tile in the global stream
-                # so ChaCha seed masks expand the right window of each
-                # participant's stream regardless of tiling
-                masked_sum, mask_sum, skey = _mask_stage(
-                    self.masking, f, x, key, round_key,
-                    pid_base=pid0, d_block0=dblk0, cipher=self._cipher,
-                )
-                # share + participant-combine fused via linearity
-                # (simpod._share_sum_stage): no [S, n, B] tensor in HBM
-                shares = _share_sum_stage(
-                    s, f, M_host, masked_sum, x.shape[0], skey)
+            # pid0/dblk0 (traced) locate this tile in the global stream so
+            # ChaCha seed masks expand the right window of each
+            # participant's stream regardless of tiling
+            shares, mask_sum = plan.local_step(
+                x, key, round_key=round_key, pid_base=pid0, d_block0=dblk0)
             with jax.named_scope("sda.stream.acc"):
                 acc_shares = f.add(acc_shares, shares)
                 if mask_sum is not None:
@@ -585,16 +539,12 @@ class StreamingAggregator:
                                   jax.jit(step, donate_argnums=(5, 6)))
 
     def _final_fn(self, d_size):
-        s, f = self.scheme, self._field
-        mask = not isinstance(self.masking, NoMasking)
+        plan = self._plan
+        masked = not isinstance(self.masking, NoMasking)
 
         def final(acc_shares, acc_mask):
-            total = _reconstruct_stage(s, f, self._L_host, acc_shares, d_size,
-                                       self.surviving_clerks)
-            with jax.named_scope("sda.unmask"):
-                if mask:
-                    total = f.sub(total, acc_mask)
-                return f.to_int64(total)
+            return plan.finale(acc_shares, acc_mask if masked else None,
+                               d_size, collective=False)
 
         return devprof.instrument("stream.finale",
                                   jax.jit(final, donate_argnums=(0, 1)))
@@ -676,7 +626,7 @@ class StreamingAggregator:
         )
 
 
-class StreamedPod:
+class StreamedPod(_Planned):
     """Streamed rounds over a SimulatedPod mesh — the flagship-scale mode.
 
     Host loop tiles (participants x dim); each tile step is a collective-
@@ -700,26 +650,11 @@ class StreamedPod:
         surviving_clerks=None,
         uniform_tail: bool = False,
     ):
-        from .simpod import SimulatedPod, default_mesh_shape, make_mesh
-
-        self.scheme = s = sharing_scheme
-        self.modulus = _scheme_modulus(s)
-        self.masking = masking_scheme or NoMasking()
-        _check_masking_supported(self.masking)
-        _check_mask_modulus(self.masking, s)
-        if mesh is None:
-            p_shards, d_shards = default_mesh_shape(
-                len(jax.devices()), s.output_size
-            )
-            mesh = make_mesh(p_shards, d_shards)
-        self.mesh = mesh
-        p_shards, d_shards = mesh.devices.shape
-        if s.output_size % p_shards:
-            raise ValueError(
-                f"committee size {s.output_size} must be divisible by the "
-                f"p axis ({p_shards})"
-            )
-        grain = _dim_grain(s, self.masking) * d_shards
+        self._plan = _RoundPlan(sharing_scheme, masking_scheme, mesh,
+                                use_pallas, surviving_clerks, pallas_interpret,
+                                pallas_external_bits_fn)
+        p_shards, d_shards = self.mesh.devices.shape
+        grain = _dim_grain(self.scheme, self.masking) * d_shards
         self._grain = grain
         # round the tile sizes up to the mesh grain
         self.participants_chunk = -(-int(participants_chunk) // p_shards) * p_shards
@@ -733,16 +668,6 @@ class StreamedPod:
         # tests/test_devscale.py. The participant axis is always uniform
         # here (make_block pads every block to participants_chunk rows).
         self.uniform_tail = bool(uniform_tail)
-        self.surviving_clerks = _normalize_survivors(s, surviving_clerks)
-        self._M_host, self._L_host = _build_matrices(s, self.surviving_clerks)
-        self._field = FieldOps.create(self.modulus, cross_terms=p_shards)
-        _check_collective_headroom(self._field, p_shards)
-        self.pallas_active = _resolve_pallas(
-            s, self.masking, self._field, use_pallas, "streamed"
-        )
-        self._cipher = _chacha_cipher(self._field, mesh.devices)
-        self._pallas_interpret = bool(pallas_interpret)
-        self._pallas_bits_fn = pallas_external_bits_fn
         self._steps = {}      # local block shape -> jitted accumulate step
         self._finals = {}     # dim-tile size -> jitted collective finale
 
@@ -763,12 +688,12 @@ class StreamedPod:
         )
 
     def _step_fn(self, block_shape):
-        f, s, masking = self._field, self.scheme, self.masking
+        plan = self._plan
+        f, draws = plan.field, plan.draws
 
         def local_step(block, tile_key, round_key, tile_base, d_block_base,
                        acc_shares, acc_mask):
             # block [Pc_loc, d_loc]; acc_shares [n, B_loc]; acc_mask [1, d_loc]
-            draws = _draw_scope(self.pallas_active)
             Pc_loc, d_loc = block.shape
             with jax.named_scope(draws):
                 pi = jax.lax.axis_index("p")
@@ -779,22 +704,9 @@ class StreamedPod:
             with jax.named_scope(draws):
                 pid0 = tile_base + pi * Pc_loc
                 dblk0 = d_block_base + di * (d_loc // 8)
-            if self.pallas_active:
-                # fused mask+share+combine in one HBM pass (pallas_round.py)
-                shares, local_mask_sum = _pallas_stage(
-                    s, f, self._M_host, masking, x, dev_key,
-                    round_key=round_key, pid_base=pid0, d_block0=dblk0,
-                    interpret=self._pallas_interpret,
-                    external_bits_fn=self._pallas_bits_fn,
-                    cipher=self._cipher,
-                )
-            else:
-                masked_sum, local_mask_sum, skey = _mask_stage(
-                    masking, f, x, dev_key, round_key,
-                    pid_base=pid0, d_block0=dblk0, cipher=self._cipher,
-                )
-                shares = _share_sum_stage(
-                    s, f, self._M_host, masked_sum, x.shape[0], skey)
+            shares, local_mask_sum = plan.local_step(
+                x, dev_key, round_key=round_key, pid_base=pid0,
+                d_block0=dblk0)
             with jax.named_scope("sda.stream.acc"):
                 acc_shares = f.add(acc_shares, shares)
                 if local_mask_sum is not None:
@@ -814,26 +726,13 @@ class StreamedPod:
                                   jax.jit(fn, donate_argnums=(5, 6)))
 
     def _final_fn(self, d_size: int):
-        f, s = self._field, self.scheme
+        plan = self._plan
         masked = not isinstance(self.masking, NoMasking)
 
         def local_final(acc_shares, acc_mask):
-            d_loc = acc_mask.shape[-1]
-            with jax.named_scope("sda.clerk_combine"):
-                clerk_rows = jax.lax.psum_scatter(
-                    acc_shares, "p", scatter_dimension=0, tiled=True
-                )
-                clerk_rows = f.canon(clerk_rows)
-                gathered = jax.lax.all_gather(
-                    clerk_rows, "p", axis=0, tiled=True)
-            masked_total = _reconstruct_stage(
-                s, f, self._L_host, gathered, d_loc, self.surviving_clerks
-            )
-            with jax.named_scope("sda.unmask"):
-                if not masked:
-                    return f.to_int64(masked_total)
-                mask_total = f.canon(jax.lax.psum(acc_mask[0], "p"))
-                return f.to_int64(f.sub(masked_total, mask_total))
+            # acc_mask [1, d_loc]: this device's row of the mask sums
+            return plan.finale(acc_shares, acc_mask if masked else None,
+                               acc_mask.shape[-1])
 
         fn = _shard_map(
             local_final,

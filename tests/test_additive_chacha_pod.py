@@ -89,10 +89,9 @@ def test_mask_stage_is_the_reference_stream_of_the_rounds_seeds(d_block0):
     rows, dim, first_id = 5, 64, 7
     field = FieldOps.create(MODULUS)
     round_key = jax.random.PRNGKey(11)
-    zeros = jnp.zeros((rows, dim), field.dtype)
-    masked_sum, mask_sum, _ = simpod._mask_stage(
-        ChaChaMasking(MODULUS, dim, SEED_BITS), field, zeros,
-        jax.random.PRNGKey(2), round_key, pid_base=first_id, d_block0=d_block0)
+    mask_sum = simpod._chacha_mask_sum(
+        ChaChaMasking(MODULUS, dim, SEED_BITS), field, round_key, first_id, rows,
+        dim, d_block0, cipher="xla")
     seeds = np.asarray(simpod._chacha_seed_words(
         round_key, first_id + jnp.arange(rows), SEED_BITS))
     streams = np.stack([
@@ -101,18 +100,17 @@ def test_mask_stage_is_the_reference_stream_of_the_rounds_seeds(d_block0):
     # row for row: the stage's three steps on every row, ordered a row
     masks = chacha_mask_rows(field, round_key, first_id, rows, dim, d_block0, SEED_BITS)
     assert np.array_equal(np.asarray(masks), streams)
-    # the stage: the rows' masks folded, then ordered; zero inputs, so the
-    # masked fold is the masks'
+    # the stage: the rows' masks folded, then ordered
     assert np.array_equal(np.asarray(mask_sum), streams.sum(axis=0) % MODULUS)
-    assert np.array_equal(np.asarray(masked_sum), np.asarray(mask_sum))
 
 
 @pytest.mark.parametrize("participants", [3, 8, 13])
 def test_a_pod_rounds_masks_are_the_reference_stream_mod_p(participants):
-    """What the round adds to the fold of each block of rows, block by
-    block as ``_scan_combine`` asks for it, and the mask total the round
-    subtracts, against the reference's stream reduced mod p on the host:
-    the aggregate alone cannot tell (masks cancel whatever they are)."""
+    """The masks of each block of rows, block by block as the XLA block
+    function expands them, what the round shares (the fold of the rows,
+    each with its mask) and the mask total it subtracts, against the
+    reference's stream reduced mod p on the host: the aggregate alone
+    cannot tell (masks cancel whatever they are)."""
     dim = 96
     pod = _pod(dim)
     field, chunk = pod._field, pod.scan_chunk
@@ -124,23 +122,23 @@ def test_a_pod_rounds_masks_are_the_reference_stream_mod_p(participants):
     want = np.stack([reference.mask_stream(seed[:SEED_BITS // 32], 0, dim, MODULUS)
                      for seed in seeds])
     for first in range(0, participants, chunk):
-        rows = x[first:first + chunk]
-        count = rows.shape[0]
-        masked_sum, mask_sum, _ = simpod._mask_stage(
-            pod.masking, field, rows, jax.random.PRNGKey(0), round_key,
-            pid_base=first, d_block0=0)
+        count = min(chunk, participants - first)
+        mask_sum = simpod._chacha_mask_sum(
+            pod.masking, field, round_key, first, count, dim, 0, cipher="xla")
         per_row = chacha_mask_rows(field, round_key, first, count, dim, 0, SEED_BITS)
         assert np.array_equal(np.asarray(per_row), want[first:first + count])
         assert np.array_equal(np.asarray(mask_sum),
                               want[first:first + count].sum(axis=0) % MODULUS)
-        # what enters sharing: the fold of the rows, each with its mask
-        masked = (np.asarray(rows).astype(np.int64) + want[first:first + count])
-        assert np.array_equal(np.asarray(masked_sum), masked.sum(axis=0) % MODULUS)
-    _, mask_total = simpod._scan_combine(
-        field, pod.scheme, pod.masking, None, x, jax.random.PRNGKey(0), round_key,
-        pid0=0, dblk0=0, chunk=chunk)
+    shares, mask_total = simpod._scan_combine(
+        pod.scheme, field, None, pod.masking, x, jax.random.PRNGKey(0),
+        round_key=round_key, pid_base=0, d_block0=0)
     # the scan expands whole blocks: padded rows carry masks too
     assert np.array_equal(np.asarray(mask_total), want.sum(axis=0) % MODULUS)
+    # what enters sharing: the fold of the rows, each with its mask (the
+    # additive share rows sum to it)
+    masked = np.asarray(x).astype(np.int64).sum(axis=0) + want.sum(axis=0)
+    assert np.array_equal(np.asarray(shares).astype(np.int64).sum(axis=0) % MODULUS,
+                          masked % MODULUS)
 
 
 def test_a_128_bit_seed_fills_four_key_words_and_leaves_four_zero():
@@ -167,10 +165,10 @@ def test_additive_share_rows_sum_to_the_masked_sum_and_are_redrawn_per_key():
     field = FieldOps.create(MODULUS)
     scheme = AdditiveSharing(SHARES, MODULUS)
     masked = jnp.asarray(_inputs(rows, dim) % MODULUS, field.dtype)
-    out = [np.asarray(simpod._share_sum_stage(
-        scheme, field, None, field.sum(masked, axis=0), rows,
-        jax.random.PRNGKey(k))).astype(np.int64)
-        for k in (0, 1)]
+    out = [np.asarray(simpod._share_combine(
+        scheme, field, None, field.sum(masked, axis=0),
+        simpod._share_draws(scheme, field, rows, dim, jax.random.PRNGKey(k))
+    )).astype(np.int64) for k in (0, 1)]
     want = np.asarray(masked).astype(np.int64).sum(axis=0) % MODULUS
     for shares in out:
         assert shares.shape == (SHARES, dim)
@@ -193,9 +191,10 @@ def test_additive_share_stage_is_the_fold_of_each_participants_shares(modulus, s
     assert (field.sp is not None) == (modulus == MODULUS)
     key = jax.random.PRNGKey(shares)
     masked = field.to_residues(_inputs(rows, dim) % modulus)
-    stage = np.asarray(simpod._share_sum_stage(
-        AdditiveSharing(shares, modulus), field, None, field.sum(masked, axis=0),
-        rows, key)).astype(np.int64)
+    scheme = AdditiveSharing(shares, modulus)
+    stage = np.asarray(simpod._share_combine(
+        scheme, field, None, field.sum(masked, axis=0),
+        simpod._share_draws(scheme, field, rows, dim, key))).astype(np.int64)
     per = sharing.additive_share(key, jnp.asarray(masked, jnp.int64),
                                  share_count=shares, modulus=modulus)
     assert per.shape == (rows, shares, dim)

@@ -189,11 +189,12 @@ def test_the_fold_of_the_rows_in_element_order_is_the_fold_of_the_ordered_rows(
 @pytest.mark.parametrize("path", ["xla-stage", "kernel-path-sum"])
 def test_the_masks_sum_is_the_sum_of_the_host_oracles_streams_window_by_window(
         path, rows):
-    """Both mask passes fold word-major and order the fold: the XLA step's
-    stage on the block it is given, the kernel path's scan 8 rows at a
-    time (13 rows expand 16: the ids after the last row cancel like any
-    mask). Each 'd' shard expands its own window at a traced block counter;
-    held to ``fields/chacha.py``'s block function, reduced on the host."""
+    """Both mask passes fold word-major and order the fold: the block
+    function's fold of the rows it is given, ordered once, and the masks'
+    sum the steps take, a scan 8 rows at a time (13 rows expand 16: the
+    ids after the last row cancel like any mask). Each 'd' shard expands
+    its own window at a traced block counter; held to
+    ``fields/chacha.py``'s block function, reduced on the host."""
     p, dim, first_id = 536870233, 96, 7
     d_loc = dim // 2
     field, masking = FieldOps.create(p), ChaChaMasking(p, dim, 128)
@@ -202,8 +203,8 @@ def test_the_masks_sum_is_the_sum_of_the_host_oracles_streams_window_by_window(
     def local(x):
         block0 = jax.lax.axis_index("d") * (d_loc // 8)
         if path == "xla-stage":
-            return simpod._mask_stage(masking, field, x, jax.random.PRNGKey(2),
-                                      round_key, pid_base=first_id, d_block0=block0)[1]
+            return simpod._element_order(simpod._chacha_mask_fold(
+                masking, field, round_key, first_id, x.shape[0], d_loc, block0))
         return simpod._chacha_mask_sum(masking, field, round_key, first_id, rows,
                                        d_loc, block0)
 
@@ -226,8 +227,8 @@ def test_the_masks_sum_is_the_sum_of_the_host_oracles_streams_window_by_window(
 @pytest.mark.parametrize("step", ["xla", "pallas-interpret"])
 def test_mask_stage_masks_are_the_stream_mod_p_row_for_row_on_a_sharded_dim(step):
     """Two 'd' shards, each expanding its own window of every row's stream
-    at a traced block counter, as ``SimulatedPod._local_round`` calls the
-    stage. Zero inputs, so what comes out is the masks."""
+    at a traced block counter, as ``SimulatedPod._local_round`` calls its
+    local step. Zero inputs, so what comes out is the masks."""
     t, p, w2, w3 = numtheory.generate_packed_params(3, 8, 28)
     scheme = PackedShamirSharing(3, 8, t, p, w2, w3)  # the kernel's: a Solinas prime
     rows, dim, first_id = 5, 96, 7
@@ -240,14 +241,15 @@ def test_mask_stage_masks_are_the_stream_mod_p_row_for_row_on_a_sharded_dim(step
     def local(x):
         block0 = jax.lax.axis_index("d") * (d_loc // 8)
         if step == "xla":
-            # the stage returns folds alone: one row a call, like the kernel's
-            sums = [simpod._mask_stage(
-                masking, field, x[row:row + 1], dev_key, round_key,
-                pid_base=first_id + row, d_block0=block0)[0]
+            # the step returns folds alone: one row a call, like the kernel's
+            sums = [simpod._scan_combine(
+                scheme, field, matrices[0], masking, x[row:row + 1], dev_key,
+                round_key=round_key, pid_base=first_id + row,
+                d_block0=block0)[1]
                 for row in range(rows)]
-            _, mask_sum, _ = simpod._mask_stage(
-                masking, field, x, dev_key, round_key,
-                pid_base=first_id, d_block0=block0)
+            _, mask_sum = simpod._scan_combine(
+                scheme, field, matrices[0], masking, x, dev_key,
+                round_key=round_key, pid_base=first_id, d_block0=block0)
             return jnp.stack(sums), mask_sum
         sums = [simpod._pallas_stage(
             scheme, field, matrices[0], masking, x[row:row + 1], dev_key,
@@ -280,12 +282,12 @@ def test_mask_stage_reads_the_keystream_with_no_gather():
     p, rows, dim = 536870233, 8, 96
     field = FieldOps.create(p)
 
-    def stage(x, key, round_key, block0):
-        return simpod._mask_stage(ChaChaMasking(p, dim, 128), field, x, key,
-                                  round_key, pid_base=0, d_block0=block0)[:2]
+    def stage(round_key, block0):
+        return simpod._chacha_mask_sum(ChaChaMasking(p, dim, 128), field,
+                                       round_key, 0, rows, dim, block0,
+                                       cipher="xla")
 
     text = jax.jit(stage).lower(
-        jnp.zeros((rows, dim), field.dtype), jax.random.PRNGKey(0),
         jax.random.PRNGKey(1), jnp.int32(0)).as_text(debug_info=True)
     assert "sda.mask/sda.mask.relayout" in text
     keystream = rows * dim * 2  # uint32 words of the block's draws

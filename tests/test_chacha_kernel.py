@@ -142,16 +142,16 @@ def _pod(kind: str, dim: int) -> SimulatedPod:
 
 
 def _mask_sums(dim: int, cipher: str):
-    """The XLA step's and the kernel path's mask sums of 13 rows from id 40
-    at block 5 with ``cipher``: ``_mask_stage`` (one call a block of rows)
-    and ``_chacha_mask_sum`` (one call for all rows)."""
+    """Two mask sums of 13 rows from id 40 at block 5 with ``cipher``: the
+    fold of the 13 rows as they are, ordered once (``_chacha_mask_fold``,
+    one call a block of rows), and ``_chacha_mask_sum`` (what both steps
+    take, one call for all rows)."""
     masking = ChaChaMasking(MODULUS, dim, 128)
     round_key = jax.random.PRNGKey(21)
 
     def both(pid_base, d_block0):
-        zeros = jnp.zeros((13, dim), jnp.uint32)
-        stage = simpod._mask_stage(masking, FIELD, zeros, None, round_key,
-                                   pid_base, d_block0, cipher)[1]
+        stage = simpod._element_order(simpod._chacha_mask_fold(
+            masking, FIELD, round_key, pid_base, 13, dim, d_block0, cipher))
         return stage, simpod._chacha_mask_sum(masking, FIELD, round_key, pid_base,
                                               13, dim, d_block0, cipher)
 

@@ -190,14 +190,15 @@ def test_pod_noncanonical_inputs():
 
 
 def test_share_sum_stage_equals_per_participant_fold():
-    """_share_sum_stage's linearity fusion must be bit-exact vs summing
-    per-participant share rows drawn from the same key (both schemes,
-    both field paths)."""
+    """The share stage's linearity fusion (``_share_draws`` folded over the
+    participants where they are drawn, ``_share_combine`` once on the
+    folds) must be bit-exact vs summing per-participant share rows drawn
+    from the same key (both schemes, both field paths)."""
     import jax.numpy as jnp
 
     from sda_tpu.fields import numtheory, sharing
     from sda_tpu.fields.ops import FieldOps
-    from sda_tpu.mesh.simpod import _build_matrices, _share_sum_stage
+    from sda_tpu.mesh.simpod import _build_matrices, _share_combine, _share_draws
 
     key = jax.random.PRNGKey(17)
     rng = np.random.default_rng(17)
@@ -215,8 +216,9 @@ def test_share_sum_stage_equals_per_participant_fold():
         f = FieldOps.create(mod)
         M_host, _ = _build_matrices(scheme)
         masked = f.to_residues(rng.integers(0, mod, size=(5, 36)))
-        fused = np.asarray(_share_sum_stage(
-            scheme, f, M_host, f.sum(masked, axis=0), masked.shape[0], key))
+        drawn = _share_draws(scheme, f, masked.shape[0], masked.shape[1], key)
+        fused = np.asarray(_share_combine(
+            scheme, f, M_host, f.sum(masked, axis=0), drawn))
         if isinstance(scheme, PackedShamirSharing):
             if f.sp is not None:
                 per = sharing.packed_share32(

@@ -200,9 +200,9 @@ def test_a_ragged_last_block_expands_its_zero_rows_and_they_cancel():
     masking = ChaChaMasking(MODULUS, DIM, SEED_BITS)
     round_key, first_id = jax.random.PRNGKey(21), 40
     blocked = simpod._chacha_mask_sum(masking, field, round_key, first_id, 13, DIM, 0)
-    zeros = jnp.zeros((16, DIM), jnp.uint32)
-    _, whole16, _ = simpod._mask_stage(masking, field, zeros, None, round_key, first_id, 0)
-    _, whole13, _ = simpod._mask_stage(masking, field, zeros[:13], None, round_key, first_id, 0)
+    whole16, whole13 = (field.sum(chacha_mask_rows(field, round_key, first_id, rows,
+                                                   DIM, 0, SEED_BITS), axis=0)
+                        for rows in (16, 13))
     np.testing.assert_array_equal(np.asarray(blocked), np.asarray(whole16))
     extra = (np.asarray(blocked).astype(np.int64) - np.asarray(whole13)) % MODULUS
     np.testing.assert_array_equal(extra, _mask_total(round_key, first_id + 13, 3, 0, DIM))

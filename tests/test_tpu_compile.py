@@ -82,28 +82,35 @@ def _compile_for(one_chip, stage, *args):
 
 @pytest.fixture(scope="module")
 def mask_stage_compiled(one_chip):
-    """``_mask_stage``'s ChaCha branch on one scan block: 8 rows x
-    1,000,000, a traced block counter, with the XLA block function (the
-    stage's default; a step built for a TPU takes the on-core cipher)."""
+    """The XLA step's ChaCha masks on one scan block: the masks' sum of 8
+    rows x 1,000,000 (``_chacha_mask_sum`` with the XLA block function, the
+    cipher of every step not built for a TPU; a step built for a TPU takes
+    the on-core cipher) at a traced block counter, and the rows' fold with
+    it added: -> (masked fold, masks' sum)."""
     field = FieldOps.create(MODULUS)
 
-    def stage(x, key, round_key, pid_base, block0):
-        return simpod._mask_stage(ChaChaMasking(MODULUS, DIM, 128), field, x, key,
-                                  round_key, pid_base=pid_base, d_block0=block0)[:2]
+    def stage(x, round_key, pid_base, block0):
+        mask_sum = simpod._chacha_mask_sum(
+            ChaChaMasking(MODULUS, DIM, 128), field, round_key, pid_base, ROWS,
+            DIM, block0, cipher="xla")
+        return field.add(field.sum(x, axis=0), mask_sum), mask_sum
 
     return _compile_for(
         one_chip, stage, ((ROWS, DIM), jnp.uint32), ((2,), jnp.uint32),
-        ((2,), jnp.uint32), ((), jnp.int32), ((), jnp.int32))
+        ((), jnp.int32), ((), jnp.int32))
 
 
 @pytest.fixture(scope="module")
 def share_stage_compiled(one_chip):
-    """``_share_sum_stage``'s additive branch, 3 shares, on one scan block."""
+    """The XLA step's additive share stage, 3 shares, on one scan block:
+    a block's draws folded (``_share_draws``) and the share rows made from
+    the folds (``_share_combine``)."""
     field = FieldOps.create(MODULUS)
+    scheme = AdditiveSharing(3, MODULUS)
 
     def stage(masked, key):
-        return simpod._share_sum_stage(AdditiveSharing(3, MODULUS), field, None,
-                                       field.sum(masked, axis=0), ROWS, key)
+        return simpod._share_combine(scheme, field, None, field.sum(masked, axis=0),
+                                     simpod._share_draws(scheme, field, ROWS, DIM, key))
 
     return _compile_for(one_chip, stage, ((ROWS, DIM), jnp.uint32), ((2,), jnp.uint32))
 
@@ -127,10 +134,12 @@ def test_mask_stage_changes_layout_with_no_gather_no_row_loop_and_no_padded_plan
     copy into ``u32[8,125000,16]`` (16 words in 128 lanes), a flatten and a
     row loop: two thirds of the round. The draws now stay word-major until
     they are residues, and those change layout once, through the matrix
-    unit (``chacha_jax.element_order``)."""
+    unit (``chacha_jax.element_order``). The one loop is the masks' sum's
+    scan over blocks of 8 rows (one block here): none inside a block."""
     text = mask_stage_compiled.as_text()
     assert " gather(" not in text
-    assert " while(" not in text
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 1 and 'op_name="jit(stage)/sda.mask/while"' in loops[0], loops
     assert not _lane_padded(text)
     assert "sda.mask.relayout" in text and "dot_general" in text
 
@@ -227,7 +236,7 @@ def test_share_stage_runs_one_cipher_block_a_draw_once(share_stage_compiled):
 
 # -- packed Shamir under ChaCha masks on the kernel path (PR 35): the masks'
 # sum is made 8 rows at a time in front of the kernel. Until then the whole
-# [S, d] block went through ``_mask_stage`` at once: 22 MB of temporaries a
+# [S, d] block had its masks expanded at once: 22 MB of temporaries a
 # row, 6.16 GB at 300 rows, and 1200 rows were refused by the compiler.
 
 PADDED_DIM = 1_000_008  # 999,999 at the grain lcm(3, 8)
@@ -555,6 +564,7 @@ LOWERED_SHA256 = {
     "packed-1m-streamed": "3cc029c33dabc5f695b23528a9a2b1e515ff9ac7d238f2015d8110faab595bda",
     "packed-chacha-1m": "e046b5d8b816cb7ccc775ee9b95c94e9a01f8879631263d45661c7dbc5333b1d",
     "fedavg-f32-1m": "dbf5d9b29710a4446e3133f7902de96be8e36b67067b830c7b70b1792c83487d",
+    "fedavg-sporadic-1m": "347d0be88de0b0b5b99f2f31c4a093b8ab20a9e5f6f4f16b6f03d539239ce36d",
 }
 
 
@@ -767,8 +777,9 @@ def _cell_lowered(cell, devices):
                     *accs),
                 agg._final_fn(dim).lower(*accs)]
     codec = None
-    if config["driver"] == "pod_fedavg":
-        pod, codec = driver.build_pod(config, devices)
+    if config["driver"] in ("pod_fedavg", "pod_fedavg_sporadic"):
+        pod, codec = harness.load_module(cell.home, "drivers", "pod_fedavg").build_pod(
+            config, devices)
     elif config["driver"] == "pod":
         pod = driver.build_pod(config, devices)
     else:
@@ -782,9 +793,13 @@ def _cell_lowered(cell, devices):
     if codec is not None:
         from sda_tpu.models import federated
 
-        program = federated._resident_program(pod, codec, participants, dim)
+        reported = config["driver"] == "pod_fedavg_sporadic"
+        program = federated._resident_program(pod, codec, participants, dim,
+                                              reported=reported)
+        who = [on(("p",), (participants,), jnp.bool_)] if reported else []
         return [program.lower(on(("d",), (dim,), jnp.float32),
-                              on(("p", "d"), (participants, dim), jnp.float32), key)]
+                              on(("p", "d"), (participants, dim), jnp.float32), key,
+                              *who)]
     padded = pod.padded_shape(participants, dim)
     dtype = jnp.int64 if traffic["input"] == "host" else jnp.uint32
     return [pod.aggregate_fn(*padded).lower(on(("p", "d"), padded, dtype), key)]

@@ -26,11 +26,16 @@ MODULUS = 536870233  # 2^29 - 679: the uint32 field path
 DIM, CHUNK, SEED_BITS = 96, 8, 128
 
 
-def _parent_scan_combine(f, scheme, masking, M_host, x, key, round_key, pid0,
-                         dblk0, chunk, reported=None, cipher="xla"):
+def _parent_scan_combine(scheme, f, M_host, masking, x, key, *, round_key,
+                         pid_base, d_block0, chunk=CHUNK, reported=None,
+                         cipher="xla"):
     """``_scan_combine`` as it was block by block, its stage scopes left out:
-    the cohort padded to whole blocks and cut into them, each block put
-    through ``_mask_stage`` and ``_share_sum_stage``."""
+    the cohort padded to whole blocks and cut into them; in each block the
+    rows that reported folded, their masks drawn (full: from the first
+    half of the block key's split) or expanded (ChaCha: the block's rows)
+    and folded onto them, and the shares made from that fold and the
+    block's draws (full: the second half of the split; else the block
+    key)."""
     P, d = x.shape
     chunk, padded_rows = simpod._scan_rows(P, chunk)
     pad = padded_rows - P
@@ -46,12 +51,20 @@ def _parent_scan_combine(f, scheme, masking, M_host, x, key, round_key, pid0,
     def body(carry, blk_i):
         acc_s, acc_m = carry
         blk, blk_reported, i = blk_i
-        blk = simpod._reported_rows(blk, blk_reported)
-        bkey = jax.random.fold_in(key, i)
-        masked_sum, mask_sum, skey = simpod._mask_stage(
-            masking, f, blk, bkey, round_key, pid_base=pid0 + i * chunk,
-            d_block0=dblk0, cipher=cipher)
-        shares = simpod._share_sum_stage(scheme, f, M_host, masked_sum, chunk, skey)
+        masked_sum = f.sum(simpod._reported_rows(blk, blk_reported), axis=0)
+        skey, mask_sum = jax.random.fold_in(key, i), None
+        if isinstance(masking, FullMasking):
+            mkey, skey = jax.random.split(skey)
+            mask_sum = f.sum(f.uniform(mkey, (chunk, d)), axis=0)
+        elif isinstance(masking, ChaChaMasking):
+            mask_sum = simpod._chacha_mask_sum(
+                masking, f, round_key, pid_base + i * chunk, chunk, d, d_block0,
+                cipher)
+        if mask_sum is not None:
+            masked_sum = f.add(masked_sum, mask_sum)
+        shares = simpod._share_combine(
+            scheme, f, M_host, masked_sum,
+            simpod._share_draws(scheme, f, chunk, d, skey))
         acc_s = f.add(acc_s, shares)
         if mask_sum is not None:
             acc_m = f.add(acc_m, mask_sum)
@@ -90,16 +103,16 @@ def test_the_scan_carries_the_draws_alone_and_combines_as_the_blocks_did(
     # steps built for the CPU take the cipher of the case, as a TPU's do
     monkeypatch.setitem(simpod._ON_CORE_CIPHER, "cpu", cipher)
     pod = SimulatedPod(_scheme(kind), _masking(masking.split("-")[0]),
-                       mesh=make_mesh(1, 1), scan_chunk=CHUNK)
+                       mesh=make_mesh(1, 1))
     assert not pod.pallas_active and pod._cipher == cipher
-    f, scheme = pod._field, pod.scheme
+    assert pod.scan_chunk == simpod._SCAN_CHUNK == CHUNK
+    f, scheme, M_host = pod._field, pod.scheme, pod._plan.M_host
     rng = np.random.default_rng(rows * 10 + len(masking))
     inputs = rng.integers(0, 1 << 20, size=(rows, DIM), dtype=np.int64)
     reported = rng.random(rows) < 0.6 if told else None
     key, round_key = jax.random.PRNGKey(rows), jax.random.PRNGKey(rows + 1)
-    args = (f, scheme, pod.masking, pod._M_host, jnp.asarray(inputs, f.dtype),
-            key, round_key)
-    kwargs = dict(pid0=0, dblk0=0, chunk=CHUNK, cipher=cipher,
+    args = (scheme, f, M_host, pod.masking, jnp.asarray(inputs, f.dtype), key)
+    kwargs = dict(round_key=round_key, pid_base=0, d_block0=0, cipher=cipher,
                   reported=None if reported is None else jnp.asarray(reported))
     acc_s, acc_m = jax.jit(lambda: simpod._scan_combine(*args, **kwargs))()
     want_s, want_m = jax.jit(lambda: _parent_scan_combine(*args, **kwargs))()
@@ -122,7 +135,7 @@ def test_the_scan_carries_the_draws_alone_and_combines_as_the_blocks_did(
     # the shares: the oracle's bit for bit, less the shares of the masks it
     # expanded besides (none but in the ragged case above)
     zero_draws = jnp.zeros(simpod._drawn_shape(scheme, DIM), f.dtype)
-    extra = simpod._share_combine(scheme, f, pod._M_host, skipped, zero_draws)
+    extra = simpod._share_combine(scheme, f, M_host, skipped, zero_draws)
     np.testing.assert_array_equal(np.asarray(f.add(acc_s, extra)), np.asarray(want_s))
     if not skipped.any():
         np.testing.assert_array_equal(np.asarray(acc_s), np.asarray(want_s))
@@ -131,3 +144,26 @@ def test_the_scan_carries_the_draws_alone_and_combines_as_the_blocks_did(
     out = np.asarray(pod.aggregate(inputs, jax.random.PRNGKey(7), reported=reported))
     counted = inputs if reported is None else inputs[reported]
     np.testing.assert_array_equal(out, counted.sum(axis=0) % MODULUS)
+
+
+@pytest.mark.parametrize("masking", ["full", "chacha"])
+def test_every_driver_on_the_xla_step_reveals_the_plain_sum_through_ragged_blocks(
+        masking):
+    """``StreamingAggregator``, ``StreamedPod`` and ``ModelScaleRound`` take
+    the pod's XLA step: 13 rows a device and a step, a whole scan block and
+    a ragged one, two dim tiles (the second at its own ChaCha window), on
+    a (2, 1) mesh where there is one."""
+    from sda_tpu.mesh import ModelScaleRound, StreamedPod, StreamingAggregator
+
+    scheme, mask = _scheme("packed"), _masking(masking)
+    rng = np.random.default_rng(len(masking))
+    inputs = rng.integers(0, 1 << 20, size=(26, DIM), dtype=np.int64)
+    drivers = [
+        StreamingAggregator(scheme, mask, participants_chunk=13, dim_chunk=48),
+        StreamedPod(scheme, mask, mesh=make_mesh(2, 1), participants_chunk=26,
+                    dim_chunk=48),
+        ModelScaleRound(scheme, mask, mesh=make_mesh(2, 1), dim_tile=48)]
+    for driver in drivers:
+        assert not driver.pallas_active
+        out = np.asarray(driver.aggregate(inputs, jax.random.PRNGKey(3)))
+        np.testing.assert_array_equal(out, inputs.sum(axis=0) % MODULUS)
